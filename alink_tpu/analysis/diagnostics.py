@@ -37,9 +37,9 @@ RULES: Dict[str, tuple] = {
                "builders — per-call rebuilt programs defeat the process-wide "
                "compile cache"),
     "ALK002": ("shard-map-drift", WARNING,
-               "direct jax.shard_map usage — import the version-compat shim "
-               "instead (alink_tpu/parallel/shardmap.py normalizes the "
-               "check_vma/check_rep and axis_names/auto API drift)"),
+               "direct jax.shard_map usage — import it from "
+               "alink_tpu/parallel/shardmap.py, the one module that follows "
+               "jax's shard_map API"),
     "ALK003": ("raw-environ", WARNING,
                "direct os.environ read bypassing the common/env.py knob "
                "parsers (env_int/env_float/env_flag/env_str) — malformed "
@@ -56,7 +56,7 @@ RULES: Dict[str, tuple] = {
                "(jax.config.update('jax_compilation_cache_*'/'jax_"
                "persistent_cache_*') or a raw compilation_cache import) "
                "outside common/jitcache.py — bypasses the one sanctioned "
-               "owner (knob ALINK_COMPILE_CACHE_DIR, persist counters, "
+               "owner (placement by JAX_COMPILATION_CACHE_DIR, persist counters, "
                "corruption fallback, disk LRU cap)"),
     "ALK008": ("unregistered-pallas", WARNING,
                "jax.experimental.pallas import or pl.pallas_call reference "

@@ -12,8 +12,9 @@ pre-lowering shape inference — PAPERS.md):
   builder idiom), and inside ``common/jitcache.py`` itself;
 - **ALK002** any direct ``jax.shard_map`` /
   ``jax.experimental.shard_map`` reference outside
-  ``parallel/shardmap.py`` — the version-compat shim is the one sanctioned
-  import (``from alink_tpu.parallel.shardmap import shard_map``); the
+  ``parallel/shardmap.py`` — that module is the one sanctioned import
+  (``from alink_tpu.parallel.shardmap import shard_map``), so a jax API
+  move is a one-file change; the
   migration retired the drift, so the baseline pins this rule at zero and
   ``--shard-map-inventory`` must stay empty;
 - **ALK003** raw ``os.environ`` *reads* (``.get``/subscript-load/``in``)
@@ -27,9 +28,10 @@ pre-lowering shape inference — PAPERS.md):
   ``jax.config.update("jax_compilation_cache_*" / "jax_persistent_cache_*",
   ...)`` or any raw ``compilation_cache`` import — outside
   ``common/jitcache.py``, the one sanctioned owner of persistent compile
-  artifacts (same single-owner shape as ALK002): bypasses the
-  ``ALINK_COMPILE_CACHE_DIR`` knob, the ``jit.persist_*`` counters, the
-  corruption fallback, and the on-disk LRU cap.
+  artifacts (same single-owner shape as ALK002): bypasses the placement
+  rule (``JAX_COMPILATION_CACHE_DIR`` or the in-checkout default), the
+  ``jit.persist_*`` counters, the corruption fallback, and the on-disk LRU
+  cap.
 
 (**ALK000** parse-error, error severity, marks a file ``ast.parse`` rejects —
 no other rule could run on it.)
@@ -299,7 +301,7 @@ class _FileLinter(ast.NodeVisitor):
                 "the sanctioned owner (no persist counters, no corruption "
                 "fallback, no disk LRU cap)",
                 hint="route through common/jitcache.enable_persistent_cache "
-                     "(knob ALINK_COMPILE_CACHE_DIR)")
+                     "(placed by JAX_COMPILATION_CACHE_DIR)")
         if tail == "get" and isinstance(node.func, ast.Attribute) \
                 and _is_environ(node.func.value) and not self.is_env_module:
             self._add(
@@ -327,9 +329,9 @@ class _FileLinter(ast.NodeVisitor):
                 and not self.is_shardmap_shim:
             self._add(
                 "ALK002", node,
-                f"direct {_dotted(node)} reference — bypasses the version-"
-                "compat shim and fails at trace time on JAX versions "
-                "without it",
+                f"direct {_dotted(node)} reference — bypasses "
+                "parallel/shardmap.py, the one module that follows jax's "
+                "shard_map API",
                 hint="from alink_tpu.parallel.shardmap import shard_map "
                      "(the one sanctioned import)")
         # jax.experimental.pallas attribute chains (innermost match, same

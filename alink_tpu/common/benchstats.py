@@ -1,8 +1,8 @@
 """Variance-hardened benchmark statistics + the BENCH regression gate.
 
-The BENCH_r0*.json trajectory accumulated five rounds with no tool that
-compares them — the headline BERT regression (r04 → r05, −12%) sat on
-record with no detector. This module is that detector, in two layers:
+Benchmark rounds once accumulated with no tool that compared them — a 12%
+drop of the headline BERT number sat on record with no detector. This
+module is that detector, in two layers:
 
 1. **In-process measurement** — :func:`measure_interleaved` runs competing
    configurations A,B,A,B,... (never a block of A then a block of B, so

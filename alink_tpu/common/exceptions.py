@@ -123,8 +123,8 @@ _NON_TRANSIENT_OS = (
     NotADirectoryError, FileExistsError,
 )
 
-# status keywords XLA/jax runtime errors carry when the device, transfer
-# tunnel, or compile service hiccuped (vs. genuine program errors like
+# status keywords XLA/jax runtime errors carry when the device, a
+# transfer, or the compile service hiccuped (vs. genuine program errors like
 # INVALID_ARGUMENT shape mismatches)
 _TRANSIENT_XLA_MARKERS = (
     "RESOURCE_EXHAUSTED", "UNAVAILABLE", "DEADLINE_EXCEEDED", "ABORTED",

@@ -26,8 +26,9 @@ once-per-machine) event:
    time on a background thread, off the serving critical path.
 
 4. **Persistent compile artifacts** — :func:`enable_persistent_cache`
-   (env ``ALINK_COMPILE_CACHE_DIR``) wires jax's persistent compilation
-   cache under the ProgramCache so executables survive process death: a
+   wires jax's persistent compilation cache under the ProgramCache (at
+   ``JAX_COMPILATION_CACHE_DIR`` when the caller set it, else at a fixed
+   directory inside the checkout) so executables survive process death: a
    fresh process pays trace + deserialize (``jit.persist_hit``) instead of
    a backend compile, corrupt entries fall back to a fresh compile
    (``jit.persist_error``), and the on-disk footprint is LRU-bounded
@@ -394,20 +395,23 @@ def load_shape_profile(path: Optional[str] = None) -> List[Tuple[str, list]]:
 # (corrupt entry, unwritable dir, version skew) falls back to a fresh
 # backend compile.
 
-_PERSIST_DIR_ENV = "ALINK_COMPILE_CACHE_DIR"
-_PERSIST_LEGACY_DIR_ENV = "ALINK_COMPILATION_CACHE_DIR"  # pre-PR-11 name
+_JAX_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 _PERSIST_CAP_ENV = "ALINK_COMPILE_CACHE_MAX_BYTES"
 _DEFAULT_PERSIST_CAP = 2 * 1024 ** 3   # on-disk LRU bound (2 GiB)
+# env defaults a pre-jax enable writes so that every compile persists, small
+# per-op programs included (jax's own floor is 1 s of compile time)
+_JAX_TUNING_ENV = {"JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0.0",
+                   "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1"}
 
 _persist_lock = threading.Lock()
 _persist: Dict[str, Any] = {"enabled": False, "dir": None, "hooked": False,
-                            "configured": False, "explicit": True,
-                            "wrote_env": {}}
+                            "configured": False, "wrote_env": set()}
 
 
 def persist_cap_bytes() -> int:
     """On-disk size bound for the persistent cache (env
-    ``ALINK_COMPILE_CACHE_MAX_BYTES``, 0 = unbounded)."""
+    ``ALINK_COMPILE_CACHE_MAX_BYTES``, 0 = unbounded), applied by
+    :func:`prune_persistent_cache` each time a process enables the cache."""
     return env_int(_PERSIST_CAP_ENV, _DEFAULT_PERSIST_CAP)
 
 
@@ -418,29 +422,38 @@ def compile_cache_dir() -> Optional[str]:
         return _persist["dir"] if _persist["enabled"] else None
 
 
-def _resolve_persist_dir(cache_dir: Optional[str]
-                         ) -> Tuple[Optional[str], bool]:
-    """Resolve the cache dir: explicit arg > ``ALINK_COMPILE_CACHE_DIR`` >
-    the legacy ``ALINK_COMPILATION_CACHE_DIR`` > (off-CPU only) the
-    per-user default. An exported-but-blank knob is an explicit OFF.
-    Returns ``(dir, explicit)`` — ``(None, _)`` when persistence should
-    stay disabled; ``explicit`` is False only for the fallback default,
-    which must YIELD to a cache dir the user configured on jax directly
-    (``JAX_COMPILATION_CACHE_DIR``) instead of clobbering it."""
+def default_cache_dir() -> str:
+    """The in-checkout cache directory: ``<checkout>/.jax_cache``, derived
+    from this package's own location so that every process of a checkout —
+    fleet workers, a second run of the same command — resolves the same
+    path (the path is part of what makes a cache reusable; a temp name, pid
+    or time would never hit). Git-ignored."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".jax_cache")
+
+
+def _resolve_persist_dir(cache_dir: Optional[str]) -> Optional[str]:
+    """Where the cache lives, or None for off. The cache is placed from
+    outside: ``JAX_COMPILATION_CACHE_DIR``, when set, IS the cache and this
+    module configures no other directory (an explicit ``cache_dir`` that
+    disagrees is an error, not an override). Unset: the explicit argument
+    (tests and drills), else :func:`default_cache_dir` — except under
+    ``JAX_PLATFORMS=cpu``, where persistence stays off by default (XLA:CPU
+    AOT entries are machine-feature-pinned, and the CPU test suite counts
+    traces and compiles)."""
+    placed = env_str(_JAX_DIR_ENV)
+    if placed is not None:
+        placed = placed.strip()
+        if cache_dir and os.path.abspath(cache_dir) != os.path.abspath(placed):
+            raise ValueError(
+                f"{_JAX_DIR_ENV}={placed!r} places the compile cache; "
+                f"refusing to configure a second one at {cache_dir!r}")
+        return placed
     if cache_dir is not None:
-        return (cache_dir or None), True
-    for name in (_PERSIST_DIR_ENV, _PERSIST_LEGACY_DIR_ENV):
-        raw = env_raw(name)  # blank-but-exported must read as explicit OFF
-        if raw is not None:
-            return (raw.strip() or None), True
-    # no knob set: default ON only off-CPU. XLA:CPU AOT entries are
-    # machine-feature-pinned and reload with SIGILL-risk warnings in
-    # heterogeneous fleets; the win this defaults for is the real TPU
-    # chip, where compiles cost 20-40s. CPU users opt in via the knob.
+        return cache_dir or None
     if (env_str("JAX_PLATFORMS", "") or "").strip() == "cpu":
-        return None, False
-    return os.path.join(os.path.expanduser("~"), ".cache", "alink_tpu",
-                        "xla_cache"), False
+        return None
+    return default_cache_dir()
 
 
 def _counted_cache_io(fn):
@@ -462,178 +475,127 @@ def _counted_cache_io(fn):
 def _install_persist_hooks() -> bool:
     """Counter plumbing: ``jit.persist_hit`` / ``jit.persist_miss`` /
     ``jit.persist_saved_s`` from jax's monitoring events,
-    ``jit.persist_error`` from wrapped cache IO. Returns True when the
-    hooks should be considered installed (callers record that under
-    ``_persist_lock`` — including after a failure, so a jax without these
-    internals is probed exactly once)."""
+    ``jit.persist_error`` from wrapped cache IO (jax 0.9 internals).
+    Returns True; callers record that under ``_persist_lock``."""
     if _persist["hooked"]:
         return True
-    try:
-        from jax._src import monitoring
+    from jax._src import compilation_cache as _cc
+    from jax._src import monitoring
 
-        def _on_event(event: str, **kwargs) -> None:
-            if event == "/jax/compilation_cache/cache_hits":
-                metrics.incr("jit.persist_hit")
-            elif event == "/jax/compilation_cache/cache_misses":
-                metrics.incr("jit.persist_miss")
+    def _on_event(event: str, **kwargs) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            metrics.incr("jit.persist_hit")
+        elif event == "/jax/compilation_cache/cache_misses":
+            metrics.incr("jit.persist_miss")
 
-        monitoring.register_event_listener(_on_event)
-        try:
-            def _on_duration(event: str, duration: float, **kwargs) -> None:
-                # backend-compile seconds each persist hit skipped (jax
-                # stores whole seconds, so sub-second CPU compiles read 0 —
-                # the number this exists for is the 20-40s TPU compile)
-                if event == "/jax/compilation_cache/compile_time_saved_sec":
-                    metrics.add_time("jit.persist_saved_s",
-                                     max(float(duration), 0.0))
+    def _on_duration(event: str, duration: float, **kwargs) -> None:
+        # backend-compile seconds each persist hit skipped (jax stores
+        # whole seconds, so sub-second CPU compiles read 0)
+        if event == "/jax/compilation_cache/compile_time_saved_sec":
+            metrics.add_time("jit.persist_saved_s", max(float(duration), 0.0))
 
-            monitoring.register_event_duration_secs_listener(_on_duration)
-        except Exception:
-            metrics.incr("jit.persist_hook_errors")
-        from jax._src import compilation_cache as _cc
-
-        for name in ("get_executable_and_time", "put_executable_and_time"):
-            fn = getattr(_cc, name, None)
-            if fn is not None and not getattr(fn, "_alink_counted", False):
-                setattr(_cc, name, _counted_cache_io(fn))
-    except Exception:
-        # hit/miss accounting is observability, not correctness: a jax
-        # without these internals still persists fine, just uncounted
-        metrics.incr("jit.persist_hook_errors")
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    for name in ("get_executable_and_time", "put_executable_and_time"):
+        fn = getattr(_cc, name)
+        if not getattr(fn, "_alink_counted", False):
+            setattr(_cc, name, _counted_cache_io(fn))
     return True
 
 
-def _apply_jax_persist_config(d: str, explicit: bool = True) -> str:
-    """Point jax's persistent cache at ``d`` and return the dir actually in
-    effect. A non-``explicit`` (fallback-default) dir yields to a cache dir
-    the user already configured on jax (``JAX_COMPILATION_CACHE_DIR`` /
-    direct config) — e.g. a pre-warmed shared cache — instead of silently
-    clobbering it with the alink default."""
+def _apply_jax_persist_config(d: str) -> None:
+    """Point the imported jax at ``d`` (a no-op when jax already read that
+    directory from ``JAX_COMPILATION_CACHE_DIR`` at import) and make every
+    compile persist."""
     import jax
+    from jax._src import compilation_cache as _cc
 
-    current = getattr(jax.config, "jax_compilation_cache_dir", None)
-    if not explicit and current:
-        d = current
-    elif current != d:
+    if getattr(jax.config, "jax_compilation_cache_dir", None) != d:
         jax.config.update("jax_compilation_cache_dir", d)
-        try:
-            # jax latches its cache-used decision on the first compile of
-            # the task; a process that already compiled before this enable
-            # (tests, late re-points) must re-evaluate or the new dir is
-            # ignored
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:
-            metrics.incr("jit.persist_hook_errors")
+        # jax latches its cache-used decision on the first compile of the
+        # task; a process that already compiled before this enable (tests,
+        # late re-points) must re-evaluate or the new dir is ignored
+        _cc.reset_cache()
     # cache everything: the default 1s floor skips exactly the small
     # per-op programs this framework compiles most often. A user-exported
     # JAX_PERSISTENT_CACHE_* knob wins (jax consumed it at import); the
-    # env vars our own pre-jax enable wrote hold these same values, so
-    # skipping the update there is equivalent.
-    if env_raw("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS") is None:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    if env_raw("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES") is None:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    cap = persist_cap_bytes()
-    if cap > 0:
-        try:
-            # jax's own LRU eviction (by entry atime) enforces the cap on
-            # every write; prune_persistent_cache() below additionally
-            # bounds a pre-existing oversized dir at enable time
-            jax.config.update("jax_compilation_cache_max_size", cap)
-        except Exception:
-            metrics.incr("jit.persist_hook_errors")
-    return d
+    # ones this module exported itself hold these same values.
+    ours = _persist["wrote_env"]
+    for name, value in (
+            ("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", 0.0),
+            ("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", -1)):
+        if name in ours or env_raw(name) is None:
+            jax.config.update(name.lower(), value)
+    # jax's own eviction (jax_compilation_cache_max_size) stays off: once on,
+    # every write scans the directory for each entry's "-atime" companion
+    # and fails on the first entry written without one — by a process that
+    # compiled before this config landed, or by any other jax that shares a
+    # directory placed from outside (seen on the chip: every put of a
+    # process raised FileNotFoundError). prune_persistent_cache() bounds the
+    # directory at enable time and copes with both kinds of entry.
 
 
 def enable_persistent_cache(cache_dir: Optional[str] = None) -> Optional[str]:
     """Wire jax's persistent compilation cache underneath the ProgramCache
     so compiled programs survive process death: a fresh process pays trace +
-    deserialize instead of trace + backend-compile (BASELINE #1: 50.2s cold
-    vs 0.35s warm on kmeans_iris).
+    deserialize instead of trace + backend-compile.
 
-    Called at package import. Directory resolution: explicit ``cache_dir``
-    argument > ``ALINK_COMPILE_CACHE_DIR`` (blank = explicitly off) > the
-    legacy ``ALINK_COMPILATION_CACHE_DIR`` > a per-user default on
-    non-CPU platforms. When jax is not imported yet this only sets the
-    ``JAX_*`` env vars (jax reads them at init) so ``import alink_tpu``
-    stays jax-free; the config + counter hooks are finalized lazily on the
-    first ``cached_jit`` miss. Returns the active dir, or None when
-    persistence stays off — in which case process behavior is byte-for-byte
-    unchanged. The fallback default (no knob anywhere) yields to a cache
-    dir the user configured on jax directly."""
-    d, explicit = _resolve_persist_dir(cache_dir)
+    Called at package import. The directory is resolved by
+    :func:`_resolve_persist_dir`: ``JAX_COMPILATION_CACHE_DIR`` if the
+    caller set it (then nothing else is ever configured), else the explicit
+    ``cache_dir``, else the in-checkout default (off under
+    ``JAX_PLATFORMS=cpu``). When jax is not imported yet this only exports
+    the directory and two tuning defaults as the ``JAX_*`` env vars jax
+    reads at import — which child processes inherit, so workers share the
+    cache — and ``import alink_tpu`` stays jax-free; the config + counter
+    hooks are finalized lazily on the first ``cached_jit`` miss. Returns
+    the active dir, or None when persistence stays off — in which case
+    process behavior is byte-for-byte unchanged."""
+    d = _resolve_persist_dir(cache_dir)
     if d is None:
         return None
     try:
         os.makedirs(d, exist_ok=True)
-        with _persist_lock:
-            if "jax" in sys.modules:
-                d = _apply_jax_persist_config(d, explicit)
-                _persist["hooked"] = _install_persist_hooks()
-                _persist["configured"] = True
-                _persist["explicit"] = explicit
-            else:
-                # pre-jax: hand the config to jax via env vars it reads at
-                # init. Precedence: an explicit re-point overrides the dir
-                # a user exported (that is what "explicit" means), but the
-                # min_* tuning knobs and — for the fallback default — the
-                # dir itself always YIELD to user-exported values. Every
-                # write records the prior value so disable can restore it.
-                wrote: Dict[str, Optional[str]] = _persist["wrote_env"]
-
-                def _set(name: str, value: str, force: bool) -> None:
-                    prior = env_raw(name)
-                    if force or prior is None:
-                        wrote.setdefault(name, prior)
-                        os.environ[name] = value
-
-                _set("JAX_COMPILATION_CACHE_DIR", d,
-                     force=cache_dir is not None)
-                d = env_raw("JAX_COMPILATION_CACHE_DIR") or d
-                _set("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.0",
-                     force=False)
-                _set("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1",
-                     force=False)
-                _persist["configured"] = False
-                _persist["explicit"] = explicit
-            _persist["enabled"] = True
-            _persist["dir"] = d
-        prune_persistent_cache()
-        return d
-    except Exception:  # pragma: no cover — unwritable dir, exotic platform
+    except OSError:  # read-only checkout: run uncached rather than not at all
         metrics.incr("jit.persist_hook_errors")
         return None
+    with _persist_lock:
+        for name, value in {_JAX_DIR_ENV: d, **_JAX_TUNING_ENV}.items():
+            if env_raw(name) is None:
+                # recorded so disable can take back exactly what it wrote
+                _persist["wrote_env"].add(name)
+                os.environ[name] = value
+        if "jax" in sys.modules:
+            _apply_jax_persist_config(d)
+            _persist["hooked"] = _install_persist_hooks()
+            _persist["configured"] = True
+        else:
+            _persist["configured"] = False
+        _persist["enabled"] = True
+        _persist["dir"] = d
+    prune_persistent_cache()
+    return d
 
 
 def disable_persistent_cache() -> None:
     """Turn persistence back off (tests, operators draining a bad disk).
     In-flight executables are unaffected; the next compile goes straight to
-    the backend. Env vars a pre-jax enable wrote are restored to their
-    prior values (user-exported ``JAX_*`` knobs this module never touched
-    stay untouched) — otherwise a jax that initializes later would read
-    our leftovers and silently re-activate the cache this call turned
-    off."""
+    the backend. Env vars an enable wrote are removed again (``JAX_*``
+    values the caller exported stay untouched) — otherwise a jax that
+    initializes later, or a child process, would read our leftovers and
+    silently re-activate the cache this call turned off."""
     with _persist_lock:
-        wrote: Dict[str, Optional[str]] = _persist["wrote_env"]
+        wrote = _persist["wrote_env"]
         _persist.update(enabled=False, dir=None, configured=False,
-                        wrote_env={})
-    for name, prior in wrote.items():
-        if prior is None:
-            os.environ.pop(name, None)
-        else:
-            os.environ[name] = prior
+                        wrote_env=set())
+    for name in wrote:
+        os.environ.pop(name, None)
     if "jax" in sys.modules:
-        try:
-            import jax
+        import jax
+        from jax._src import compilation_cache as _cc
 
-            jax.config.update("jax_compilation_cache_dir", None)
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:
-            metrics.incr("jit.persist_hook_errors")
+        jax.config.update("jax_compilation_cache_dir", None)
+        _cc.reset_cache()
 
 
 def _ensure_persist_ready() -> None:
@@ -647,13 +609,9 @@ def _ensure_persist_ready() -> None:
     with _persist_lock:
         if _persist["configured"] or not _persist["enabled"]:
             return
-        try:
-            _persist["dir"] = _apply_jax_persist_config(
-                _persist["dir"], bool(_persist.get("explicit", True)))
-            _persist["hooked"] = _install_persist_hooks()
-        except Exception:
-            metrics.incr("jit.persist_hook_errors")
-        _persist["configured"] = True  # do not retry per miss
+        _apply_jax_persist_config(_persist["dir"])
+        _persist["hooked"] = _install_persist_hooks()
+        _persist["configured"] = True
 
 
 def _persist_entries(d: str) -> List[Tuple[str, float, int]]:

@@ -25,9 +25,9 @@ fp32 tolerance (atol=1e-5), not bit-equality (tests/test_kernels.py).
 Knob-off the caller compiles the untouched XLA path — byte-identical to
 pre-kernel builds.
 
-Off-TPU the kernel runs in interpret mode, so the 8-virtual-device CPU
-mesh validates the exact same program. Gated by ``ALINK_SGNS_PALLAS``
-through the shared registry gate (native/kernels.py).
+The tests run the same program under the Pallas interpreter on the
+8-virtual-device CPU mesh. Gated by ``ALINK_SGNS_PALLAS`` through the
+shared registry gate (native/kernels.py).
 """
 
 from __future__ import annotations
@@ -73,7 +73,10 @@ def sgns_block_grads(v, u_pos, u_neg, *, interpret: bool = False):
     negs = u_neg.shape[1]
     v_p = _pad_axis(_pad_axis(v, _BB, 0), _LANES, 1)
     up_p = _pad_axis(_pad_axis(u_pos, _BB, 0), _LANES, 1)
-    un_p = _pad_axis(_pad_axis(u_neg, _BB, 0), _LANES, 2)
+    # negatives ride as the LEADING axis, (negs, B, D): Mosaic wants the
+    # last two block dims (8, 128)-aligned or the array's own, and one
+    # negative's 8-row slice is then a plain (8, D) trailing slab
+    un_p = _pad_axis(_pad_axis(u_neg.transpose(1, 0, 2), _BB, 1), _LANES, 2)
     b_pad, d_pad = v_p.shape
 
     grid = (b_pad // _BB, negs)   # negatives grid-minor: grad_v block
@@ -82,9 +85,9 @@ def sgns_block_grads(v, u_pos, u_neg, *, interpret: bool = False):
     def kernel(v_ref, up_ref, un_ref, gv_ref, gup_ref, gun_ref):
         n = pl.program_id(1)
         vb = v_ref[:]                                   # (_BB, D)
-        un = un_ref[:][:, 0, :]                         # (_BB, D)
+        un = un_ref[0]                                  # (_BB, D)
         g_n = jax.nn.sigmoid((vb * un).sum(-1, keepdims=True))  # (_BB, 1)
-        gun_ref[:] = (g_n * vb)[:, None, :]
+        gun_ref[0] = g_n * vb
 
         @pl.when(n == 0)
         def _first():
@@ -103,21 +106,22 @@ def sgns_block_grads(v, u_pos, u_neg, *, interpret: bool = False):
         in_specs=[
             pl.BlockSpec((_BB, d_pad), lambda r, n: (r, 0)),
             pl.BlockSpec((_BB, d_pad), lambda r, n: (r, 0)),
-            pl.BlockSpec((_BB, 1, d_pad), lambda r, n: (r, n, 0)),
+            pl.BlockSpec((1, _BB, d_pad), lambda r, n: (n, r, 0)),
         ],
         out_specs=[
             pl.BlockSpec((_BB, d_pad), lambda r, n: (r, 0)),
             pl.BlockSpec((_BB, d_pad), lambda r, n: (r, 0)),
-            pl.BlockSpec((_BB, 1, d_pad), lambda r, n: (r, n, 0)),
+            pl.BlockSpec((1, _BB, d_pad), lambda r, n: (n, r, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b_pad, d_pad), jnp.float32),
             jax.ShapeDtypeStruct((b_pad, d_pad), jnp.float32),
-            jax.ShapeDtypeStruct((b_pad, negs, d_pad), jnp.float32),
+            jax.ShapeDtypeStruct((negs, b_pad, d_pad), jnp.float32),
         ],
         interpret=interpret,
     )(v_p, up_p, un_p)
     grad_v = gv[:B, :D]
     grad_u = jnp.concatenate(
-        [gup[:B, :D], gun[:B, :, :D].reshape(B * negs, D)])
+        [gup[:B, :D],
+         gun[:, :B, :D].transpose(1, 0, 2).reshape(B * negs, D)])
     return grad_v, grad_u

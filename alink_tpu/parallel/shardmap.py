@@ -1,30 +1,15 @@
-"""Version-compat layer for ``shard_map`` — the one sanctioned import.
-
-The manual-sharding API moved twice across the JAX versions this repo spans:
-
-- current JAX exposes ``jax.shard_map`` with a ``check_vma`` kwarg (the
-  varying-manual-axes type checker) and ``axis_names`` (the set of mesh axes
-  the kernel is manual over; the rest stay in GSPMD auto mode);
-- JAX 0.4.x (this container) only has
-  ``jax.experimental.shard_map.shard_map`` with the older ``check_rep``
-  replication checker and the complementary ``auto`` kwarg (the axes that
-  are NOT manual).
+"""The one sanctioned import of ``jax.shard_map`` and its manual-mode helpers.
 
 Every kernel in the tree imports :func:`shard_map` from here
 (``from alink_tpu.parallel.shardmap import shard_map``) instead of touching
-``jax.shard_map`` directly — alink-lint rule ALK002 bans direct use. The
-shim normalizes the kwarg differences:
+``jax.shard_map`` directly — alink-lint rule ALK002 bans direct use, so an
+API move in jax is a one-file change. Written for the installed jax (0.9):
+``jax.shard_map`` with ``check_vma`` (the varying-manual-axes type checker)
+and ``axis_names`` (the mesh axes the kernel is manual over; the rest stay
+in GSPMD auto mode).
 
-- ``check_vma``/``check_rep`` are aliases; on the legacy path the rep
-  checker is force-disabled (it predates vma typing and rejects valid
-  kernels written for ``check_vma`` — e.g. accumulators initialised via
-  :func:`pvary`), numerics are unaffected;
-- ``axis_names`` ⇄ ``auto`` are complements over ``mesh.axis_names``.
-
-Also shims the two small manual-mode helpers that moved in the same API
-cycle: :func:`pvary` (``jax.lax.pcast(..., to="varying")``; identity on
-legacy JAX where replication is untyped) and :func:`axis_size` (static mesh
-axis size inside a manual kernel).
+jax is imported inside the functions: this package defers jax imports so
+that supervisors and linters can import it without touching a runtime.
 """
 
 from __future__ import annotations
@@ -32,104 +17,27 @@ from __future__ import annotations
 from typing import Any, Optional
 
 
-def _resolve():
+def shard_map(f, *, mesh, in_specs, out_specs,
+              check_vma: bool = True,
+              axis_names: Optional[Any] = None):
+    """``jax.shard_map``; ``axis_names=None`` means manual over every mesh
+    axis."""
     import jax
 
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, "jax.shard_map"
-    from jax.experimental import shard_map as _legacy
-
-    return _legacy.shard_map, "jax.experimental.shard_map.shard_map"
-
-
-_IMPL = None
-IMPL_SOURCE: Optional[str] = None
-_IMPL_PARAMS: Optional[frozenset] = None
-
-
-def _impl():
-    """Resolve lazily (jax imports are deferred across this package)."""
-    global _IMPL, IMPL_SOURCE, _IMPL_PARAMS
-    if _IMPL is None:
-        import inspect
-
-        _IMPL, IMPL_SOURCE = _resolve()
-        _IMPL_PARAMS = frozenset(inspect.signature(_IMPL).parameters)
-    return _IMPL
-
-
-def impl_source() -> str:
-    """Which underlying API the shim resolved to (for tests/docs)."""
-    _impl()
-    return IMPL_SOURCE or ""
-
-
-def shard_map(f, *, mesh, in_specs, out_specs,
-              check_vma: Optional[bool] = None,
-              check_rep: Optional[bool] = None,
-              axis_names: Optional[Any] = None,
-              auto: Optional[Any] = None):
-    """``jax.shard_map`` with version-normalized kwargs.
-
-    ``check_vma``/``check_rep`` name the same flag across versions; pass
-    either. ``axis_names`` (manual axes, current API) and ``auto``
-    (non-manual axes, legacy API) are complements — pass whichever the call
-    site was written for and the shim derives the other.
-    """
-    impl = _impl()
-    params = _IMPL_PARAMS or frozenset()
-    check = check_vma if check_vma is not None else check_rep
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-
-    if "check_vma" in params:
-        if check is not None:
-            kwargs["check_vma"] = check
-    elif "check_rep" in params:
-        # the legacy rep checker predates vma typing and rejects valid
-        # kernels written for check_vma; disable it on this path
-        kwargs["check_rep"] = False
-
-    manual = set(axis_names) if axis_names is not None else None
-    if manual is None and auto is not None:
-        manual = set(mesh.axis_names) - set(auto)
-    if manual is not None and manual != set(mesh.axis_names):
-        if "axis_names" in params:
-            kwargs["axis_names"] = manual
-        # legacy path: the experimental `auto` mode rejects most real
-        # kernels (NotImplementedError on collectives/loops), so run
-        # full-manual instead — in_specs that omit the auto axes replicate
-        # over them, which preserves semantics at the cost of redundant
-        # compute on those axes (acceptable for the in-container CPU mesh;
-        # the current-API path keeps true partial-manual on real pods)
-    return impl(f, **kwargs)
+    kwargs = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)
 
 
 def pvary(x, axis_name):
-    """Mark ``x`` as varying over ``axis_name`` for the vma checker.
-
-    Identity on legacy JAX (replication there is untyped; the shim also
-    disables ``check_rep``, so no annotation is needed).
-    """
+    """Mark ``x`` as varying over ``axis_name`` for the vma checker."""
     import jax
 
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axis_name, to="varying")
-    fn = getattr(jax.lax, "pvary", None)
-    if fn is not None:
-        return fn(x, axis_name)
-    return x
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def axis_size(axis_name) -> int:
     """Static size of a bound mesh axis inside a manual kernel."""
     import jax
 
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    from jax.core import axis_frame
-
-    frame = axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
+    return jax.lax.axis_size(axis_name)

@@ -21,6 +21,16 @@ serving when individual replicas die:
   the ``.ak.warmup.json`` sidecar — never from live traffic — so the
   zero-trace steady-state contract holds across replica generations
   (plan rule ALK110 refuses fleet loads that would break it).
+- **device**: a chip belongs to one process. Every replica announces the
+  platform jax gave it in its ``ready`` frame; one that did not get the
+  platform it was meant to have — the supervisor's own, when the
+  supervisor has started a jax backend, else the one the first ready
+  replica reported — reports ``failed`` with the reason instead, is never
+  routed to and is not respawned, and :meth:`ServingFleet.start` raises
+  with that reason. A supervisor that has trained on the one chip of a
+  host therefore cannot start chip replicas: stay off jax in the
+  supervisor, or hand the workers ``JAX_PLATFORMS=cpu`` through
+  ``worker_env`` to serve from the CPU on purpose.
 - **drain**: decommission stops routing, lets the worker finish every
   accepted request (``server.close()`` drains its queues), then exits.
 - **hot-swap**: :meth:`ServingFleet.load` broadcasts one committed model
@@ -182,7 +192,7 @@ class _Replica:
     __slots__ = ("rid", "gen", "proc", "log_fh", "state", "client",
                  "data_port", "last_hb", "hb_stats", "ready_info",
                  "ready_trace", "trace_delta", "synced", "spawned_at",
-                 "conn")
+                 "conn", "device", "fail_reason")
 
     def __init__(self, rid: str, gen: int, proc: subprocess.Popen,
                  log_fh=None):
@@ -201,6 +211,8 @@ class _Replica:
         self.synced: Dict[str, int] = {}
         self.spawned_at = time.monotonic()
         self.conn: Optional[socket.socket] = None
+        self.device: Optional[Dict[str, Any]] = None
+        self.fail_reason: Optional[str] = None
 
 
 def _validate_hb_stats(stats: Any) -> Dict[str, Any]:
@@ -258,6 +270,7 @@ class ServingFleet:
         self._started = False
         self._closing = False
         self._control_sock: Optional[socket.socket] = None
+        self._platform: Optional[str] = None   # see _expected_platform
         self._control_port: Optional[int] = None
         self._threads: List[threading.Thread] = []
         self._frontend = FleetFrontend(
@@ -369,6 +382,7 @@ class ServingFleet:
             # a respawned replica's boot loads are recovery loads: an
             # unproven quantized policy escalates ALK111 to error there
             "recovery": bool(respawn),
+            "platform": self._expected_platform(),
         }
         env = scrub_cluster_env(dict(os.environ))
         env.update(self._cfg.worker_env or {})
@@ -408,8 +422,14 @@ class ServingFleet:
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
             with self._lock:
-                states = {rid: self._replicas[rid].state
-                          for rid in rids if rid in self._replicas}
+                reps = [self._replicas[rid] for rid in rids
+                        if rid in self._replicas]
+            states = {rep.rid: rep.state for rep in reps}
+            refused = {rep.rid: rep.fail_reason for rep in reps
+                       if rep.fail_reason}
+            if refused:
+                raise AkIllegalStateException(
+                    f"fleet replicas refused to serve: {refused}")
             if states and all(s == "ready" for s in states.values()):
                 return
             time.sleep(0.05)
@@ -478,6 +498,20 @@ class ServingFleet:
             port = msg.get("data_port")
             if not isinstance(port, int):
                 raise ValueError("ready without a data port")
+            rep.device = msg.get("device")
+            got = (rep.device or {}).get("platform")
+            with self._lock:
+                if self._platform is None:
+                    self._platform = got   # first ready replica decides
+                want = self._platform
+            if got != want:
+                # two workers raced for one chip from a jax-free
+                # supervisor: the loser fell back to another platform
+                self._refuse_replica(
+                    rep, f"replica {rep.rid} serves from {got!r} but the "
+                         f"fleet serves from {want!r}")
+                rep.proc.kill()
+                return
             rep.client = ReplicaClient(rep.rid, self._cfg.bind_host, port)
             rep.data_port = port
             rep.ready_info = msg.get("loads")
@@ -491,6 +525,8 @@ class ServingFleet:
                     rep.state = "ready"
             logger.info("fleet replica %s (gen %d, pid %d) ready",
                         rep.rid, rep.gen, rep.proc.pid)
+        elif t == "failed":
+            self._refuse_replica(rep, str(msg.get("reason")))
         elif t == "hb":
             stats = _validate_hb_stats(msg.get("stats"))
             rep.hb_stats = stats
@@ -538,6 +574,31 @@ class ServingFleet:
                 metrics.incr("fleet.bad_telemetry")
                 logger.warning("dropped span batch from %s: %s",
                                rep.rid, e)
+
+    def _expected_platform(self) -> Optional[str]:
+        """The platform replicas are meant to serve from, or None while
+        nothing has decided it: this process's own when it has started a
+        jax backend (what its models were trained and checked on — asking
+        then costs nothing, and a supervisor that has not started one is
+        not made to), else whatever the first ready replica reported."""
+        with self._lock:
+            if self._platform is None:
+                from ..native.kernels import backend_started
+
+                if backend_started():
+                    import jax
+
+                    self._platform = jax.default_backend()
+            return self._platform
+
+    def _refuse_replica(self, rep: _Replica, reason: str) -> None:
+        """Record why a replica will not serve. It is never routed to and
+        never respawned: the same process on the same host would be
+        refused the same device again."""
+        rep.fail_reason = reason
+        metrics.incr("fleet.replica_refused")
+        logger.warning("fleet replica %s (gen %d) not ready: %s",
+                       rep.rid, rep.gen, reason)
 
     def _mark_unhealthy(self, rep: _Replica, why: str) -> None:
         with self._lock:
@@ -604,7 +665,7 @@ class ServingFleet:
         logger.warning("fleet replica %s (gen %d) died with rc=%s",
                        rep.rid, rep.gen, rep.proc.returncode)
         if (self._closing or was == "draining" or not current
-                or not self._cfg.respawn):
+                or not self._cfg.respawn or rep.fail_reason):
             return
         metrics.incr("fleet.respawns")
         self._spawn(rep.rid, respawn=True)
@@ -926,6 +987,8 @@ class ServingFleet:
                 if rep.last_hb is not None else None,
                 "trace_delta": rep.trace_delta,
                 "synced": dict(rep.synced),
+                "device": rep.device,
+                "fail_reason": rep.fail_reason,
                 "loads": rep.ready_info,
                 "queued": hb.get("queued"),
                 "accepted": hb.get("accepted"),
@@ -1066,6 +1129,7 @@ class _WorkerRuntime:
         self.server = ModelServer(self.serving_cfg)
         self.models: List[Dict[str, Any]] = cfg.get("models") or []
         self.recovery: bool = bool(cfg.get("recovery"))
+        self.platform: Optional[str] = cfg.get("platform")
         self._synced: Dict[str, int] = {}
         self._synced_lock = threading.Lock()
         self._hung = threading.Event()
@@ -1237,12 +1301,46 @@ class _WorkerRuntime:
         }
 
     # -- main ----------------------------------------------------------------
+    def _say_hello(self) -> None:
+        self._csock = socket.create_connection(self.control_addr,
+                                               timeout=10.0)
+        self._send_line({"t": "hello", "rid": self.rid, "gen": self.gen,
+                         "token": self.token, "pid": os.getpid()})
+
+    def _claim_device(self) -> Dict[str, Any]:
+        """Start this process's jax backend and say what it got; raise if
+        that is not the platform the supervisor meant this replica to
+        have. (With ``JAX_PLATFORMS`` unset jax itself carries on on the
+        CPU when the chip is held by another process.)"""
+        import jax
+
+        devs = jax.devices()
+        got = {"platform": devs[0].platform,
+               "device_kind": devs[0].device_kind,
+               "device_count": len(devs)}
+        if self.platform and got["platform"] != self.platform:
+            raise AkIllegalStateException(
+                f"replica {self.rid} was meant to serve from "
+                f"{self.platform!r} but jax gave it {got['platform']!r} "
+                f"({got['device_kind']}): a chip belongs to one process "
+                f"at a time")
+        return got
+
     def run(self) -> int:
         lsock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         lsock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         lsock.bind((self.control_addr[0], 0))
         lsock.listen(64)
         data_port = lsock.getsockname()[1]
+        try:
+            device = self._claim_device()
+        except Exception as e:
+            # the process boundary: report why this replica will not
+            # serve, then leave — never serve from a device nobody chose
+            self._say_hello()
+            self._send_line({"t": "failed",
+                             "reason": f"{type(e).__name__}: {e}"})
+            return 3
         threading.Thread(target=self._accept_loop, args=(lsock,),
                          daemon=True).start()
         loads = []
@@ -1263,18 +1361,16 @@ class _WorkerRuntime:
                 metrics.incr("fleet.worker_load_errors")
                 loads.append({"model": m["name"], "ok": False,
                               "error": str(e)})
-        self._csock = socket.create_connection(self.control_addr,
-                                               timeout=10.0)
         # everything after this line must add ZERO traces: the baseline
         # the supervisor pins trace_delta == 0 against
         self._trace_base = metrics.counter("jit.trace")
-        self._send_line({"t": "hello", "rid": self.rid, "gen": self.gen,
-                         "token": self.token, "pid": os.getpid()})
+        self._say_hello()
         with self._synced_lock:
             synced = dict(self._synced)
         self._send_line({"t": "ready", "data_port": data_port,
                          "loads": loads, "jit_trace": self._trace_base,
-                         "synced": synced, "pid": os.getpid()})
+                         "synced": synced, "pid": os.getpid(),
+                         "device": device})
         while not self._draining.is_set():
             time.sleep(self.heartbeat_s)
             if self._hung.is_set() or self._refuse.is_set():

@@ -14,8 +14,8 @@ sidecar next to the ``.ak`` model (``<model>.ak.warmup.json``):
   (``common/jitcache.seen_warmup_specs`` format — consumable by
   ``alink_tpu.warmup()`` for non-serving AOT warm paths).
 
-Paired with the persistent compile cache (``ALINK_COMPILE_CACHE_DIR``,
-``common/jitcache.py``), a replica that has NEVER compiled reaches
+Paired with the persistent compile cache (``common/jitcache.py``, placed
+by ``JAX_COMPILATION_CACHE_DIR``), a replica that has NEVER compiled reaches
 zero-trace readiness from disk artifacts alone: the sidecar replays the
 warmup shapes, the compile cache serves each executable. Predictions are
 bit-identical either way — warmup only populates caches, it never changes

@@ -9,8 +9,8 @@ blocks with one-hot × value products — the scatter becomes a streaming
 compare+matvec, revisiting the same output block across the row grid
 (sequential TPU grid ⇒ safe accumulation).
 
-Off-TPU the kernel runs in interpret mode, so tests validate the exact same
-program on the 8-virtual-device CPU mesh.
+The tests run the same program under the Pallas interpreter on the
+8-virtual-device CPU mesh.
 
 Registered as ``tree.pallas_hist`` in the custom-kernel registry
 (``native/kernels.py``); the gate and interpret-mode switches are the

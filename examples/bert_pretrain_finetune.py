@@ -9,8 +9,8 @@
 4. report holdout accuracy on the held-out rows — the same split the
    BENCH ``bert_text_quality`` metric of record uses.
 
-Runs in a few minutes on CPU. Scale ``--epochs``/``--finetune-epochs`` up
-on an accelerator; ``bench.py`` runs the full-budget version.
+A CPU demo: runs in a few minutes at this size. ``bench.py`` runs the
+full-budget version.
 """
 
 import argparse

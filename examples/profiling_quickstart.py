@@ -102,6 +102,6 @@ print(f"perf gate, +20% slowdown:  {slow['verdict']} "
       f"(delta {slow['delta_pct']}%, gate {slow['gate_pct']}%)")
 assert same["verdict"] == "no-change" and slow["verdict"] == "regression"
 
-print("\ncompare two archived rounds with: "
-      "python bench.py --compare BENCH_r04.json BENCH_r05.json "
+print("\ncompare two saved rounds with: "
+      "python bench.py --compare OLD.json NEW.json "
       "(schema: docs/bench_schema.md)")

@@ -507,13 +507,12 @@ def test_lint_alk002_catches_experimental_bypasses(tmp_path):
 
 
 def test_lint_alk002_exempts_the_shim_itself(tmp_path):
-    """parallel/shardmap.py IS the sanctioned owner of the legacy import."""
+    """parallel/shardmap.py IS the sanctioned owner of the jax.shard_map
+    reference."""
     diags = _lint_src(tmp_path, "parallel/shardmap.py", """
-        from jax.experimental import shard_map as _legacy
-
         def f():
             import jax
-            return jax.experimental.shard_map.shard_map
+            return jax.shard_map
     """)
     assert diags == []
 

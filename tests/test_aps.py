@@ -290,19 +290,12 @@ def test_bucket_slack_env_knob(monkeypatch):
 
 
 def test_estimator_shard_map_fit_path_runs_in_container():
-    """Guardrail-expiry pin: tier-1 used to have to route around shard_map
-    fit paths (container JAX dropped ``jax.shard_map``, so estimator tests
-    were restricted to StandardScaler+VectorAssembler+NaiveBayes). The
-    compat shim retired that rule — a KMeans ``Pipeline.fit``, whose Lloyd
-    kernel is ``jax.jit(shard_map(...))``, must now run in-container
-    through whichever underlying API the shim resolved."""
+    """A KMeans ``Pipeline.fit``, whose Lloyd kernel is
+    ``jax.jit(shard_map(...))``, runs in-container through
+    ``parallel/shardmap.py``."""
     from alink_tpu.common.mtable import MTable
     from alink_tpu.operator.batch.base import TableSourceBatchOp
-    from alink_tpu.parallel.shardmap import impl_source
     from alink_tpu.pipeline import KMeans, Pipeline
-
-    assert impl_source() in ("jax.shard_map",
-                             "jax.experimental.shard_map.shard_map")
 
     rng = np.random.default_rng(9)
     blob = np.concatenate([rng.normal(-4, 0.3, size=(40, 2)),

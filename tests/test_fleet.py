@@ -486,6 +486,45 @@ def test_fleet_zero_trace_after_warmup(fleet2, serial_rows):
     assert all(r["trace_delta"] == 0 for r in summary["replicas"])
 
 
+def test_replicas_announce_the_device_they_serve_from(fleet2):
+    """Every ready frame names the platform jax gave the worker; the fleet
+    readout carries it, so no replica serves from a device unannounced."""
+    for r in fleet2.fleet_summary()["replicas"]:
+        assert r["device"]["platform"] == "cpu"     # this suite's backend
+        assert r["device"]["device_count"] >= 1 and r["device"]["device_kind"]
+        assert r["fail_reason"] is None
+
+
+def test_replica_refuses_to_serve_from_a_device_it_was_not_meant_to_have(
+        monkeypatch):
+    """A supervisor that trained on a chip holds it; its workers then get
+    the CPU from jax. Such a replica must report not-ready with the reason
+    (never serve from the CPU unannounced), start() must raise with that
+    reason instead of waiting out the ready timeout, and the refused
+    replica must not respawn-loop."""
+    fleet = ServingFleet(FleetConfig(replicas=1, heartbeat_s=0.2,
+                                     ready_timeout_s=120.0))
+    # stand in for a supervisor whose own backend is a TPU
+    monkeypatch.setattr(fleet, "_expected_platform", lambda: "tpu")
+    spawned0, refused0 = _counter("fleet.spawned"), \
+        _counter("fleet.replica_refused")
+    t0 = time.monotonic()
+    try:
+        with pytest.raises(Exception) as ei:
+            fleet.start()
+        assert "meant to serve from 'tpu'" in str(ei.value)
+        assert "jax gave it 'cpu'" in str(ei.value)
+        assert time.monotonic() - t0 < 60.0          # not the ready timeout
+        assert _counter("fleet.replica_refused") == refused0 + 1
+        assert _wait(lambda: fleet.replica_states() == {"r0": "dead"})
+        time.sleep(0.6)                               # a few monitor ticks
+        assert _counter("fleet.spawned") == spawned0 + 1   # no respawn
+        rep = fleet.fleet_summary()["replicas"][0]
+        assert rep["device"] is None and "tpu" in rep["fail_reason"]
+    finally:
+        fleet.stop()
+
+
 def test_fleet_load_requires_saved_path(fleet2):
     with pytest.raises(AkIllegalArgumentException):
         fleet2.load("bad", object())

@@ -1,14 +1,18 @@
 """Custom-kernel program tests (native/kernels.py registry + the fused SGNS
 and flash-attention Pallas kernels).
 
-Everything runs in Pallas interpret mode on the 8-virtual-device CPU mesh —
-the exact programs Mosaic compiles on TPU. Parity contracts follow the
-registry: pinned fp32 tolerance (atol=1e-5) where the kernel's reduction
-order differs from XLA's, byte-identity for the knob-off path.
+Numerics run in Pallas interpret mode (the root conftest asks for it) on the
+8-virtual-device CPU mesh; the Mosaic side is checked as far as a CPU can
+take it, by lowering each kernel for the ``tpu`` platform at the block
+shapes its caller uses. Parity contracts follow the registry: pinned fp32
+tolerance (atol=1e-5) where the kernel's reduction order differs from
+XLA's, byte-identity for the knob-off path.
 """
 
 import json
 import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -45,10 +49,46 @@ def test_registry_contents():
     assert covering("optim.lbfgs") is None
     assert covering("embedding.sgns") is None   # host engine: no kernel
 
+    import jax
+
+    jax.devices()                          # this process has a backend
     live = registry()
     for kid, rec in live.items():
-        assert isinstance(rec["enabled"], bool)
-        assert rec["interpret"] is True   # CPU container
+        assert rec["enabled"] is False     # unset knob, cpu backend
+        assert rec["interpret"] is True    # the conftest asked for it
+
+
+def test_registry_readout_never_starts_a_backend():
+    """A supervisor or the WebUI asking for the kernel table must not take
+    the chip: in a process that has not started a backend the live readout
+    answers from the env alone (``enabled`` is None for unset knobs) and
+    leaves jax unimported."""
+    code = (
+        "import sys, json\n"
+        "from alink_tpu.native.kernels import registry\n"
+        "rows = registry()\n"
+        "print(json.dumps({'jax': 'jax' in sys.modules,\n"
+        "  'enabled': {k: r['enabled'] for k, r in rows.items()}}))\n")
+    env = dict(os.environ, ALINK_GBDT_PALLAS="1")
+    env.pop("ALINK_ATTN_PALLAS", None)
+    env.pop("ALINK_SGNS_PALLAS", None)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["jax"] is False
+    assert out["enabled"] == {"dl.attn_pallas": None,
+                              "embedding.sgns_pallas": None,
+                              "tree.pallas_hist": True}
+
+
+def test_interpret_mode_is_asked_for_not_inferred(monkeypatch):
+    from alink_tpu.native.kernels import interpret_mode
+
+    assert interpret_mode() is True        # root conftest
+    monkeypatch.delenv("ALINK_PALLAS_INTERPRET")
+    assert interpret_mode() is False       # cpu backend, and still False
 
 
 @pytest.mark.parametrize("value,expect", [
@@ -159,8 +199,9 @@ def test_flash_block_update_matches_online_softmax():
     # p.sum / matmuls per (b, h) tile, XLA over the whole 4D block
     import jax.numpy as jnp
 
-    from alink_tpu.dl.attention import _NEG_INF, _online_softmax_update
-    from alink_tpu.dl.attn_pallas import flash_block_update
+    from alink_tpu.dl.attention import _NEG_INF
+    from alink_tpu.dl.attn_pallas import (_xla_block_update,
+                                          flash_block_update)
 
     rng = np.random.default_rng(1)
     B, H, Q, D, K = 2, 3, 5, 7, 11
@@ -176,18 +217,14 @@ def test_flash_block_update_matches_online_softmax():
     l0 = jnp.zeros((B, H, Q), jnp.float32)
     scale = float(D) ** -0.5
 
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
-    s = jnp.where(kvalid[:, None, None, :] > 0, s, _NEG_INF)
-    s = jnp.where(ok[None, None] > 0, s, _NEG_INF)
-    o_ref, m_ref, l_ref = _online_softmax_update(
-        o0.transpose(0, 2, 1, 3), m0, l0, s, v.transpose(0, 2, 1, 3),
-        q.dtype)
+    # the XLA form built on _online_softmax_update — also the function whose
+    # VJP is the kernel's backward pass
+    o_ref, m_ref, l_ref = _xla_block_update(q, k, v, kvalid, ok, o0, m0, l0,
+                                            scale)
 
     o, m, l = flash_block_update(q, k, v, kvalid, ok, o0, m0, l0,
                                  scale=scale, interpret=True)
-    np.testing.assert_allclose(np.asarray(o),
-                               np.asarray(o_ref.transpose(0, 2, 1, 3)),
-                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=1e-5)
     np.testing.assert_allclose(np.asarray(m), np.asarray(m_ref), atol=1e-5)
     np.testing.assert_allclose(np.asarray(l), np.asarray(l_ref), atol=1e-5)
     assert not np.isnan(np.asarray(o)).any()
@@ -240,6 +277,104 @@ def test_ring_attention_knob_parity(monkeypatch, causal, with_mask):
     np.testing.assert_allclose(np.asarray(on), np.asarray(off), atol=1e-5)
     full = full_attention(q, k, v, mask, causal=causal)
     np.testing.assert_allclose(np.asarray(on), np.asarray(full), atol=2e-5)
+
+
+def test_blockwise_and_ring_attention_differentiate_through_the_kernel(
+        monkeypatch):
+    """With the knob where a one-chip TPU process defaults it (on), a
+    training step must be able to take a gradient through the flash update:
+    its backward is the VJP of the XLA update it is pinned against."""
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl.attention import (blockwise_attention, full_attention,
+                                        ring_attention)
+    from alink_tpu.parallel.mesh import AXIS_DATA, AXIS_SEQ, make_mesh
+
+    rng = np.random.default_rng(4)
+    b, s, h, d = 4, 32, 2, 8
+    q, k, v = (jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
+               for _ in range(3))
+    mask = jnp.asarray(rng.integers(0, 2, size=(b, s)), jnp.int32)
+    mask = mask.at[:, 0].set(1)
+    mesh = make_mesh({AXIS_DATA: 2, AXIS_SEQ: 4})
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v: (fn(q, k, v) ** 2).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    monkeypatch.setenv("ALINK_ATTN_PALLAS", "1")
+    ref = grads(lambda q, k, v: full_attention(q, k, v, mask))
+    for fn in (lambda q, k, v: blockwise_attention(q, k, v, mask,
+                                                   block_size=8),
+               lambda q, k, v: ring_attention(q, k, v, mask, mesh=mesh)):
+        for g, g_ref in zip(grads(fn), ref):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
+                                       atol=2e-5)
+
+
+def test_attention_kernel_defaults_off_where_gspmd_would_partition_it(
+        monkeypatch):
+    """jax cannot partition a Mosaic kernel, and the attention call sites
+    are not inside a shard_map manual over every axis: with no knob set
+    the kernel is on only in a one-device TPU process."""
+    import jax
+
+    from alink_tpu.dl.attn_pallas import use_attn_pallas
+    from alink_tpu.tree.pallas_hist import use_pallas_hist
+
+    monkeypatch.delenv("ALINK_ATTN_PALLAS", raising=False)
+    monkeypatch.delenv("ALINK_GBDT_PALLAS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert jax.device_count() == 8
+    assert use_attn_pallas() is False
+    assert use_pallas_hist() is True      # full-manual shard_map: any count
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    assert use_attn_pallas() is True
+    monkeypatch.setenv("ALINK_ATTN_PALLAS", "0")
+    assert use_attn_pallas() is False
+
+
+def test_every_kernel_lowers_for_the_tpu_platform(monkeypatch):
+    """As far as a CPU can check Mosaic: trace each kernel with
+    ``interpret=False`` and lower it for the ``tpu`` platform, which runs the
+    BlockSpec tiling rules and the jaxpr->Mosaic lowering (the Mosaic
+    compiler proper only runs on the chip — chip_smoke.py). Shapes are the
+    callers': 12 heads x 64 with K/V block 128; dim 128 with 5 negatives;
+    a depth-6, 64-bin histogram."""
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl.attention import blockwise_attention, ring_attention
+    from alink_tpu.embedding.sgns_pallas import sgns_block_grads
+    from alink_tpu.parallel.mesh import AXIS_SEQ, make_mesh
+    from alink_tpu.tree.pallas_hist import pallas_histogram
+
+    monkeypatch.delenv("ALINK_PALLAS_INTERPRET")
+    monkeypatch.setenv("ALINK_ATTN_PALLAS", "1")
+
+    def lowers(f, *args):
+        text = jax.jit(f).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text
+
+    q = jnp.zeros((2, 256, 12, 64), jnp.bfloat16)
+    mask = jnp.ones((2, 256), jnp.int32)
+    block = lambda q, k, v, m: blockwise_attention(q, k, v, m,
+                                                   block_size=128)
+    lowers(block, q, q, q, mask)
+    lowers(jax.grad(lambda *a: block(*a).astype(jnp.float32).sum()),
+           q, q, q, mask)
+    # under shard_map(check_vma=True) the kernel's outputs carry the vma of
+    # its inputs; manual over every axis, as Mosaic requires
+    mesh = make_mesh({AXIS_SEQ: 4}, devices=jax.devices()[:4])
+    lowers(lambda q, k, v, m: ring_attention(q, k, v, m, mesh=mesh),
+           q, q, q, mask)
+
+    v = jnp.zeros((256, 128), jnp.float32)
+    lowers(sgns_block_grads, v, v, jnp.zeros((256, 5, 128), jnp.float32))
+    lowers(lambda i, w: pallas_histogram(i, w, num_segments=32 * 64),
+           jnp.zeros((4096, 20), jnp.int32), jnp.zeros((4096,), jnp.float32))
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,7 @@ resolution, the on-disk LRU cap, warmup-spec persistence, and
 profiling-record survival across persist hits.
 
 The cross-process tests are the PR's reason to exist: two FRESH interpreters
-sharing one ``ALINK_COMPILE_CACHE_DIR`` must produce bit-identical results,
+handed one ``JAX_COMPILATION_CACHE_DIR`` must produce bit-identical results,
 with the second reaching them on ``jit.persist_hit`` instead of backend
 compiles — and a truncated cache entry must degrade to a fresh compile
 (counted), never to a wrong answer or a crash.
@@ -71,7 +71,7 @@ print(json.dumps({{
 
 def _run_child(cache_dir: str) -> dict:
     env = dict(os.environ)
-    env["ALINK_COMPILE_CACHE_DIR"] = str(cache_dir)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
     env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.run(
         [sys.executable, "-c", _CHILD.format(repo=REPO_ROOT)],
@@ -133,33 +133,70 @@ def test_corrupt_cache_entry_falls_back_to_fresh_compile(tmp_path):
 # knob resolution + lifecycle (in-process)
 # ---------------------------------------------------------------------------
 
-def test_knob_resolution(monkeypatch, tmp_path):
-    # tests run with JAX_PLATFORMS=cpu (root conftest): default is OFF
-    monkeypatch.delenv("ALINK_COMPILE_CACHE_DIR", raising=False)
-    monkeypatch.delenv("ALINK_COMPILATION_CACHE_DIR", raising=False)
-    assert jitcache._resolve_persist_dir(None)[0] is None
-    # blank-but-exported knob is an explicit OFF
-    monkeypatch.setenv("ALINK_COMPILE_CACHE_DIR", "  ")
-    assert jitcache._resolve_persist_dir(None)[0] is None
-    # the legacy name still works ...
-    monkeypatch.setenv("ALINK_COMPILE_CACHE_DIR", "")
-    monkeypatch.setenv("ALINK_COMPILATION_CACHE_DIR", str(tmp_path / "b"))
-    monkeypatch.delenv("ALINK_COMPILE_CACHE_DIR")
-    assert jitcache._resolve_persist_dir(None) == (str(tmp_path / "b"), True)
-    # ... and the new name wins over it
-    monkeypatch.setenv("ALINK_COMPILE_CACHE_DIR", str(tmp_path / "a"))
-    assert jitcache._resolve_persist_dir(None) == (str(tmp_path / "a"), True)
-    # an explicit argument wins over everything
+def test_cache_dir_resolution(monkeypatch, tmp_path):
+    """The cache is placed from outside. ``JAX_COMPILATION_CACHE_DIR`` set:
+    that directory, untouched, and nothing else may be configured. Unset:
+    off on the CPU (this suite), else the in-checkout default."""
+    # the root conftest removes the variable: default is OFF under cpu
+    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
+    assert jitcache._resolve_persist_dir(None) is None
+    # an explicit argument (tests, drills) is honoured while nothing else
+    # placed the cache
     assert jitcache._resolve_persist_dir(str(tmp_path / "c")) == \
-        (str(tmp_path / "c"), True)
-    # off-CPU (knobs unset): the per-user default dir, marked NON-explicit
-    # so it yields to a user-configured jax cache dir instead of
-    # clobbering it
-    monkeypatch.delenv("ALINK_COMPILE_CACHE_DIR")
-    monkeypatch.delenv("ALINK_COMPILATION_CACHE_DIR")
+        str(tmp_path / "c")
+    # placed from outside: used as is, on any platform ...
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "x"))
+    assert jitcache._resolve_persist_dir(None) == str(tmp_path / "x")
+    assert jitcache._resolve_persist_dir(str(tmp_path / "x")) == \
+        str(tmp_path / "x")
+    # ... and a second directory is refused, not layered on top
+    with pytest.raises(ValueError, match="places the compile cache"):
+        jitcache._resolve_persist_dir(str(tmp_path / "c"))
+    # blank counts as unset (jax itself reads '' as no cache)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", " ")
+    assert jitcache._resolve_persist_dir(None) is None
+    # off the CPU with nothing set: the fixed in-checkout directory
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-    d, explicit = jitcache._resolve_persist_dir(None)
-    assert d.endswith("xla_cache") and explicit is False
+    assert jitcache._resolve_persist_dir(None) == \
+        os.path.join(REPO_ROOT, ".jax_cache") == jitcache.default_cache_dir()
+
+
+_RESOLVE_CHILD = """
+import json, os, sys
+sys.path.insert(0, {repo!r})
+import alink_tpu
+print(json.dumps({{"dir": alink_tpu.compile_cache_dir(),
+                   "env": os.environ.get("JAX_COMPILATION_CACHE_DIR"),
+                   "jax": "jax" in sys.modules}}))
+"""
+
+
+def _resolve_in_fresh_process(tmp_path, **env_over):
+    env = dict(os.environ, JAX_PLATFORMS="tpu", **env_over)
+    proc = subprocess.run(
+        [sys.executable, "-c", _RESOLVE_CHILD.format(repo=REPO_ROOT)],
+        env=env, cwd=str(tmp_path), capture_output=True, text=True,
+        timeout=60)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_fresh_processes_resolve_one_fixed_dir_or_the_placed_one(tmp_path):
+    """Two fresh processes (started from another cwd) resolve the SAME
+    directory inside the checkout — never a temp name, pid or time — and
+    export it for jax and for their own children, without importing jax.
+    With the variable set, that directory is the answer and stays as set."""
+    first = _resolve_in_fresh_process(tmp_path)
+    second = _resolve_in_fresh_process(tmp_path)
+    want = os.path.join(REPO_ROOT, ".jax_cache")
+    assert first == second == {"dir": want, "env": want, "jax": False}
+
+    placed = str(tmp_path / "x")
+    got = _resolve_in_fresh_process(tmp_path,
+                                    JAX_COMPILATION_CACHE_DIR=placed)
+    assert got == {"dir": placed, "env": placed, "jax": False}
+    assert os.listdir(placed) == []        # created, nothing else written
 
 
 def _build_scale(factor):
@@ -206,6 +243,29 @@ def test_in_process_persist_hit_and_profiling_survival(tmp_path):
     from alink_tpu.common.jitcache import compile_summary
 
     assert compile_summary()["persist"]["enabled"] is False
+
+
+def test_entries_without_atime_companions_do_not_break_writes(tmp_path):
+    """A directory placed from outside can hold entries any other jax wrote
+    with default settings — no ``-atime`` companion. jax's own eviction
+    fails every later write on the first such entry (seen on the chip), so
+    this module keeps it off and bounds the directory itself."""
+    import jax
+
+    d = tmp_path / "cc"
+    d.mkdir()
+    (d / "foreign-cache").write_bytes(b"x" * 64)      # no foreign-atime
+    e0 = metrics.counter("jit.persist_error")
+    try:
+        assert enable_persistent_cache(str(d)) == str(d)
+        assert jax.config.jax_compilation_cache_max_size == -1
+        prog = cached_jit("test.persist_foreign", _build_scale, 1.75)
+        prog(np.arange(32, dtype=np.float32))
+        assert persist_summary()["entries"] >= 2        # ours landed too
+        assert metrics.counter("jit.persist_error") == e0
+    finally:
+        disable_persistent_cache()
+        clear_program_cache()
 
 
 def test_disabled_writes_nothing(tmp_path):
@@ -276,33 +336,29 @@ def test_warmup_specs_roundtrip_from_disk(tmp_path):
         "disk-spec-warmed shape must not compile on first real call"
 
 
-def test_prejax_enable_env_writes_are_restored_on_disable(monkeypatch,
-                                                          tmp_path):
-    """A pre-jax enable hands config to jax via env vars; disable must
-    restore exactly what it changed — a user-exported JAX_* knob is
-    neither clobbered (min_* tuning) nor deleted (their own cache dir)."""
-    import os
-
+def test_prejax_enable_env_writes_are_taken_back_on_disable(monkeypatch,
+                                                           tmp_path):
+    """A pre-jax enable hands config to jax via env vars; disable must take
+    back exactly what it wrote — a user-exported JAX_* tuning knob is
+    neither clobbered nor deleted."""
     monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "2.5")
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES",
                        raising=False)
-    # simulate the pre-jax branch directly: force configured=False path
     with jitcache._persist_lock:
         saved = dict(jitcache._persist)
-    monkeypatch.setattr(jitcache, "sys", jitcache.sys)  # no-op guard
+        jitcache._persist.update(enabled=False, dir=None, configured=False,
+                                 wrote_env=set())
+    real_modules = jitcache.sys.modules
+
+    class _NoJax(dict):
+        def __contains__(self, k):
+            return False if k == "jax" else k in real_modules
+
     try:
-        # pretend jax is absent for the enable by driving the env branch:
-        # call the writer helper the way enable does
-        with jitcache._persist_lock:
-            jitcache._persist.update(enabled=False, dir=None,
-                                     configured=False, wrote_env={})
-        real_modules = jitcache.sys.modules
-        class _NoJax(dict):
-            def __contains__(self, k):
-                return False if k == "jax" else k in real_modules
+        # pretend jax is not imported yet, the state at `import alink_tpu`
         monkeypatch.setattr(jitcache.sys, "modules", _NoJax())
         d = jitcache.enable_persistent_cache(str(tmp_path / "cc"))
+        monkeypatch.setattr(jitcache.sys, "modules", real_modules)
         assert d == str(tmp_path / "cc")
         # user's min-compile floor survived; our writes landed
         assert os.environ[
@@ -310,7 +366,6 @@ def test_prejax_enable_env_writes_are_restored_on_disable(monkeypatch,
         assert os.environ["JAX_COMPILATION_CACHE_DIR"] == d
         assert os.environ[
             "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] == "-1"
-        monkeypatch.setattr(jitcache.sys, "modules", real_modules)
         jitcache.disable_persistent_cache()
         # ours removed, the user's untouched
         assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
@@ -318,5 +373,6 @@ def test_prejax_enable_env_writes_are_restored_on_disable(monkeypatch,
         assert os.environ[
             "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "2.5"
     finally:
+        monkeypatch.setattr(jitcache.sys, "modules", real_modules)
         with jitcache._persist_lock:
             jitcache._persist.update(saved)

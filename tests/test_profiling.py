@@ -445,17 +445,33 @@ def test_metric_direction_classification():
 
 
 def test_compare_bench_files_flags_bert_regression(tmp_path):
-    """Acceptance: --compare BENCH_r04.json BENCH_r05.json flags the bert
-    samples/s drop as a significant regression, while a same-config
-    (self) compare reports no regressions."""
+    """Acceptance: ``--compare`` over two archived-layout rounds flags a
+    12% drop of the headline samples/s as a significant regression, while
+    a same-config (self) compare reports no regressions."""
     from alink_tpu.common.benchstats import compare_bench_files
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    r04 = os.path.join(root, "BENCH_r04.json")
-    r05 = os.path.join(root, "BENCH_r05.json")
-    if not (os.path.exists(r04) and os.path.exists(r05)):
-        pytest.skip("BENCH round files not present")
-    rep = compare_bench_files(r04, r05)
+    def round_file(n, value, kmeans_cold_s):
+        parsed = {
+            "metric": "bert_base_finetune_throughput_per_chip",
+            "value": value,
+            "unit": "samples/sec/chip (seq128, bs32, bf16)",
+            "extras": {
+                "kmeans_iris": {"wall_clock_s": kmeans_cold_s,
+                                "wall_clock_warm_s": 0.4,
+                                "cluster_purity": 0.8933},
+                "gbdt_train": {"samples_per_sec": 1.5e6, "trees": 20,
+                               "train_accuracy": 0.9936},
+            },
+        }
+        path = tmp_path / f"round_{n}.json"
+        path.write_text(json.dumps(
+            {"n": n, "cmd": "python bench.py", "rc": 0, "tail": "",
+             "parsed": parsed}))
+        return str(path)
+
+    old = round_file(4, 2163.9, 2.7)
+    new = round_file(5, 1897.7, 2.8)
+    rep = compare_bench_files(old, new)
     assert rep["verdict"] == "regression"
     flagged = {e["metric"] for e in rep["regressions"]}
     assert "value" in flagged          # the bert samples/s/chip drop
@@ -463,7 +479,7 @@ def test_compare_bench_files_flags_bert_regression(tmp_path):
     assert bert["delta_pct"] < -10.0
     assert bert["direction"] == "higher"
 
-    same = compare_bench_files(r04, r04)
+    same = compare_bench_files(old, old)
     assert same["verdict"] == "ok"
     assert same["regressions"] == []
 

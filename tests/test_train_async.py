@@ -261,8 +261,11 @@ def test_pretrain_finetune_real_text_smoke(tmp_path):
     summary = pretrain_and_save(
         load_reviews(limit=192), d, vocab_size=400, hidden_size=32,
         num_layers=1, num_heads=2, intermediate_size=64, max_len=24,
-        epochs=2, batch_size=32, seed=0)
-    assert summary["final_loss"] < summary["initial_loss"]
+        epochs=6, batch_size=32, seed=0)
+    # epoch means over freshly masked batches are noisy (the first two
+    # read 6.070 and 6.075): compare across enough epochs for the trend to
+    # clear that noise
+    assert summary["final_loss"] < summary["initial_loss"] - 0.1
 
     tr_t, tr_y, ho_t, ho_y = sst2_split(seed=0)
     acc = _finetune_acc(d, tr_t[:128], tr_y[:128], ho_t[:64], ho_y[:64])
