@@ -328,7 +328,6 @@ def _run_unit(unit: _Unit, record: bool, ctx=None):
         for k, v in phases.items():
             rec[k] = round(v, 6) if isinstance(v, float) else v
         metrics.record_bounded("executor.node", _TRACE_LIMIT, **rec)
-        metrics.add_time("executor.node_wall", wall)
         metrics.observe("executor.node_s", wall)
 
 
@@ -430,7 +429,6 @@ def _run_scheduled(env, roots: Sequence[Any], units: List[_Unit],
             for r in roots:
                 r._evaluate()
     if record:
-        metrics.add_time("executor.schedule", time.perf_counter() - t_start)
         metrics.record_bounded(
             "executor.run", _TRACE_LIMIT,
             units=len(units), nodes=len(nodes),

@@ -474,7 +474,8 @@ def _counted_cache_io(fn):
 
 def _install_persist_hooks() -> bool:
     """Counter plumbing: ``jit.persist_hit`` / ``jit.persist_miss`` /
-    ``jit.persist_saved_s`` from jax's monitoring events,
+    ``jit.persist_saved_s`` / ``jit.persist_load_s`` from jax's monitoring
+    events,
     ``jit.persist_error`` from wrapped cache IO (jax 0.9 internals).
     Returns True; callers record that under ``_persist_lock``."""
     if _persist["hooked"]:
@@ -493,6 +494,10 @@ def _install_persist_hooks() -> bool:
         # whole seconds, so sub-second CPU compiles read 0)
         if event == "/jax/compilation_cache/compile_time_saved_sec":
             metrics.add_time("jit.persist_saved_s", max(float(duration), 0.0))
+        elif event == "/jax/compilation_cache/cache_retrieval_time_sec":
+            # what one persist hit spent reading and deserialising its
+            # cached executable (jax 0.9.0, _src/compiler.py)
+            metrics.observe("jit.persist_load_s", max(float(duration), 0.0))
 
     monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_duration)

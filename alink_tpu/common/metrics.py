@@ -571,14 +571,22 @@ def _count_drop(where: str, exc: BaseException):
 
 @contextlib.contextmanager
 def profile_trace(log_dir: str, *, host_tracer_level: int = 2):
-    """``jax.profiler`` trace context (Perfetto/TensorBoard viewable). No-op
-    fallback if the profiler cannot start (e.g. twice in one process);
-    start/stop failures are counted in ``metrics.dropped``, never raised."""
+    """``jax.profiler`` trace context (Perfetto/TensorBoard viewable): the
+    device's operations and, on the ``/host:CPU`` plane, every
+    :func:`~alink_tpu.common.tracing.trace_span` and ``train.step`` opened
+    inside it (``docs/observability.md``, "Reading a device trace"). The
+    Python tracer stays off: it records every call and slows the host it
+    measures. No-op fallback if the profiler cannot start (e.g. twice in
+    one process); start/stop failures are counted in ``metrics.dropped``,
+    never raised."""
     import jax
 
     started = False
     try:
-        jax.profiler.start_trace(log_dir)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = host_tracer_level
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
         started = True
     except Exception as e:
         _count_drop("profile_trace.start", e)

@@ -8,17 +8,27 @@ on, and where?* This module adds the missing correlation key — a trace id —
 and the span tree under it:
 
 - :func:`trace_span` — context-managed span: trace id / span id / parent id,
-  wall time, per-phase seconds (compile/transfer/compute, fed by the same
+  wall time and self time (wall less the children that finished on the same
+  thread), per-phase seconds (compile/transfer/compute, fed by the same
   ``node_phase_context`` plumbing the executor already uses), and an outcome
   (``ok`` / ``retried`` / ``failed`` / ``defused``). Spans nest through a
   thread-local; :func:`capture_context` + :func:`attach_context` carry the
   parent across explicit thread handoffs (the ``alink-dag`` executor pool,
   ``alink-h2d`` transfer streams, recovery chain threads), so a span started
-  on a worker thread still parents correctly.
+  on a worker thread still parents correctly. Each span is also a
+  ``jax.profiler.TraceAnnotation`` of its name on the thread that opened it:
+  under a profiler session (:func:`~alink_tpu.common.metrics.profile_trace`)
+  it lies on that thread's line of the ``/host:CPU`` plane, on the clock of
+  the device operations; with no session it costs one flag test.
 - :class:`Tracer` — process-wide finished-span sink: a bounded in-memory
   ring (``ALINK_TRACE_RING``, default 4096 spans) plus an optional append-
   only JSONL event log (``ALINK_TRACE_LOG=<path>``; one JSON object per
-  finished span, crash-greppable).
+  finished span, crash-greppable). Every finished span's wall also goes
+  into one histogram per name, ``span.<name>_s``, in the metrics registry
+  (exact sum and count). Span names are therefore a closed set
+  (``docs/observability.md`` lists them; the executor's unit spans are
+  named by operator class): a request's, a row's or a model's name is an
+  attribute, never part of a span's name.
 - :func:`job_report` — one dict per job run: the span tree (one span per
   scheduled DAG unit, fused chains as ONE span with a ``fused`` mark), the
   compile/transfer/compute split, retries absorbed, outcome counts, and the
@@ -38,6 +48,7 @@ import contextlib
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -95,8 +106,8 @@ class Span:
     ``attrs``; everything else is filled by the tracer."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "t_start",
-                 "start_perf", "wall_s", "phases", "outcome", "retries",
-                 "attrs", "thread", "error")
+                 "start_perf", "wall_s", "child_s", "phases", "outcome",
+                 "retries", "attrs", "thread", "thread_id", "error", "keep")
 
     def __init__(self, trace_id: str, span_id: str, parent_id: Optional[str],
                  name: str, attrs: Dict[str, Any]):
@@ -107,12 +118,25 @@ class Span:
         self.t_start = time.time()
         self.start_perf = time.perf_counter()
         self.wall_s: float = 0.0
+        # wall of the children that finished on this span's own thread
+        self.child_s: float = 0.0
         self.phases: Dict[str, float] = {}
         self.outcome: Optional[str] = None
         self.retries = 0
         self.attrs = attrs
         self.thread = threading.current_thread().name
+        self.thread_id = threading.get_ident()
         self.error: Optional[str] = None
+        # False: the span leaves no record (ring, log, histogram) when it
+        # ends — the batcher's last wait, which ends in shutdown
+        self.keep = True
+
+    @property
+    def self_s(self) -> float:
+        """Wall less the children that finished on the same thread. A
+        child on another thread (:func:`attach_context`) runs beside its
+        parent, not inside it, and is not subtracted."""
+        return max(self.wall_s - self.child_s, 0.0)
 
     def to_dict(self) -> Dict[str, Any]:
         d: Dict[str, Any] = {
@@ -123,6 +147,7 @@ class Span:
             "t_start": round(self.t_start, 6),
             "start_perf": self.start_perf,
             "wall_s": round(self.wall_s, 6),
+            "self_s": round(self.self_s, 6),
             "outcome": self.outcome,
             "thread": self.thread,
         }
@@ -269,8 +294,9 @@ class Tracer:
         span.wall_s = time.perf_counter() - span.start_perf
         if span.outcome is None:
             span.outcome = "retried" if span.retries else "ok"
-        metrics.incr("trace.spans")
-        metrics.observe("trace.span_s", span.wall_s)
+        if not span.keep:
+            return
+        metrics.observe(f"span.{span.name}_s", span.wall_s)
         d = span.to_dict()
         with self._lock:
             self._ring.append(d)
@@ -450,6 +476,13 @@ class Tracer:
 tracer = Tracer()
 
 
+def _jax_profiler():
+    """``jax.profiler`` where jax is already imported, else None: a process
+    that never imported jax has no profiler session to write to, and a
+    fleet parent must stay off jax."""
+    return getattr(sys.modules.get("jax"), "profiler", None)
+
+
 @contextlib.contextmanager
 def trace_span(name: str, **attrs):
     """Open a span around a block::
@@ -469,8 +502,11 @@ def trace_span(name: str, **attrs):
     span = tracer.start(name, **attrs)
     prev = getattr(_ctx, "span", None)
     _ctx.span = span
+    profiler = _jax_profiler()
     try:
-        yield span
+        with profiler.TraceAnnotation(name) if profiler \
+                else contextlib.nullcontext():
+            yield span
     except BaseException as e:
         span.outcome = "failed"
         span.error = f"{type(e).__name__}: {e}"[:200]
@@ -478,6 +514,20 @@ def trace_span(name: str, **attrs):
     finally:
         _ctx.span = prev
         tracer.finish(span)
+        if isinstance(prev, Span) and prev.thread_id == span.thread_id:
+            prev.child_s += span.wall_s
+
+
+def step_annotation(name: str, step: int):
+    """``jax.profiler.StepTraceAnnotation(name, step_num=step)``: a step
+    boundary of the program's own in a profiler trace (the train loop opens
+    one per optimizer step). It is no span — the ring and the registry
+    already count steps (``train.step_s``) — and, like a span's annotation,
+    a null context when tracing is off or jax was never imported."""
+    profiler = _jax_profiler()
+    if profiler is None or not tracing_enabled():
+        return contextlib.nullcontext()
+    return profiler.StepTraceAnnotation(name, step_num=step)
 
 
 def note_retry() -> None:

@@ -78,13 +78,16 @@ class SelfAttention(nn.Module):
         q, k, v = [
             qkv[:, :, i].reshape(x.shape[0], x.shape[1], h, d) for i in range(3)
         ]
-        if c.use_ring_attention and self.mesh is not None:
-            o = ring_attention(q, k, v, mask, mesh=self.mesh)
-        elif c.attention_block_size:
-            o = blockwise_attention(q, k, v, mask,
-                                    block_size=c.attention_block_size)
-        else:
-            o = full_attention(q, k, v, mask)
+        # scores, softmax and PV under one name in every operation's
+        # op_name, whichever of the three computes them
+        with jax.named_scope("attention_core"):
+            if c.use_ring_attention and self.mesh is not None:
+                o = ring_attention(q, k, v, mask, mesh=self.mesh)
+            elif c.attention_block_size:
+                o = blockwise_attention(q, k, v, mask,
+                                        block_size=c.attention_block_size)
+            else:
+                o = full_attention(q, k, v, mask)
         o = o.reshape(x.shape[0], x.shape[1], h * d)
         return nn.DenseGeneral(c.hidden_size, dtype=c.dtype, name="out")(o)
 

@@ -35,6 +35,7 @@ Steady-state execution contract (the BERT hot path):
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from dataclasses import dataclass
 from functools import partial
@@ -42,6 +43,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tupl
 
 import numpy as np
 
+from ..common.tracing import step_annotation, trace_span
 from .sharding import batch_sharding, param_shardings
 
 
@@ -205,12 +207,15 @@ def make_train_step(model, tx, loss_of, *, weighted: bool = False,
                         deterministic=dkey is None, **kwargs
                     )
                     new_stats = {}
-                l = loss_of(logits, y, w) if weighted else loss_of(logits, y)
+                with jax.named_scope("loss"):
+                    l = (loss_of(logits, y, w) if weighted
+                         else loss_of(logits, y))
                 return l, new_stats
 
             (l, new_stats), g = jax.value_and_grad(loss, has_aux=True)(params)
-            updates, opt_state = tx.update(g, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(g, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
             return {"params": new_params, **dict(new_stats)}, opt_state, l
 
         if weighted:
@@ -276,7 +281,8 @@ def make_accum_programs(model, tx, loss_sum_of, accum: int, *,
             kwargs = {"rngs": {"dropout": dkey}} if dkey is not None else {}
             logits = model.apply({"params": p}, **batch,
                                  deterministic=dkey is None, **kwargs)
-            return loss_sum_of(logits, y, w)
+            with jax.named_scope("loss"):
+                return loss_sum_of(logits, y, w)
 
         return jax.value_and_grad(loss)(params)
 
@@ -294,11 +300,12 @@ def make_accum_programs(model, tx, loss_sum_of, accum: int, *,
         return micro_step
 
     def _apply_math(jax, jnp, optax, params, opt_state, gacc, wacc, lacc):
-        denom = jnp.maximum(wacc, 1.0)
-        g = jax.tree.map(lambda a: a / denom, gacc)
-        updates, opt_state2 = tx.update(g, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        return new_params, opt_state2, lacc / denom
+        with jax.named_scope("optimizer"):
+            denom = jnp.maximum(wacc, 1.0)
+            g = jax.tree.map(lambda a: a / denom, gacc)
+            updates, opt_state2 = tx.update(g, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
+            return new_params, opt_state2, lacc / denom
 
     def _build_apply():
         import jax
@@ -585,11 +592,11 @@ def train_model(
         params = model.init(key, **sample, deterministic=True)
     else:
         params = init_params
-    p_shard = param_shardings(params, mesh)
-    params = jax.device_put(params, p_shard)
-
     tx = _make_optimizer(cfg, total_steps)
-    opt_state = tx.init(params["params"])
+    with trace_span("train.place_state"):
+        p_shard = param_shardings(params, mesh)
+        params = jax.device_put(params, p_shard)
+        opt_state = tx.init(params["params"])
     loss_of = _loss_fn(cfg.loss, regression, weighted=True)
 
     def in_shard(arr):
@@ -622,7 +629,6 @@ def train_model(
 
     from ..common.metrics import metrics as _metrics
     from ..common.tracing import set_process_identity
-    from ..common.tracing import trace_span as _trace_span
     import time as _time
 
     if num_shards > 1:
@@ -735,7 +741,7 @@ def train_model(
     for epoch in range(start_epoch, cfg.num_epochs):
         # one rank-tagged span per epoch: in a multi-process drill each
         # rank exports its own train.epoch lane into the stitched trace
-        with _trace_span("train.epoch", epoch=epoch, rank=shard_idx,
+        with trace_span("train.epoch", epoch=epoch, rank=shard_idx,
                          shards=num_shards):
             # per-(seed, epoch) generator, NOT the sequentially-consumed rng: a
             # crash-resumed run must replay the exact shuffle of the epochs it
@@ -761,10 +767,11 @@ def train_model(
                         depth=cfg.feed_depth, phases=feed_phases)):
                     batch = dict(zip(names, devs[:-2]))
                     yb, wb = devs[-2], devs[-1]
-                    params, opt_state, l = train_step(
-                        params, opt_state, batch, yb, wb,
-                        jax.random.fold_in(key, step)
-                    )
+                    with step_annotation("train.step", step):
+                        params, opt_state, l = train_step(
+                            params, opt_state, batch, yb, wb,
+                            jax.random.fold_in(key, step)
+                        )
                     _metrics.observe("train.step_s",
                                      _time.perf_counter() - t_step)
                     t_step = _time.perf_counter()
@@ -791,11 +798,12 @@ def train_model(
                         phases=feed_phases)):
                     batch = dict(zip(names, devs[:-2]))
                     yb, wb = devs[-2], devs[-1]
-                    skey = jax.random.fold_in(key, step)
-                    dkeys = jnp.stack([jax.random.fold_in(skey, k)
-                                       for k in range(accum)])
-                    params, opt_state, l = fused_prog(
-                        params, opt_state, batch, yb, wb, dkeys)
+                    with step_annotation("train.step", step):
+                        skey = jax.random.fold_in(key, step)
+                        dkeys = jnp.stack([jax.random.fold_in(skey, k)
+                                           for k in range(accum)])
+                        params, opt_state, l = fused_prog(
+                            params, opt_state, batch, yb, wb, dkeys)
                     _metrics.observe("train.step_s",
                                      _time.perf_counter() - t_step)
                     t_step = _time.perf_counter()
@@ -816,35 +824,43 @@ def train_model(
 
                 t_step = _time.perf_counter()
                 skey = None
-                for m, devs in _timed_feed(_feed(
-                        build_micro, place, steps_per_epoch * accum,
-                        mode=cfg.feed, depth=cfg.feed_depth,
-                        phases=feed_phases)):
-                    s, k = divmod(m, accum)
-                    if k == 0:
-                        skey = jax.random.fold_in(key, step)
-                    batch = dict(zip(names, devs[:-2]))
-                    yb, wb = devs[-2], devs[-1]
-                    gacc, wacc, lacc = micro_prog(
-                        gacc, wacc, lacc, params, batch, yb, wb,
-                        jax.random.fold_in(skey, k))
-                    _metrics.incr("train.micro_steps")
-                    if k == accum - 1:
-                        ga, wa, la = gacc, wacc, lacc
-                        if num_shards > 1:
-                            # rank-ordered sum of the per-process chunk
-                            # accumulators — bit-identical on every process
-                            ga, wa, la = ordered_cross_process_sum(
-                                (gacc, wacc, lacc))
-                        t_f = _time.perf_counter()
-                        params, opt_state, l, gacc, wacc, lacc = apply_prog(
-                            params, opt_state, ga, wa, la)
-                        _metrics.observe("train.accum_flush_s",
-                                         _time.perf_counter() - t_f)
-                        _metrics.observe("train.step_s",
-                                         _time.perf_counter() - t_step)
-                        t_step = _time.perf_counter()
-                        _after_step(s, l, epoch)
+                # an optimizer step spans accum feed items: its annotation
+                # opens on the first chunk and closes after the apply (or
+                # when the loop raises)
+                with contextlib.ExitStack() as step_scope:
+                    for m, devs in _timed_feed(_feed(
+                            build_micro, place, steps_per_epoch * accum,
+                            mode=cfg.feed, depth=cfg.feed_depth,
+                            phases=feed_phases)):
+                        s, k = divmod(m, accum)
+                        if k == 0:
+                            skey = jax.random.fold_in(key, step)
+                            step_scope.enter_context(
+                                step_annotation("train.step", step))
+                        batch = dict(zip(names, devs[:-2]))
+                        yb, wb = devs[-2], devs[-1]
+                        gacc, wacc, lacc = micro_prog(
+                            gacc, wacc, lacc, params, batch, yb, wb,
+                            jax.random.fold_in(skey, k))
+                        _metrics.incr("train.micro_steps")
+                        if k == accum - 1:
+                            ga, wa, la = gacc, wacc, lacc
+                            if num_shards > 1:
+                                # rank-ordered sum of the per-process chunk
+                                # accumulators — bit-identical on every
+                                # process
+                                ga, wa, la = ordered_cross_process_sum(
+                                    (gacc, wacc, lacc))
+                            t_f = _time.perf_counter()
+                            params, opt_state, l, gacc, wacc, lacc = \
+                                apply_prog(params, opt_state, ga, wa, la)
+                            _metrics.observe("train.accum_flush_s",
+                                             _time.perf_counter() - t_f)
+                            _metrics.observe("train.step_s",
+                                             _time.perf_counter() - t_step)
+                            step_scope.close()
+                            t_step = _time.perf_counter()
+                            _after_step(s, l, epoch)
             if not cfg.log_every:
                 lv = float(l)
                 history["loss"].append(lv)
@@ -885,7 +901,8 @@ def train_model(
             "transfer_s": round(feed_phases.get("transfer_s", 0.0), 4),
             "batches": feed_phases.get("batches", 0),
         }
-    return jax.device_get(params), history
+    with trace_span("train.export_model"):
+        return jax.device_get(params), history
 
 
 def _batched_apply(fn, params, inputs: Dict[str, np.ndarray], mesh, in_shard,
@@ -900,18 +917,22 @@ def _batched_apply(fn, params, inputs: Dict[str, np.ndarray], mesh, in_shard,
     n = inputs[names[0]].shape[0]
     outs = []
     for s in range(0, n, bs):
-        chunk = [np.asarray(inputs[k][s:s + bs]) for k in names]
-        m = chunk[0].shape[0]
-        # pad up the bucket ladder (then to the data-axis multiple) and trim
-        # after — the forward pass is row-wise, so repeated-last-row padding
-        # is exact, and ragged eval tails reuse the full-chunk program
-        target = bucket_rows(m) if bucketing_enabled() else m
-        target += (-target) % dp
-        if target != m:
-            chunk = _pad_tail(chunk, target)
-        batch = {k: jax.device_put(v, in_shard(v))
-                 for k, v in zip(names, chunk)}
-        outs.append(np.asarray(fn(params, batch))[:m])
+        # one span per chunk: pad, place, call, and the copy back that
+        # waits for the device
+        with trace_span("dl.predict.apply"):
+            chunk = [np.asarray(inputs[k][s:s + bs]) for k in names]
+            m = chunk[0].shape[0]
+            # pad up the bucket ladder (then to the data-axis multiple) and
+            # trim after — the forward pass is row-wise, so repeated-last-row
+            # padding is exact, and ragged eval tails reuse the full-chunk
+            # program
+            target = bucket_rows(m) if bucketing_enabled() else m
+            target += (-target) % dp
+            if target != m:
+                chunk = _pad_tail(chunk, target)
+            batch = {k: jax.device_put(v, in_shard(v))
+                     for k, v in zip(names, chunk)}
+            outs.append(np.asarray(fn(params, batch))[:m])
     return np.concatenate(outs, axis=0)
 
 
@@ -932,26 +953,29 @@ def predict_model(
     from ..common import quant
     from ..parallel.mesh import default_mesh
 
-    mesh = mesh or default_mesh()
-    policy = quant.resolve_policy(precision)
-    if policy == quant.BF16:
-        params = jax.tree_util.tree_map(
-            lambda a: quant.bf16_round(a)
-            if np.issubdtype(np.asarray(a).dtype, np.floating) else a,
-            params)
-        policy = None
-    if policy == quant.INT8:
-        qparams, scales = quant.quantize_tree(params)
-        p_shard = param_shardings(qparams, mesh)
-        params = jax.device_put(qparams, p_shard)
-        apply = _apply_program_int8(model, scales)
-    else:
-        p_shard = param_shardings(params, mesh)
-        params = jax.device_put(params, p_shard)
-        apply = _apply_program(model)
-
     def in_shard(arr):
         sa = seq_axis if arr.ndim > (seq_axis or 0) else None
         return batch_sharding(mesh, arr.ndim, seq_axis=sa)
 
-    return _batched_apply(apply, params, inputs, mesh, in_shard, batch_size)
+    mesh = mesh or default_mesh()
+    policy = quant.resolve_policy(precision)
+    with trace_span("dl.predict"):
+        # dispatch time: nothing is synchronised for the span's sake
+        with trace_span("dl.predict.place_params"):
+            if policy == quant.BF16:
+                params = jax.tree_util.tree_map(
+                    lambda a: quant.bf16_round(a)
+                    if np.issubdtype(np.asarray(a).dtype, np.floating)
+                    else a, params)
+                policy = None
+            if policy == quant.INT8:
+                qparams, scales = quant.quantize_tree(params)
+                p_shard = param_shardings(qparams, mesh)
+                params = jax.device_put(qparams, p_shard)
+                apply = _apply_program_int8(model, scales)
+            else:
+                p_shard = param_shardings(params, mesh)
+                params = jax.device_put(params, p_shard)
+                apply = _apply_program(model)
+        return _batched_apply(apply, params, inputs, mesh, in_shard,
+                              batch_size)

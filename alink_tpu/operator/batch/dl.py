@@ -29,6 +29,7 @@ from ...common.exceptions import (AkIllegalArgumentException,
 from ...common.model import model_to_table, table_to_model
 from ...common.mtable import AlinkTypes, MTable
 from ...common.params import InValidator, MinValidator, ParamInfo
+from ...common.tracing import trace_span
 from ...mapper import (
     HasFeatureCols,
     HasPredictionCol,
@@ -140,7 +141,8 @@ class BaseKerasSequentialTrainBatchOp(ModelTrainOpMixin, BatchOperator,
             "dim": int(X.shape[1]),
             "finalLoss": history.get("final_loss"),
         }
-        return model_to_table(meta, {"params": _params_to_bytes(params)})
+        with trace_span("train.export_model"):
+            return model_to_table(meta, {"params": _params_to_bytes(params)})
 
 
 class KerasSequentialClassifierTrainBatchOp(BaseKerasSequentialTrainBatchOp):
@@ -334,7 +336,8 @@ class BaseBertTextTrainBatchOp(ModelTrainOpMixin, BatchOperator, HasDLTrainParam
         if pre_dir:
             from ...dl.pretrained import load_bert_checkpoint, load_vocab_file
 
-            ckpt_cfg, pre_subtree = load_bert_checkpoint(pre_dir)
+            with trace_span("train.ingest_checkpoint", part="read"):
+                ckpt_cfg, pre_subtree = load_bert_checkpoint(pre_dir)
             do_lower = ckpt_cfg.pop("do_lower_case", True)
             vocab_list = load_vocab_file(pre_dir)
             if len(vocab_list) != ckpt_cfg["vocab_size"]:
@@ -361,7 +364,8 @@ class BaseBertTextTrainBatchOp(ModelTrainOpMixin, BatchOperator, HasDLTrainParam
                 texts + (pairs or []), vocab_size=self.get(self.VOCAB_SIZE)
             )
             cfg = self._bert_config(tok.vocab_size, num_labels)
-        enc = tok.encode_batch(texts, pairs, max_len=max_len)
+        with trace_span("train.tokenize", rows=len(texts)):
+            enc = tok.encode_batch(texts, pairs, max_len=max_len)
         if cfg.use_ring_attention:
             # mesh with a seq axis for ring attention (dp fills the rest)
             from ...dl.sharding import make_dl_mesh
@@ -384,9 +388,10 @@ class BaseBertTextTrainBatchOp(ModelTrainOpMixin, BatchOperator, HasDLTrainParam
             from ...dl.pretrained import init_from_pretrained
 
             sample = {k: v[:1] for k, v in enc.items()}
-            init_params = init_from_pretrained(
-                model, cfg, pre_subtree, sample,
-                seed=self.get(self.RANDOM_SEED))
+            with trace_span("train.ingest_checkpoint", part="init"):
+                init_params = init_from_pretrained(
+                    model, cfg, pre_subtree, sample,
+                    seed=self.get(self.RANDOM_SEED))
         params, history = train_model(
             model, enc, y, tc, mesh=mesh, regression=self._regression,
             init_params=init_params,
@@ -411,7 +416,8 @@ class BaseBertTextTrainBatchOp(ModelTrainOpMixin, BatchOperator, HasDLTrainParam
             "pretrainedFrom": pre_dir,
             "finalLoss": history.get("final_loss"),
         }
-        return model_to_table(meta, {"params": _params_to_bytes(params)})
+        with trace_span("train.export_model"):
+            return model_to_table(meta, {"params": _params_to_bytes(params)})
 
 
 class BertTextClassifierTrainBatchOp(BaseBertTextTrainBatchOp):
@@ -472,23 +478,27 @@ class BertTextModelMapper(RichModelMapper):
         meta = self.meta
         text_col = self.get(self.TEXT_COL) or meta["textCol"]
         pair_col = self.get(self.TEXT_PAIR_COL) or meta.get("textPairCol")
-        texts = [str(v) for v in t.col(text_col)]
-        pairs = [str(v) for v in t.col(pair_col)] if pair_col else None
-        enc = self.tokenizer.encode_batch(
-            texts, pairs, max_len=int(meta["maxSeqLength"])
-        )
+        with trace_span("bert.tokenize", rows=t.num_rows):
+            texts = [str(v) for v in t.col(text_col)]
+            pairs = [str(v) for v in t.col(pair_col)] if pair_col else None
+            enc = self.tokenizer.encode_batch(
+                texts, pairs, max_len=int(meta["maxSeqLength"])
+            )
         logits = predict_model(self.model, self.params, enc,
                                precision=self._policy)
-        if meta["regression"]:
-            return logits[:, 0].astype(np.float64), AlinkTypes.DOUBLE, None
-        probs = softmax_np(logits)
-        idx = probs.argmax(axis=1)
-        labels = meta["labels"]
-        pred = np_labels(labels, meta.get("labelType", AlinkTypes.STRING), idx)
-        detail = None
-        if self.get(HasPredictionDetailCol.PREDICTION_DETAIL_COL):
-            detail = detail_json(labels, probs)
-        return pred, self._pred_type(), detail
+        with trace_span("bert.postprocess"):
+            if meta["regression"]:
+                return (logits[:, 0].astype(np.float64), AlinkTypes.DOUBLE,
+                        None)
+            probs = softmax_np(logits)
+            idx = probs.argmax(axis=1)
+            labels = meta["labels"]
+            pred = np_labels(labels,
+                             meta.get("labelType", AlinkTypes.STRING), idx)
+            detail = None
+            if self.get(HasPredictionDetailCol.PREDICTION_DETAIL_COL):
+                detail = detail_json(labels, probs)
+            return pred, self._pred_type(), detail
 
 
 class BertTextClassifierPredictBatchOp(ModelMapBatchOp, HasPredictionCol,

@@ -11,8 +11,10 @@ from __future__ import annotations
 from typing import Type
 
 from ...common.exceptions import AkIllegalOperationException
+from ...common.metrics import metrics
 from ...common.model import MODEL_SCHEMA
 from ...common.mtable import MTable, TableSchema
+from ...common.tracing import trace_span
 from ..base import AlgoOperator
 from .base import BatchOperator
 
@@ -75,18 +77,22 @@ class ModelMapBatchOp(BatchOperator):
     def _make_mapper(self, model_schema, data_schema):
         return self.mapper_cls(model_schema, data_schema, self.get_params())
 
+    def _loaded_mapper(self, model: MTable, data_schema):
+        mapper = self._make_mapper(model.schema, data_schema)
+        with trace_span("mapper.load_model", mapper=type(mapper).__name__):
+            mapper.load_model(model)
+        metrics.incr("mapper.model_loads")
+        return mapper
+
     def _fusion_mapper(self, data_schema):
         # deps are evaluated before a fused unit runs, so the model read is
         # a memoized fetch — same load path as _execute_impl
-        model = self._inputs[0]._evaluate()
-        mapper = self._make_mapper(model.schema, data_schema)
-        mapper.load_model(model)
-        return mapper
+        return self._loaded_mapper(self._inputs[0]._evaluate(), data_schema)
 
     def _execute_impl(self, model: MTable, t: MTable) -> MTable:
-        mapper = self._make_mapper(model.schema, t.schema)
-        mapper.load_model(model)
-        return mapper.map_table(t)
+        mapper = self._loaded_mapper(model, t.schema)
+        with trace_span("mapper.map_table", mapper=type(mapper).__name__):
+            return mapper.map_table(t)
 
     def _out_schema(self, model_schema: TableSchema,
                     data_schema: TableSchema) -> TableSchema:

@@ -280,23 +280,33 @@ class _ModelEntry:
         return batch
 
     def _batcher(self) -> None:
+        # the spans serving.wait, serving.collect, serving.batch and
+        # serving.reply tile this thread's time, batch after batch
         while True:
             with self._cond:
-                while not (self._high or self._normal):
-                    if self._draining:
-                        return
-                    self._cond.wait(0.1)
-                # let the batch fill until the oldest waiter's flush deadline
-                flush_at = (self._oldest_enqueued()
-                            + self.config.flush_deadline_s)
-                while (len(self._high) + len(self._normal)
-                       < self.config.max_batch_rows):
-                    rem = flush_at - time.perf_counter()
-                    if rem <= 0 or self._draining:
-                        break
-                    self._cond.wait(rem)
-                batch = self._pop_batch_locked()
-                self.batches += 1
+                if not (self._high or self._normal):
+                    with trace_span("serving.wait") as sp:
+                        while not (self._high or self._normal):
+                            if self._draining:
+                                if sp is not None:
+                                    sp.keep = False
+                                return
+                            self._cond.wait(0.1)
+                with trace_span("serving.collect") as sp:
+                    # let the batch fill until the oldest waiter's flush
+                    # deadline
+                    flush_at = (self._oldest_enqueued()
+                                + self.config.flush_deadline_s)
+                    while (len(self._high) + len(self._normal)
+                           < self.config.max_batch_rows):
+                        rem = flush_at - time.perf_counter()
+                        if rem <= 0 or self._draining:
+                            break
+                        self._cond.wait(rem)
+                    batch = self._pop_batch_locked()
+                    self.batches += 1
+                    if sp is not None:
+                        sp.attrs["rows"] = len(batch)
             try:
                 self._run_batch(batch)
             except BaseException as e:
@@ -309,7 +319,37 @@ class _ModelEntry:
                         self._finish(req, None, e)
 
     def _run_batch(self, batch: List[_Request]) -> None:
+        """Serve one batch under ``serving.batch``, then complete its
+        futures under ``serving.reply``. No future is completed before the
+        batch's span is finished and observed: a client that wakes on its
+        result finds the batch's seconds already in the registry."""
         now = time.perf_counter()
+        # parent both spans under the oldest live request's trace — a
+        # coalesced batch belongs to many traces; Dapper convention is to
+        # follow the request that opened it
+        ctx = next((r.ctx for r in batch if r.ctx is not None
+                    and not (r.future.deadline is not None
+                             and now > r.future.deadline)), None)
+        failed: List[Tuple[_Request, BaseException]] = []
+        with attach_context(ctx):
+            with trace_span("serving.batch", model=self.name) as sp:
+                served = self._serve_batch(batch, now, failed, sp)
+            # nothing but completions from here on: once the first client
+            # wakes and resubmits, the next batch's flush deadline runs, and
+            # an allocation here could start a collection that outlasts it
+            # (the batch would flush in parts, and a closed loop stays split)
+            with trace_span("serving.reply", rows=len(failed) + len(served)):
+                for req, error in failed:
+                    self._finish(req, None, error)
+                for req, row in served:
+                    self._finish(req, row, None)
+
+    def _serve_batch(self, batch: List[_Request], now: float,
+                     failed: List[Tuple[_Request, BaseException]], sp
+                     ) -> List[Tuple[_Request, Tuple]]:
+        """Admission, table build and predict of one batch. Returns each
+        request served with its row; each request that is to be answered
+        with an error goes onto ``failed``."""
         live: List[_Request] = []
         for req in batch:
             fut = req.future
@@ -318,41 +358,38 @@ class _ModelEntry:
                 with self._lock:
                     self.expired += 1
                 metrics.incr("serving.deadline_expired")
-                self._finish(req, None, AkDeadlineExceededException(
+                failed.append((req, AkDeadlineExceededException(
                     f"request deadline expired after "
-                    f"{now - fut.enqueued_at:.3f}s in queue"))
+                    f"{now - fut.enqueued_at:.3f}s in queue")))
                 continue
             live.append(req)
         if not live:
-            return
+            return []
         try:
             self.breaker.before_call()
         except AkCircuitOpenException as e:
             with self._lock:
                 self.breaker_rejected += len(live)
             metrics.incr("serving.breaker_rejected", len(live))
-            for req in live:
-                self._finish(req, None, e)
-            return
-        live, t = self._build_batch_table(live)
+            failed.extend((req, e) for req in live)
+            return []
+        with trace_span("serving.build_table"):
+            live, t = self._build_batch_table(live, failed)
         if not live:
             self.breaker.release_probe()  # no health verdict this round
-            return
+            return []
         n = len(live)
+        if sp is not None:
+            sp.attrs["rows"] = n
         metrics.observe("serving.batch_rows", float(n), buckets=_ROW_BUCKETS)
-        # parent the batch span under the oldest live request's trace —
-        # a coalesced batch belongs to many traces; Dapper convention is
-        # to follow the request that opened it
-        ctx = next((r.ctx for r in live if r.ctx is not None), None)
         try:
-            with attach_context(ctx), \
-                    trace_span("serving.batch", model=self.name, rows=n):
+            with trace_span("serving.predict"):
                 out = self.predictor.predict_table(t)
-                if out.num_rows != n:
-                    raise AkIllegalStateException(
-                        f"model {self.name!r} returned {out.num_rows} rows "
-                        f"for a {n}-row batch; serving requires row-wise "
-                        f"pipelines (one output row per input row)")
+            if out.num_rows != n:
+                raise AkIllegalStateException(
+                    f"model {self.name!r} returned {out.num_rows} rows "
+                    f"for a {n}-row batch; serving requires row-wise "
+                    f"pipelines (one output row per input row)")
         except BaseException as e:
             # every EXECUTION failure feeds the breaker: a model failing
             # batch after batch is unhealthy regardless of error taxonomy,
@@ -363,18 +400,20 @@ class _ModelEntry:
             with self._lock:
                 self.errors += n
             metrics.incr("serving.errors", n)
-            for req in live:
-                self._finish(req, None, e)
-            return
+            failed.extend((req, e) for req in live)
+            if sp is not None:
+                sp.outcome = "failed"
+                sp.error = f"{type(e).__name__}: {e}"[:200]
+            return []
         self.breaker.record_success()
         with self._lock:
             self.completed += n
             self.rows_total += n
         metrics.incr("serving.completed", n)
-        for i, req in enumerate(live):
-            self._finish(req, out.get_row(i), None)
+        return [(req, out.get_row(i)) for i, req in enumerate(live)]
 
-    def _build_batch_table(self, live: List[_Request]
+    def _build_batch_table(self, live: List[_Request],
+                           failed: List[Tuple[_Request, BaseException]]
                            ) -> Tuple[List[_Request], Optional[MTable]]:
         """Coalesce rows into one MTable. Rows that cannot build against the
         input schema are CALLER errors: each is rejected individually (the
@@ -394,8 +433,8 @@ class _ModelEntry:
                     with self._lock:
                         self.bad_rows += 1
                     metrics.incr("serving.bad_rows")
-                    self._finish(req, None, AkIllegalArgumentException(
-                        f"row does not fit input schema: {e}"))
+                    failed.append((req, AkIllegalArgumentException(
+                        f"row does not fit input schema: {e}")))
             if not good:
                 return [], None
             return good, MTable.from_rows([r.row for r in good],

@@ -589,8 +589,10 @@ def test_fleet_stitched_trace_acceptance(traced_fleet, serial_rows):
         return [n for _, n in _walk(job_report(tid)["tree"])
                 if n.get("proc")]
 
-    # the replica's spans arrive by heartbeat relay — poll for stitch
-    assert _wait(lambda: bool(_replica_spans()), timeout=15), \
+    # the replica's spans arrive by heartbeat relay, children a heartbeat
+    # before the parents they finished inside — poll until the stitch is whole
+    assert _wait(lambda: bool(_replica_spans())
+                 and len(job_report(tid)["tree"]) == 1, timeout=15), \
         "replica spans never stitched into the frontdoor trace"
     rep = job_report(tid)
     rows_ = _walk(rep["tree"])

@@ -171,7 +171,9 @@ def test_metrics_endpoint_and_traces(server, monkeypatch):
                  l)
         for l in body), body[:5]
     assert any("_bucket{le=" in l for l in body)   # >= one histogram
-    assert any(l.startswith("alink_trace_spans_total") for l in body)
+    # every span's wall is summed under its name
+    assert any(l.startswith("alink_span_webui_run_experiment_seconds_count")
+               for l in body)
 
 
 def test_canvas_page_has_ports_and_forms(server):
