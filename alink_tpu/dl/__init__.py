@@ -30,7 +30,8 @@ from .data import (CorpusStream, load_reviews, load_sst2, scheduled_order,
 from .modules import BertConfig, TransformerEncoder, KerasSequential, parse_layers
 from .pretrain import pretrain_and_save, pretrain_mlm
 from .sharding import param_shardings, make_dl_mesh
-from .train import TrainConfig, train_model, predict_model
+from .train import (TrainConfig, train_model, predict_model,
+                    prepare_params)
 from .tokenizer import Tokenizer
 
 __all__ = [
@@ -46,6 +47,7 @@ __all__ = [
     "TrainConfig",
     "train_model",
     "predict_model",
+    "prepare_params",
     "pretrain_mlm",
     "pretrain_and_save",
     "load_reviews",

@@ -39,7 +39,8 @@ import contextlib
 import dataclasses
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, Iterator, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import numpy as np
 
@@ -936,6 +937,53 @@ def _batched_apply(fn, params, inputs: Dict[str, np.ndarray], mesh, in_shard,
     return np.concatenate(outs, axis=0)
 
 
+class PreparedParams(NamedTuple):
+    """Parameters as the forward program takes them: what of
+    :func:`predict_model` depends on (model, params, precision, mesh) alone."""
+
+    apply: Any   # the forward program: dl.apply_logits or its int8 twin
+    params: Any  # rounded or quantised by the policy, placed under ``mesh``
+    mesh: Any
+
+
+def prepare_params(model, params, *, mesh=None,
+                   precision: Optional[str] = None) -> PreparedParams:
+    """Apply the serving precision policy to a host parameter tree, place it
+    under ``mesh`` and choose the forward program (see :func:`predict_model`
+    for the policies). A caller that predicts more than once keeps the result
+    and hands it to :func:`predict_model` in the tree's place.
+
+    Given a prepared form, returns it as it is when it was placed under this
+    mesh, and otherwise places its tree (the policy already applied) under
+    this one: ``precision`` is read for a host tree only."""
+    import jax
+
+    from ..common import quant
+    from ..parallel.mesh import default_mesh
+
+    mesh = mesh or default_mesh()
+    if isinstance(params, PreparedParams) and params.mesh == mesh:
+        return params
+    # dispatch time: nothing is synchronised for the span's sake
+    with trace_span("dl.predict.place_params"):
+        if isinstance(params, PreparedParams):
+            apply, params = params.apply, params.params
+        else:
+            policy = quant.resolve_policy(precision)
+            if policy == quant.BF16:
+                params = jax.tree_util.tree_map(
+                    lambda a: quant.bf16_round(a)
+                    if np.issubdtype(np.asarray(a).dtype, np.floating)
+                    else a, params)
+            if policy == quant.INT8:
+                params, scales = quant.quantize_tree(params)
+                apply = _apply_program_int8(model, scales)
+            else:
+                apply = _apply_program(model)
+        placed = jax.device_put(params, param_shardings(params, mesh))
+    return PreparedParams(apply, placed, mesh)
+
+
 def predict_model(
     model, params, inputs: Dict[str, np.ndarray], *, mesh=None,
     batch_size: int = 256, seq_axis: Optional[int] = 1,
@@ -947,35 +995,18 @@ def predict_model(
     ``int8`` quantizes every >=2-D float parameter per-channel (weight-only
     — dequantized in-kernel by the ``dl.apply_logits.int8`` program);
     ``bf16`` rounds float parameters through bfloat16. Unset leaves the
-    fp32 path byte-identical."""
-    import jax
+    fp32 path byte-identical.
 
-    from ..common import quant
-    from ..parallel.mesh import default_mesh
-
-    def in_shard(arr):
-        sa = seq_axis if arr.ndim > (seq_axis or 0) else None
-        return batch_sharding(mesh, arr.ndim, seq_axis=sa)
-
-    mesh = mesh or default_mesh()
-    policy = quant.resolve_policy(precision)
+    ``params`` is a host tree, prepared here on every call, or what
+    :func:`prepare_params` made of one, which is then neither rounded nor
+    placed again."""
     with trace_span("dl.predict"):
-        # dispatch time: nothing is synchronised for the span's sake
-        with trace_span("dl.predict.place_params"):
-            if policy == quant.BF16:
-                params = jax.tree_util.tree_map(
-                    lambda a: quant.bf16_round(a)
-                    if np.issubdtype(np.asarray(a).dtype, np.floating)
-                    else a, params)
-                policy = None
-            if policy == quant.INT8:
-                qparams, scales = quant.quantize_tree(params)
-                p_shard = param_shardings(qparams, mesh)
-                params = jax.device_put(qparams, p_shard)
-                apply = _apply_program_int8(model, scales)
-            else:
-                p_shard = param_shardings(params, mesh)
-                params = jax.device_put(params, p_shard)
-                apply = _apply_program(model)
-        return _batched_apply(apply, params, inputs, mesh, in_shard,
-                              batch_size)
+        prepared = prepare_params(model, params, mesh=mesh,
+                                  precision=precision)
+
+        def in_shard(arr):
+            sa = seq_axis if arr.ndim > (seq_axis or 0) else None
+            return batch_sharding(prepared.mesh, arr.ndim, seq_axis=sa)
+
+        return _batched_apply(prepared.apply, prepared.params, inputs,
+                              prepared.mesh, in_shard, batch_size)

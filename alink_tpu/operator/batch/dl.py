@@ -465,6 +465,7 @@ class BertTextModelMapper(RichModelMapper):
         from ...common import quant
 
         self._policy = quant.policy_of(self.get_params())
+        self._placed = None
         return self
 
     def _pred_type(self) -> str:
@@ -473,7 +474,7 @@ class BertTextModelMapper(RichModelMapper):
         return self.meta.get("labelType", AlinkTypes.STRING)
 
     def predict_block(self, t: MTable):
-        from ...dl.train import predict_model
+        from ...dl.train import predict_model, prepare_params
 
         meta = self.meta
         text_col = self.get(self.TEXT_COL) or meta["textCol"]
@@ -484,8 +485,14 @@ class BertTextModelMapper(RichModelMapper):
             enc = self.tokenizer.encode_batch(
                 texts, pairs, max_len=int(meta["maxSeqLength"])
             )
-        logits = predict_model(self.model, self.params, enc,
-                               precision=self._policy)
+        # the first predict applies the policy and places the parameters;
+        # from then on they stay where the forward program takes them (only
+        # another default mesh places them again) and the host tree goes
+        self._placed = prepare_params(
+            self.model, self.params if self._placed is None else self._placed,
+            precision=self._policy)
+        self.params = None
+        logits = predict_model(self.model, self._placed, enc)
         with trace_span("bert.postprocess"):
             if meta["regression"]:
                 return (logits[:, 0].astype(np.float64), AlinkTypes.DOUBLE,

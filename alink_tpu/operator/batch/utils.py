@@ -4,6 +4,16 @@ Capability parity with reference operator/batch/utils/ModelMapBatchOp.java:62
 (model broadcast at :64,175) and MapBatchOp.java. The model "broadcast" is
 trivial here — the mapper loads the model MTable once and the batched jit
 kernel is replicated by XLA as needed.
+
+"Once" holds across executes of one op: a ``ModelMapBatchOp`` keeps the mapper
+it last loaded, and runs the next execute through it when the model table is
+the same object, the data schema is equal and the op's params read as they
+did at the load. A batch DAG executes each op once and sees no difference; a
+``LocalPredictor`` plan, whose ops live as long as the predictor, loads on its
+first batch and never again. Anything else (another table object, another
+schema, a param set or removed since) loads anew and takes the slot, so the
+contract on a mapper is the reference's: ``load_model`` once, ``map_table``
+many times, and ``map_table`` leaves alone what ``load_model`` set up.
 """
 
 from __future__ import annotations
@@ -78,11 +88,24 @@ class ModelMapBatchOp(BatchOperator):
         return self.mapper_cls(model_schema, data_schema, self.get_params())
 
     def _loaded_mapper(self, model: MTable, data_schema):
-        mapper = self._make_mapper(model.schema, data_schema)
-        with trace_span("mapper.load_model", mapper=type(mapper).__name__):
-            mapper.load_model(model)
-        metrics.incr("mapper.model_loads")
-        return mapper
+        # one slot: the mapper last loaded and what it was loaded for. The
+        # slot holds the model table itself, so its identity cannot be
+        # recycled; the params are kept as they read at the load, because a
+        # mapper takes its settings in load_model (ModelServer stamps the
+        # precision policy onto a plan's ops after the plan exists)
+        with self._eval_lock:
+            params = self.get_params().to_json()
+            kept = getattr(self, "_kept_mapper", None)
+            if (kept is not None and kept[0] is model
+                    and kept[1] == data_schema and kept[2] == params):
+                metrics.incr("mapper.model_reuses")
+                return kept[3]
+            mapper = self._make_mapper(model.schema, data_schema)
+            with trace_span("mapper.load_model", mapper=type(mapper).__name__):
+                mapper.load_model(model)
+            metrics.incr("mapper.model_loads")
+            self._kept_mapper = (model, data_schema, params, mapper)
+            return mapper
 
     def _fusion_mapper(self, data_schema):
         # deps are evaluated before a fused unit runs, so the model read is

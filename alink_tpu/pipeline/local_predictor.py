@@ -9,9 +9,17 @@ The transform plan (the mapper chain: one predict/map op per pipeline stage,
 linked over a swappable source) is built ONCE at construction and reused for
 every predict — repeated predicts skip stage re-planning (op construction,
 param cloning, link_from) and go straight to the already-compiled kernels.
+The plan's ops live as long as the predictor, and each model-mapper op keeps
+the mapper it loaded (``ModelMapBatchOp._loaded_mapper``): the first predict
+loads every model, later ones map through the loaded mappers, as the
+reference's LocalPredictor loads each ModelMapper once. A mapper is loaded
+anew only when its op sees another model table object, another data schema
+or params other than at the load (``ModelServer`` stamps a precision policy
+onto the plan's ops, which is such a change); a new predictor starts empty.
 The cached-plan path is bit-identical to rebuilding the DAG per call
-(``tests/test_pipeline.py`` pins the parity); ``cache_plan=False`` restores
-the rebuild-per-call behavior.
+(``tests/test_pipeline.py`` and ``tests/test_model_residency.py`` pin the
+parity); ``cache_plan=False`` restores the rebuild-per-call behavior, model
+loads included.
 """
 
 from __future__ import annotations
@@ -68,8 +76,10 @@ class LocalPredictor:
             src, tail, ops = self._plan
             src._table = t
             # re-arm every node: model TableSourceBatchOps re-"execute" for
-            # free (they return their held table); predict ops re-run on the
-            # fresh input through their long-lived cached_jit programs
+            # free (they return their held table, the same object, which is
+            # what lets a predict op keep its loaded mapper); predict ops
+            # re-run on the fresh input through their long-lived cached_jit
+            # programs
             for op in ops:
                 op._executed = False
                 op._output = None

@@ -436,6 +436,40 @@ def test_hot_swap_and_unload(fitted, serial_rows):
         srv.close()
 
 
+def test_hot_swap_answers_with_the_new_weights_after_one_more_load(fitted):
+    """The loaded mapper lives in the entry's plan: traffic loads nothing
+    after the warm-up, and a swap under the same name brings a new plan that
+    loads the new model once and answers from it."""
+    from alink_tpu.pipeline import LogisticRegression
+
+    X, t, _ = fitted
+    flipped = t.with_column(
+        "label", np.where(t.col("label") == "pos", "neg", "pos"))
+    models = [Pipeline(LogisticRegression(
+        featureCols=FEATS, labelCol="label", predictionCol="pred",
+        maxIter=20)).fit(d) for d in (t, flipped)]
+    rows = [tuple(r) for r in X[::7]]
+    want = [[LocalPredictor(m, SCHEMA, cache_plan=False).predict_row(r)
+             for r in rows] for m in models]
+    assert all(a[-1] != b[-1] for a, b in zip(*want))
+
+    def loads():
+        return metrics.counter("mapper.model_loads")
+
+    srv = ModelServer(ServingConfig(max_batch_rows=8,
+                                    flush_deadline_s=0.002))
+    try:
+        for model, answers in zip(models, want):
+            at = loads()
+            srv.load("reswap", model, SCHEMA, warmup_rows=[rows[0]])
+            assert loads() - at == 1
+            for _ in range(2):
+                assert srv.predict_many("reswap", rows, timeout=60) == answers
+            assert loads() - at == 1
+    finally:
+        srv.close()
+
+
 def test_unload_fails_fast_without_drain(fitted):
     X, _, model = fitted
     srv = ModelServer(ServingConfig(max_batch_rows=4,
