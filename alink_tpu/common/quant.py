@@ -361,16 +361,25 @@ def int8_mlp_program(sizes: tuple):
                       tuple(int(s) for s in sizes))
 
 
+def bf16_cast(a: np.ndarray) -> np.ndarray:
+    """A block as bfloat16 itself (two bytes a value): what a model that is
+    held in bfloat16 on the device is placed from. :func:`bf16_round` widens
+    this back to f32, which for a tree of billions of parameters is twice
+    the device memory the policy was chosen to save."""
+    import ml_dtypes
+
+    a = np.asarray(a)
+    return a if a.dtype == ml_dtypes.bfloat16 else \
+        a.astype(np.float32, copy=False).astype(ml_dtypes.bfloat16)
+
+
 def bf16_round(a: np.ndarray) -> np.ndarray:
     """The ``bf16`` policy's numerics: round a block through bfloat16 and
     hand it back as f32. TPU bf16 matmuls accumulate in f32, so rounding
     the inputs and computing in the already-warmed f32 programs reproduces
     the bf16 result without tracing a single new program — the policy
     changes values, never shapes or dtypes on the wire."""
-    import ml_dtypes
-
-    return np.asarray(a, np.float32).astype(
-        ml_dtypes.bfloat16).astype(np.float32)
+    return bf16_cast(a).astype(np.float32)
 
 
 def _build_int8_tree_predict(depth: int):

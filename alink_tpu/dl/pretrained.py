@@ -12,6 +12,9 @@ pre-downloaded plugin layout — the user drops a checkpoint directory there
 Supported on-disk formats (auto-detected):
 - HuggingFace layout: ``config.json`` + ``model.safetensors`` /
   ``pytorch_model.bin`` / ``flax_model.msgpack`` + ``vocab.txt``
+- HuggingFace sharded layout: ``model-0000k-of-0000n.safetensors`` with
+  ``model.safetensors.index.json``, read tensor by tensor in the file's own
+  dtype (:func:`iter_safetensors`; the causal LM's ingest, dl/lm.py)
 - google-research TF v1 checkpoint: ``bert_config.json`` +
   ``bert_model.ckpt.{index,data-*}`` + ``vocab.txt`` (the exact artifact the
   reference's CKPT resources unpack, e.g. uncased_L-12_H-768_A-12.zip)
@@ -26,7 +29,7 @@ from __future__ import annotations
 import json
 import os
 import struct
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -116,6 +119,49 @@ def _read_safetensors(path: str) -> Dict[str, np.ndarray]:
             arr = np.frombuffer(raw, _DT[info["dtype"]])
         out[name] = arr.reshape(info["shape"]).copy()
     return out
+
+
+SAFETENSORS_INDEX = "model.safetensors.index.json"
+_ST_NAMES = {"F64": "float64", "F32": "float32", "F16": "float16",
+             "BF16": "bfloat16", "I64": "int64", "I32": "int32",
+             "I16": "int16", "I8": "int8", "U8": "uint8", "BOOL": "bool"}
+
+
+def _st_dtype(tag: str):
+    import ml_dtypes
+
+    return np.dtype(ml_dtypes.bfloat16 if tag == "BF16" else _ST_NAMES[tag])
+
+
+def safetensors_files(path: str) -> List[str]:
+    """The safetensors files of an HF-layout directory, in the index's
+    order (``model.safetensors.index.json``) or the single
+    ``model.safetensors``."""
+    index = os.path.join(path, SAFETENSORS_INDEX)
+    if os.path.isfile(index):
+        with open(index) as f:
+            names = json.load(f)["weight_map"].values()
+        return [os.path.join(path, n) for n in dict.fromkeys(names)]
+    return [os.path.join(path, "model.safetensors")]
+
+
+def iter_safetensors(path: str) -> Iterator[Tuple[str, np.ndarray]]:
+    """``(name, array)`` for every tensor of a sharded or single-file
+    safetensors directory, each a read-only view of the memory-mapped file
+    in the file's own dtype (bfloat16 as ``ml_dtypes.bfloat16``): nothing
+    is widened and no file is held in memory whole, so a tree larger than
+    the host would like as float32 goes to the device tensor by tensor."""
+    for file in safetensors_files(path):
+        with open(file, "rb") as f:
+            (hlen,) = struct.unpack("<Q", f.read(8))
+            header = json.loads(f.read(hlen))
+        blob = np.memmap(file, np.uint8, mode="r", offset=8 + hlen)
+        for name, info in header.items():
+            if name == "__metadata__":
+                continue
+            a, b = info["data_offsets"]
+            yield name, blob[a:b].view(_st_dtype(info["dtype"])).reshape(
+                info["shape"])
 
 
 def _read_torch_bin(path: str) -> Dict[str, np.ndarray]:

@@ -11,6 +11,10 @@ conventions):
 - mlp_in kernel         (D, F)        -> shard F over `model`
 - mlp_out kernel        (F, D)        -> shard F over `model`
 - tok_emb embedding     (V, D)        -> shard V over `model`
+- the causal LM's leaves (dl/lm.py), which keep the checkpoint's (out, in)
+  layout: q/k/v/gate/up_proj shard `out` (heads, FFN columns) over `model`,
+  o/down_proj shard `in`, embed_tokens and lm_head shard V; g_proj (one
+  output a key/value head), its bias and the norms are replicated
 - everything else replicated
 Batch dims of activations shard over `data`; sequence over `seq` when ring
 attention is enabled.
@@ -52,28 +56,41 @@ def _spec_for(path: str, shape) -> "jax.sharding.PartitionSpec":
         return P(*([None] * (nd - 1)), AXIS_MODEL) if nd >= 1 else P()
     if path.endswith("tok_emb/embedding"):
         return P(AXIS_MODEL, None)
+    leaf = path.rsplit("/", 1)[-1]
+    if leaf in _LM_SHARD_OUT and nd == 2:
+        return P(AXIS_MODEL, None)
+    if leaf in _LM_SHARD_IN and nd == 2:
+        return P(None, AXIS_MODEL)
     return P()
+
+
+_LM_SHARD_OUT = frozenset({"q_proj", "k_proj", "v_proj", "gate_proj", "up_proj",
+                           "embed_tokens", "lm_head"})
+_LM_SHARD_IN = frozenset({"o_proj", "down_proj"})
+
+
+def sharding_for(path: str, shape, mesh):
+    """The NamedSharding of one leaf: its declared spec where every named
+    axis exists in this mesh and divides the dimension, else replicated."""
+    from jax.sharding import NamedSharding
+
+    spec = _spec_for(path, shape)
+    for dim, ax in enumerate(spec):
+        if ax is None:
+            continue
+        if ax not in mesh.shape or shape[dim] % mesh.shape[ax] != 0:
+            return NamedSharding(mesh, jax.sharding.PartitionSpec())
+    return NamedSharding(mesh, spec)
 
 
 def param_shardings(params, mesh) -> Any:
     """NamedSharding pytree for a flax param tree (same structure)."""
-    from jax.sharding import NamedSharding
-
-    flat = jax.tree_util.tree_flatten_with_path(params)
-    specs = {}
-
     def to_spec(path_entries, leaf):
         path = "/".join(
-            getattr(e, "key", getattr(e, "name", str(e))) for e in path_entries
+            str(getattr(e, "key", getattr(e, "name", getattr(e, "idx", e))))
+            for e in path_entries
         )
-        spec = _spec_for(path, leaf.shape)
-        # the axis must exist in this mesh and divide the dim; replicate otherwise
-        for dim, ax in enumerate(spec):
-            if ax is None:
-                continue
-            if ax not in mesh.shape or leaf.shape[dim] % mesh.shape[ax] != 0:
-                return NamedSharding(mesh, jax.sharding.PartitionSpec())
-        return NamedSharding(mesh, spec)
+        return sharding_for(path, leaf.shape, mesh)
 
     return jax.tree_util.tree_map_with_path(to_spec, params)
 

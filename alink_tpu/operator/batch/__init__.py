@@ -86,6 +86,7 @@ from .modelpredict import (
     TorchModelPredictBatchOp,
     export_stablehlo,
 )
+from .lm import CausalLMGenerateBatchOp
 from .clustering import (
     GeoKMeansPredictBatchOp,
     GeoKMeansTrainBatchOp,

@@ -1,0 +1,133 @@
+"""Text generation with a causal language model.
+
+``CausalLMGenerateBatchOp`` continues each row's prompt by ``maxNewTokens``
+tokens, greedily and with no stop token. The model is an HF-layout checkpoint
+directory named by ``modelPath`` (``config.json``, sharded bfloat16
+safetensors, ``vocab.txt``; ``alink_tpu.dl.lm`` has the block), read straight
+to the device: billions of parameters do not travel as a model table, so this
+is a ``MapBatchOp`` with a path, as the ingest ops of ``modelpredict.py`` are.
+
+Columns in: ``selectedCol``, the prompt (STRING). Columns out, appended to the
+reserved ones: ``predictionCol`` (STRING), the continuation's word pieces
+joined into text; ``predictionDetailCol`` (STRING, optional), one JSON object
+``{"prompt_tokens": n, "ids": [maxNewTokens ids], "logprobs": [maxNewTokens
+log-probabilities, each of the id chosen at that step under the model's own
+distribution]}``.
+
+The op keeps its mapper, the mapper its placed parameters and its state cache
+(``stateSlots`` sequences): a ``LocalPredictor`` or ``ModelServer`` loads the
+model in its first batch and never again. A table of more rows than
+``stateSlots`` is generated in groups of that many. Rows of one group may
+differ in prompt length; none of a row's answer depends on its neighbours.
+A prompt of any length is taken whole: the model's memory is a state of fixed
+size, and the prefill program runs once per ``dl.lm.PREFILL_CHUNK`` positions.
+"""
+
+from __future__ import annotations
+
+import json
+from typing import List
+
+import numpy as np
+
+from ...common.exceptions import AkIllegalArgumentException
+from ...common.metrics import metrics
+from ...common.mtable import AlinkTypes, MTable, TableSchema
+from ...common.params import MinValidator, ParamInfo
+from ...common.tracing import trace_span
+from ...mapper import (HasPredictionCol, HasPredictionDetailCol,
+                       HasReservedCols, HasSelectedCol, Mapper)
+from .utils import MapBatchOp
+
+
+class HasCausalLMParams(HasSelectedCol, HasPredictionCol,
+                        HasPredictionDetailCol, HasReservedCols):
+    MODEL_PATH = ParamInfo(
+        "modelPath", str, optional=False,
+        desc="HF-layout checkpoint directory: config.json, safetensors "
+        "(sharded or single), vocab.txt")
+    MAX_NEW_TOKENS = ParamInfo(
+        "maxNewTokens", int, default=128, validator=MinValidator(1),
+        desc="tokens generated for every row (greedy, no stop token)")
+    STATE_SLOTS = ParamInfo(
+        "stateSlots", int, default=16, validator=MinValidator(1),
+        desc="sequences the state cache holds, so the rows generated "
+        "together; rounded up to a rung of the row ladder")
+
+
+class CausalLMGenerateMapper(Mapper, HasCausalLMParams):
+    def __init__(self, data_schema=None, params=None, **kw):
+        super().__init__(data_schema, params, **kw)
+        self._lm = None
+        self._tokenizer = None
+
+    def _ensure_loaded(self):
+        if self._lm is not None:
+            return
+        from ...dl.lm import load_causal_lm
+        from ...dl.tokenizer import Tokenizer
+
+        with trace_span("lm.load_model"):
+            self._lm, vocab = load_causal_lm(
+                self.get(self.MODEL_PATH), slots=self.get(self.STATE_SLOTS))
+            self._tokenizer = Tokenizer.from_list(vocab)
+        metrics.incr("lm.model_loads")
+
+    def output_schema(self, input_schema: TableSchema) -> TableSchema:
+        names, types = [self.get(self.PREDICTION_COL)], [AlinkTypes.STRING]
+        if self.get(self.PREDICTION_DETAIL_COL):
+            names.append(self.get(self.PREDICTION_DETAIL_COL))
+            types.append(AlinkTypes.STRING)
+        return self._append_result_schema(input_schema, names, types)
+
+    def _encode(self, text: str) -> List[int]:
+        tok = self._tokenizer
+        unk = tok.vocab["[UNK]"]
+        return [tok.vocab.get(p, unk) for p in tok.tokenize(text)] or [unk]
+
+    def _decode(self, ids) -> str:
+        words: List[str] = []
+        for i in ids:
+            piece = self._tokenizer.inv[int(i)]
+            if not piece.startswith("##"):
+                words.append(piece)
+            elif words:
+                words[-1] += piece[2:]
+            else:
+                words.append(piece[2:])
+        return " ".join(words)
+
+    def map_table(self, t: MTable) -> MTable:
+        col = self.get(self.SELECTED_COL)
+        if not col:
+            raise AkIllegalArgumentException("set selectedCol (the prompt)")
+        self._ensure_loaded()
+        pred, detail = self.get(self.PREDICTION_COL), \
+            self.get(self.PREDICTION_DETAIL_COL)
+        out_cols, out_types = {pred: []}, {pred: AlinkTypes.STRING}
+        if detail:
+            out_cols[detail], out_types[detail] = [], AlinkTypes.STRING
+        if t.num_rows:
+            with trace_span("lm.tokenize", rows=t.num_rows):
+                prompts = [self._encode(str(v)) for v in t.col(col)]
+            ids, logprobs = self._lm.generate(
+                prompts, self.get(self.MAX_NEW_TOKENS))
+            with trace_span("lm.detokenize", rows=t.num_rows):
+                out_cols[pred] = [self._decode(row) for row in ids]
+                if detail:
+                    out_cols[detail] = [
+                        json.dumps({"prompt_tokens": len(p),
+                                    "ids": row.tolist(),
+                                    "logprobs": np.round(
+                                        lp.astype(np.float64), 6).tolist()})
+                        for p, row, lp in zip(prompts, ids, logprobs)]
+        return self._append_result(t, out_cols, out_types)
+
+
+class CausalLMGenerateBatchOp(MapBatchOp, HasCausalLMParams):
+    __doc__ = __doc__
+
+    mapper_cls = CausalLMGenerateMapper
+    # a mapper with a model on the device and a state cache is no link of a
+    # fused row-wise chain
+    _fusable = False

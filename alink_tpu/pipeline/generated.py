@@ -197,6 +197,7 @@ TRANSFORMERS: Dict[str, str] = {
     'KvToJson': 'KvToJsonBatchOp',
     'KvToVector': 'KvToVectorBatchOp',
     'LookupHBase': 'LookupHBaseBatchOp',
+    'CausalLMGenerator': 'CausalLMGenerateBatchOp',
     'LookupRedisRow': 'LookupRedisRowBatchOp',
     'LookupRedisString': 'LookupRedisStringBatchOp',
     'NGram': 'NGramBatchOp',

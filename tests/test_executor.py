@@ -290,8 +290,16 @@ def test_fused_chain_keeps_passthrough_columns():
 # -- per-node executor trace -------------------------------------------------
 
 
+def _trace_since(before):
+    """The records added after ``before`` was read, by identity: the trace is
+    a ring, and an offset into it means nothing once earlier tests of this
+    process have filled it."""
+    old = {id(r) for r in before}
+    return [r for r in executor_trace() if id(r) not in old]
+
+
 def test_executor_records_per_node_trace():
-    n0 = len(executor_trace())
+    before = executor_trace()
     src = TableSourceBatchOp(_table(seed=7))
     a = src.select(["x"])
     b = src.filter("x > 0.25")
@@ -299,7 +307,7 @@ def test_executor_records_per_node_trace():
     a.lazy_collect(lambda t: got.setdefault("a", t))
     b.lazy_collect(lambda t: got.setdefault("b", t))
     src.execute()
-    trace = executor_trace()[n0:]
+    trace = _trace_since(before)
     assert len(trace) >= 3                       # src + two branches
     assert all("op" in r and "wall_s" in r for r in trace)
     run = metrics.last("executor.run")
@@ -307,11 +315,11 @@ def test_executor_records_per_node_trace():
 
 
 def test_trace_marks_fused_units():
-    n0 = len(executor_trace())
+    before = executor_trace()
     src = TableSourceBatchOp(_table(seed=8))
     _, _, tail = _chain(src)
     tail.collect()
-    fused = [r for r in executor_trace()[n0:] if r.get("fused")]
+    fused = [r for r in _trace_since(before) if r.get("fused")]
     assert fused and fused[0]["fused"] == 3
     assert "+" in fused[0]["op"]
 
