@@ -7,16 +7,34 @@ the tokenizer can (a) load a local vocab file with the standard BERT format,
 or (b) build a frequency vocab from the training corpus — greedy
 longest-match-first WordPiece with ``##`` continuation, same algorithm family
 as the reference's BERT tokenization.
+
+The ids are the published algorithm's (BERT's ``BasicTokenizer`` then
+``WordpieceTokenizer``; ``tests/test_tokenizer_corpus.py`` holds the
+per-character form as its plain reference and compares with
+``transformers.BertTokenizer``). What differs is where the work runs:
+
+- *the character table*: the per-character rules (drop, separate, isolate,
+  keep) are applied by one ``str.translate`` through ``_CharTable``, a dict
+  that classifies a code point with this file's predicates the first time a
+  text holds it and keeps the answer, so the pass over a text's characters
+  runs in C and the rules are written once;
+- *the word memo*: ``encode_batch`` looks each distinct word of one call up
+  in the vocabulary once (``word -> ids``, a dict that is dropped when the
+  call returns: no text and no id outlives a call), and counts
+  ``tokenizer.words`` and ``tokenizer.word_memo_hits`` once per call.
 """
 
 from __future__ import annotations
 
 import collections
 import re
+import sys
 import unicodedata
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from ..common.metrics import metrics
 
 PAD, UNK, CLS, SEP, MASK = "[PAD]", "[UNK]", "[CLS]", "[SEP]", "[MASK]"
 _SPECIALS = [PAD, UNK, CLS, SEP, MASK]
@@ -39,40 +57,57 @@ def _is_cjk(cp: int) -> bool:
             0xF900 <= cp <= 0xFAFF or 0x2F800 <= cp <= 0x2FA1F)
 
 
+class _CharTable(dict):
+    """``str.translate`` table of BERT's basic tokenization, filled the first
+    time a code point is met: ``None`` drops it (control characters, U+0000,
+    U+FFFD, and combining marks where accents are stripped), a space
+    separates words, a character between spaces stands alone (CJK,
+    punctuation), and any other maps to itself."""
+
+    def __init__(self, strip_marks: bool):
+        super().__init__()
+        self.strip_marks = strip_marks
+
+    def __missing__(self, cp: int):
+        ch = chr(cp)
+        cat = unicodedata.category(ch)
+        # whitespace first: \t \n \r are category Cc but BERT treats them
+        # as word separators, not strippable control chars
+        if ch.isspace():
+            to = " "
+        elif (cp == 0 or cp == 0xFFFD or cat.startswith("C")
+              or (self.strip_marks and cat == "Mn")):
+            to = None
+        elif _is_cjk(cp) or _is_punctuation(ch):
+            to = f" {ch} "
+        else:
+            to = cp
+        self[cp] = to
+        return to
+
+
+# what a code point maps to depends on nothing but the Unicode database, so
+# the two tables (accents kept, accents stripped) are the process's; a thread
+# that fills an entry another is filling writes the same value
+_CHAR_TABLES = (_CharTable(strip_marks=False), _CharTable(strip_marks=True))
+
+
 def _basic_tokens(text: str, do_lower_case: bool = True) -> List[str]:
     """BERT basic tokenization: clean control chars, isolate CJK chars,
     optionally lowercase + strip accents, split on punctuation."""
     if do_lower_case:
-        text = text.lower()
-        text = "".join(ch for ch in unicodedata.normalize("NFD", text)
-                       if unicodedata.category(ch) != "Mn")
-    out: List[str] = []
-    word: List[str] = []
-
-    def flush():
-        if word:
-            out.append("".join(word))
-            word.clear()
-
-    for ch in text:
-        # whitespace first: \t \n \r are category Cc but BERT treats them
-        # as word separators, not strippable control chars
-        if ch.isspace():
-            flush()
-            continue
-        cp = ord(ch)
-        if cp == 0 or cp == 0xFFFD or unicodedata.category(ch).startswith("C"):
-            continue
-        if _is_cjk(cp) or _is_punctuation(ch):
-            flush()
-            out.append(ch)
-        else:
-            word.append(ch)
-    flush()
-    return out
+        text = unicodedata.normalize("NFD", text.lower())
+    return text.translate(_CHAR_TABLES[bool(do_lower_case)]).split()
 
 
 _LEGACY_TOKEN_RE = re.compile(r"\w+|[^\w\s]", re.UNICODE)
+
+# one call's word -> ids. The ids are a tuple because the memo holds them for
+# the length of the call: a tuple of ints leaves the garbage collector's lists
+# at its first young collection, where a list for each of a batch's tens of
+# thousands of distinct words would be promoted to the oldest generation and
+# bring on a full collection of the process's heap every few batches
+_WordMemo = Dict[str, Tuple[int, ...]]
 
 
 class Tokenizer:
@@ -123,33 +158,77 @@ class Tokenizer:
 
     # -- encoding ----------------------------------------------------------
     def _wordpiece(self, word: str) -> List[str]:
-        if len(word) > self.max_chars:
+        n, vocab = len(word), self.vocab
+        if n > self.max_chars:
             return [UNK]
         pieces, start = [], 0
-        while start < len(word):
-            end = len(word)
-            cur = None
+        while start < n:
+            end = n
             while start < end:
                 sub = word[start:end]
                 if start > 0:
                     sub = "##" + sub
-                if sub in self.vocab:
-                    cur = sub
+                if sub in vocab:
                     break
                 end -= 1
-            if cur is None:
+            else:
                 return [UNK]
-            pieces.append(cur)
+            pieces.append(sub)
             start = end
         return pieces
 
+    def _words(self, text: str) -> List[str]:
+        return (_LEGACY_TOKEN_RE.findall(text.lower()) if self.legacy
+                else _basic_tokens(text, self.do_lower_case))
+
     def tokenize(self, text: str) -> List[str]:
-        words = (_LEGACY_TOKEN_RE.findall(text.lower()) if self.legacy
-                 else _basic_tokens(text, self.do_lower_case))
         out = []
-        for w in words:
+        for w in self._words(text):
             out.extend(self._wordpiece(w))
         return out
+
+    def _piece_ids(self, text: str, memo: _WordMemo,
+                   budget: int = sys.maxsize) -> Tuple[List[int], int]:
+        """Ids of ``text``'s word pieces, each distinct word looked up once
+        per ``memo``, and the number of words looked at. The row stops at
+        the word that reaches ``budget``: what follows would be cut off
+        anyway."""
+        vocab, unk = self.vocab, self.vocab[UNK]
+        out: List[int] = []
+        n = 0
+        for n, w in enumerate(self._words(text), 1):
+            ids = memo.get(w)
+            if ids is None:
+                ids = memo[w] = tuple(
+                    [vocab.get(p, unk) for p in self._wordpiece(w)])
+            out += ids
+            if len(out) >= budget:
+                break
+        return out, n
+
+    def _encode_row(self, text: str, pair: Optional[str], max_len: int,
+                    memo: _WordMemo
+                    ) -> Tuple[List[int], int, int]:
+        """One row's ids ``[CLS] a... [SEP] b... [SEP]`` (unpadded), the
+        length of its first segment with [CLS] and [SEP], and the number of
+        words looked up."""
+        vocab, unk = self.vocab, self.vocab[UNK]
+        cls, sep = vocab.get(CLS, unk), vocab.get(SEP, unk)
+        if pair is None:
+            a, n = self._piece_ids(text, memo, max_len - 2)
+            b: List[int] = []
+        else:
+            a, n = self._piece_ids(text, memo)
+            b, nb = self._piece_ids(pair, memo)
+            n += nb
+        budget = max_len - 2 - (1 if b else 0)
+        if b:
+            # longest-first truncation keeps both segments represented
+            while len(a) + len(b) > budget:
+                (a if len(a) >= len(b) else b).pop()
+        else:
+            a = a[:budget]
+        return [cls] + a + [sep] + (b + [sep] if b else []), len(a) + 2, n
 
     def encode(
         self,
@@ -159,42 +238,37 @@ class Tokenizer:
     ):
         """Returns (input_ids, attention_mask, token_type_ids), BERT layout:
         [CLS] a... [SEP] b... [SEP], padded to max_len."""
-        a = self.tokenize(text)
-        b = self.tokenize(pair) if pair is not None else []
-        budget = max_len - 2 - (1 if b else 0)
-        if b:
-            # longest-first truncation keeps both segments represented
-            while len(a) + len(b) > budget:
-                (a if len(a) >= len(b) else b).pop()
-        else:
-            a = a[:budget]
-        toks = [CLS] + a + [SEP] + (b + [SEP] if b else [])
-        types = [0] * (len(a) + 2) + [1] * (len(b) + 1 if b else 0)
-        ids = [self.vocab.get(t, self.vocab[UNK]) for t in toks]
-        mask = [1] * len(ids)
-        pad = max_len - len(ids)
-        ids += [self.vocab[PAD]] * pad
-        mask += [0] * pad
-        types += [0] * pad
-        return ids, mask, types
+        ids, n_a, _ = self._encode_row(text, pair, max_len, {})
+        n = len(ids)
+        pad = max_len - n
+        return (ids + [self.vocab[PAD]] * pad, [1] * n + [0] * pad,
+                [0] * n_a + [1] * (n - n_a) + [0] * pad)
 
     def encode_batch(
         self, texts: Sequence[str], pairs: Optional[Sequence[str]] = None,
         max_len: int = 128,
     ):
-        """Vectorized batch encode -> dict of (n, max_len) int32 arrays."""
-        ids, masks, types = [], [], []
+        """Batch encode -> dict of (n, max_len) int32 arrays, each row what
+        ``encode`` gives. Every distinct word of the call meets the
+        vocabulary once (the memo lives as long as the call)."""
+        n = len(texts)
+        ids = np.full((n, max_len), self.vocab[PAD], np.int32)
+        mask = np.zeros((n, max_len), np.int32)
+        types = np.zeros((n, max_len), np.int32)
+        memo: _WordMemo = {}
+        words = 0
         for i, t in enumerate(texts):
             p = pairs[i] if pairs is not None else None
-            a, m, ty = self.encode(str(t), p if p is None else str(p), max_len)
-            ids.append(a)
-            masks.append(m)
-            types.append(ty)
-        return {
-            "input_ids": np.asarray(ids, np.int32),
-            "attention_mask": np.asarray(masks, np.int32),
-            "token_type_ids": np.asarray(types, np.int32),
-        }
+            row, n_a, n_words = self._encode_row(
+                str(t), p if p is None else str(p), max_len, memo)
+            ids[i, :len(row)] = row
+            mask[i, :len(row)] = 1
+            types[i, n_a:len(row)] = 1
+            words += n_words
+        metrics.incr("tokenizer.words", words)
+        metrics.incr("tokenizer.word_memo_hits", words - len(memo))
+        return {"input_ids": ids, "attention_mask": mask,
+                "token_type_ids": types}
 
     # -- persistence -------------------------------------------------------
     def to_list(self) -> List[str]:
