@@ -893,9 +893,9 @@ ElasticStreamJob._coordinator_cls = ElasticCoordinator
 
 
 def elastic_summary() -> Dict[str, Any]:
-    """One-call readout of the elastic-streaming counters (the BENCH
-    ``elastic`` extra and the WebUI recovery line): rescale events and
-    latency, plus the current backpressure lag gauge."""
+    """One-call readout of the elastic-streaming counters (the WebUI
+    recovery line reads it): rescale events and latency, plus the current
+    backpressure lag gauge."""
     out: Dict[str, Any] = {
         "rescale_out": metrics.counter("recovery.rescale_out"),
         "rescale_in": metrics.counter("recovery.rescale_in"),

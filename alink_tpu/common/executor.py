@@ -25,8 +25,8 @@ another. This module replaces that walk with a real scheduler:
    same order.
 3. **Per-node trace** — every unit records wall time plus whatever phases
    the lower layers report (``transfer_s``/``compute_s`` from
-   ``common/streaming.py``) into ``common/metrics.py``; BENCH surfaces the
-   breakdown as the ``executor`` extra.
+   ``common/streaming.py``) into ``common/metrics.py``
+   (``executor_trace()`` / ``executor_phase_summary()``).
 4. **Fault tolerance** — failed units are retried under the central
    :class:`~alink_tpu.common.resilience.RetryPolicy` when the error is
    transient (``is_retryable``); this is safe because ``_executed`` is only

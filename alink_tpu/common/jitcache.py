@@ -1,7 +1,7 @@
 """Shape-stable execution: process-wide program cache, shape bucketing, AOT warmup.
 
 The platform's dominant cost on short jobs is not compute but compilation
-(BENCH r05: kmeans_iris 50.2s cold vs 0.35s warm). Three mechanisms cut the
+(PERF.md: ``setup_s`` on a checkout's first run). Three mechanisms cut the
 compile tax to a once-per-process (or, with the persistent XLA cache,
 once-per-machine) event:
 
@@ -40,8 +40,8 @@ once-per-machine) event:
 Observability: every first call of a program with a new shape signature is
 counted (``jit.trace`` / ``jit.compile``) and timed (global and per-kernel
 ``jitcache.*.compile_s`` timers, plus a ``compile_s`` phase on the active
-executor node trace). :func:`compile_summary` aggregates the lot for the
-BENCH ``compile`` extra.
+executor node trace). :func:`compile_summary` aggregates the lot
+(``job_report()`` carries it).
 
 Buffer donation: builders may return programs built with
 ``jax.jit(..., donate_argnums=...)`` (the DL train/MLM steps do — params
@@ -682,8 +682,7 @@ def prune_persistent_cache(cache_dir: Optional[str] = None,
 def persist_summary() -> Dict[str, Any]:
     """One-call persistence readout: knob state, on-disk entry count/bytes
     vs the cap, and the ``jit.persist_*`` counters. Embedded in
-    :func:`compile_summary` (the BENCH ``compile``/``coldstart`` extras) and
-    exported as gauges at ``/metrics``."""
+    :func:`compile_summary` and exported as gauges at ``/metrics``."""
     d = compile_cache_dir()
     out: Dict[str, Any] = {
         "enabled": d is not None,
@@ -912,7 +911,7 @@ def clear_kernel(kernel_id: str) -> int:
 def compile_summary() -> Dict[str, Any]:
     """Aggregate compile observability: program counts, jit.* counters, the
     program-cache hit rate, and per-kernel signature counts + compile-time
-    stats. Feeds the BENCH ``compile`` extra."""
+    stats."""
     with _lock:
         progs = list(_PROGRAMS.values())
     counters = metrics.counters("jit.")

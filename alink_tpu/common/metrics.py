@@ -521,7 +521,7 @@ def add_node_phase(key: str, seconds: float):
 def executor_trace() -> List[Dict[str, Any]]:
     """Per-node records of the last executed DAGs: one dict per node with
     ``op``/``wall_s`` plus any phases (``transfer_s``, ``compute_s``,
-    ``fused``) the node reported. Feeds the BENCH ``executor`` extra."""
+    ``fused``) the node reported."""
     return metrics.series("executor.node")
 
 

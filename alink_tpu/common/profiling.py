@@ -11,8 +11,8 @@ side of the ledger and joins it with the measured one:
    FLOPs, transcendentals, bytes accessed — runs on the first *readout*
    (:func:`profile_summary`, ``job_report()``, a ``/metrics`` scrape), by
    re-lowering the cached program on zeros of the recorded signature. That
-   keeps the execution path bit-identical and within noise of
-   profiling-off (the BENCH ``profiling`` extra audits the delta).
+   keeps the execution path bit-identical to profiling-off
+   (``tests/test_profiling.py``); its cost on the chip is not measured.
    ``ALINK_PROFILING=deep`` switches to eager capture at compile time and
    additionally runs ``Compiled.memory_analysis()`` for exact
    argument/output/temp/peak HBM; the default ``on`` mode estimates memory
@@ -771,7 +771,7 @@ def kernel_candidates(top: Optional[int] = None, *,
     capture or no warm timing yet) follow, ordered by wall time.
 
     Surfaced by ``profile_summary()`` (hence ``job_report()`` and
-    ``GET /api/profile``) and the BENCH ``kernels`` extra."""
+    ``GET /api/profile``)."""
     from ..native.kernels import covering, kernel_enabled, kernel_spec
 
     if resolve and profiling_enabled():
@@ -815,8 +815,7 @@ def profile_summary(top: Optional[int] = None, *,
     HBM watermark, a per-kernel table joining static XLA cost with
     measured exec timings into roofline verdicts, and the ranked
     ``candidates`` worst-offenders table. Feeds ``job_report()``,
-    ``GET /api/profile``, the ``alink_profile_*`` Prometheus gauges, and
-    the BENCH ``profiling``/``kernels`` extras."""
+    ``GET /api/profile`` and the ``alink_profile_*`` Prometheus gauges."""
     if resolve and profiling_enabled():
         resolve_pending()
         sample_device_memory()

@@ -906,9 +906,9 @@ def run_with_recovery(
 
 
 def recovery_summary() -> Dict[str, Any]:
-    """One-call readout of the recovery counters (the BENCH ``recovery``
-    extra): epochs committed, restarts absorbed, sink commits/replays,
-    chunks replayed-and-skipped, snapshot/commit time."""
+    """One-call readout of the recovery counters: epochs committed,
+    restarts absorbed, sink commits/replays, chunks replayed-and-skipped,
+    snapshot/commit time."""
     out: Dict[str, Any] = dict(metrics.counters("recovery."))
     out.update(metrics.counters("checkpoint."))
     for timer in ("recovery.snapshot_s", "recovery.commit_s",

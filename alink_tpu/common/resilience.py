@@ -32,7 +32,7 @@ Knobs (env):
 
 Every retry/degradation/dead-letter event lands in ``common/metrics.py``
 counters (``resilience.*``); :func:`resilience_summary` is the one-call
-readout BENCH surfaces as the ``resilience`` extra.
+readout.
 """
 
 from __future__ import annotations
@@ -333,9 +333,9 @@ dead_letters = DeadLetterBuffer()
 
 
 def resilience_summary() -> Dict[str, Any]:
-    """One-call readout of every resilience counter (the BENCH
-    ``resilience`` extra): retries by layer, defusions, serial
-    degradations, breaker trips, dead-letter volume, injected faults."""
+    """One-call readout of every resilience counter: retries by layer,
+    defusions, serial degradations, breaker trips, dead-letter volume,
+    injected faults."""
     out: Dict[str, Any] = dict(metrics.counters("resilience."))
     out.update(metrics.counters("faults."))
     dropped = metrics.counter("metrics.dropped")

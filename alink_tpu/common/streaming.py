@@ -98,8 +98,8 @@ def stream_map(
     accumulates ``transfer_s`` / ``wait_s`` (consumer stall on the
     in-flight transfer — ~0 when the pipeline overlaps) / ``compute_s`` /
     ``batches``; the same numbers also land on the active executor node
-    trace, so BENCH and the per-node breakdown see the split without
-    extra plumbing.
+    trace, so the per-node breakdown sees the split without extra
+    plumbing.
 
     Transfers retry under the central
     :class:`~alink_tpu.common.resilience.RetryPolicy` when the failure is

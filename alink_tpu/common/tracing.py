@@ -38,8 +38,8 @@ Everything is gated behind ``ALINK_TRACING`` (default **on**; ``off``
 restores zero-span execution). The gate is read per span open, so a test or
 a latency-critical section can flip it at runtime. Tracing NEVER changes
 results — the bit-parity contract is CI-pinned in
-``tests/test_observability.py`` and the measured overhead budget (<3% wall
-on kmeans_iris) is tracked by the BENCH ``observability`` extra.
+``tests/test_observability.py``; what tracing costs on the chip is in
+PERF.md (PR 26).
 """
 
 from __future__ import annotations
@@ -689,8 +689,8 @@ def chrome_trace(trace_id: Optional[str] = None) -> Dict[str, Any]:
     a stitched fleet trace reads frontdoor-over-here, batcher-over-there.
     Local spans stay on the canonical ``pid: 1`` lane — single-process
     output is byte-stable. Load the file via ui.perfetto.dev or
-    chrome://tracing. ``bench.py --trace-artifact`` writes one per
-    round."""
+    chrome://tracing; :func:`write_chrome_trace` writes it to a
+    path."""
     spans = tracer.spans(trace_id)
     events: List[Dict[str, Any]] = [{
         "ph": "M", "pid": 1, "tid": 0, "name": "process_name",

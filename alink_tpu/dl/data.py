@@ -10,7 +10,7 @@ for the reference's downloadable BERT resources, BertResources.java):
 - ``data/bert_tiny_sst/`` — a staged HF-layout checkpoint directory
   (config.json + model.safetensors + vocab.txt) for ingest tests.
 
-These loaders are the one sanctioned way to read them: bench, tests and
+These loaders are the one sanctioned way to read them: tests and
 examples all consume the same splits, so "real-text holdout accuracy"
 means the same rows everywhere.
 
@@ -247,8 +247,8 @@ class CorpusStream:
 def sst2_split(seed: int = 0, holdout: float = 0.2,
                path: Optional[str] = None):
     """Deterministic train/holdout split of the sst2 rows:
-    ``(train_texts, train_y, hold_texts, hold_y)`` — the split bench and
-    tests both report against."""
+    ``(train_texts, train_y, hold_texts, hold_y)`` — the split tests and
+    examples both report against."""
     texts, y = load_sst2(path)
     rng = np.random.default_rng(seed)
     perm = rng.permutation(len(texts))

@@ -168,7 +168,7 @@ def _model_key(model) -> tuple:
 def make_train_step(model, tx, loss_of, *, weighted: bool = False,
                     cache_key: Any = None):
     """One optimizer step, resident in the process-wide ProgramCache —
-    shared by train_model, bench, and the multichip dryrun.
+    shared by train_model, the benchmark, and the multichip dryrun.
     ``loss_of(logits, y[, w]) -> scalar``.
 
     ``variables`` is the full flax variables dict; non-"params" collections

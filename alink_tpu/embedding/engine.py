@@ -79,11 +79,10 @@ def collective_bytes_probe(m: int, engine: str, *, hot_rows: int = 0,
                            rows: int = 64, dim: int = 16, batch: int = 32,
                            negatives: int = 3, zipf_a: float = 1.2) -> int:
     """Per-device steady-state collective bytes of ONE compiled SGNS
-    training program on an ``m``-device mesh — the canonical weak-scaling
-    probe shared by ``tests/test_weak_scaling.py`` and the BENCH ``huge``
-    extra (one recipe, one set of constants, both consumers measure the
-    same program). Weak scaling: rows-per-shard, per-device batch, and dim
-    stay constant while the vocabulary (``rows·m``) grows with the mesh;
+    training program on an ``m``-device mesh — the weak-scaling probe of
+    ``tests/test_weak_scaling.py`` (one recipe, one set of constants).
+    Weak scaling: rows-per-shard, per-device batch, and dim stay
+    constant while the vocabulary (``rows·m``) grows with the mesh;
     the frequency table is Zipf-ish so the hot-key cache has a head to
     serve. Compile-only (``_lower_only``): nothing executes."""
     import jax

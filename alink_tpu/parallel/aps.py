@@ -199,7 +199,7 @@ def apply_gathered_replicated(table, ids, grads, axis: str, num_rows: int,
 
 def aps_summary() -> dict:
     """One-call health readout of the APS exchange + hot-key cache counters
-    (the block the WebUI profile panel and bench read)."""
+    (the block the WebUI profile panel reads)."""
     from ..common.metrics import metrics
 
     hits = metrics.counter("aps.cache_hits")

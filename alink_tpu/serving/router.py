@@ -1041,7 +1041,7 @@ def default_server() -> ModelServer:
 
 
 def serving_summary(server: Optional[ModelServer] = None) -> Dict[str, Any]:
-    """One-call readout (the BENCH ``serving`` extra reads through this):
+    """One-call readout (the WebUI's ``serving`` endpoint reads it):
     per-model stats, latency histograms, ``serving.*`` counters, and the
     jit trace/compile counters active during the serving window. Reads the
     given server, defaulting to the process-wide one (empty stats if none

@@ -6,11 +6,11 @@
    model.safetensors + vocab.txt);
 3. fine-tune through ``BertTextClassifierTrainBatchOp`` with
    ``checkpointFilePath`` on the ``data/sst2_mini.csv`` train split;
-4. report holdout accuracy on the held-out rows — the same split the
-   BENCH ``bert_text_quality`` metric of record uses.
+4. report holdout accuracy on the held-out rows (``dl.data.sst2_split``,
+   the split ``tests/test_train_async.py`` pins).
 
-A CPU demo: runs in a few minutes at this size. ``bench.py`` runs the
-full-budget version.
+A CPU demo: runs in a few minutes at this size; the full-budget version is
+``tests/test_train_async.py::test_pretrain_finetune_real_text_e2e``.
 """
 
 import argparse

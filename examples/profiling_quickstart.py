@@ -1,16 +1,13 @@
-"""Performance observatory quick start: per-kernel XLA cost accounting,
-roofline attribution, and the benchstats perf gate
-(alink_tpu/common/profiling.py + benchstats.py — see README
-"Profiling & perf regression").
+"""Performance observatory quick start: per-kernel XLA cost accounting and
+roofline attribution (alink_tpu/common/profiling.py — see README
+"Profiling").
 
-Runs a fitted pipeline and a fused mapper-chain DAG with profiling on,
+Runs a fitted pipeline and a fused mapper-chain DAG with profiling on and
 prints the per-kernel cost/roofline table every readout surface shares
 (job_report()["profile"], GET /api/profile, alink_profile_* gauges at
-/metrics), and demos the in-process regression gate: a same-config pair
-reads no-change, a synthetic 20% slowdown is flagged."""
+/metrics)."""
 
 import os
-import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")    # drop on a TPU host
 os.environ.setdefault("ALINK_PROFILING", "on")   # the default; explicit here
@@ -18,7 +15,6 @@ os.environ.setdefault("ALINK_PROFILING", "on")   # the default; explicit here
 import numpy as np  # noqa: E402
 
 from alink_tpu import job_report, profile_summary  # noqa: E402
-from alink_tpu.common.benchstats import perf_gate  # noqa: E402
 from alink_tpu.common.mtable import AlinkTypes, MTable  # noqa: E402
 from alink_tpu.mapper.base import BlockKernelMapper  # noqa: E402
 from alink_tpu.operator.batch import TableSourceBatchOp  # noqa: E402
@@ -90,18 +86,3 @@ prof = report.get("profile", {})
 print(f"\njob_report(): {len(report.get('spans', []))} spans, "
       f"profile table of {len(prof.get('kernels', []))} kernels "
       f"attached under report['profile']")
-
-# -- 4. the perf gate: noise passes, a 20% slowdown is flagged ---------------
-same = perf_gate(lambda: time.sleep(0.004), lambda: time.sleep(0.004),
-                 repeats=7)
-slow = perf_gate(lambda: time.sleep(0.004), lambda: time.sleep(0.0048),
-                 repeats=7)
-print(f"\nperf gate, same config:    {same['verdict']} "
-      f"(delta {same['delta_pct']}%, gate {same['gate_pct']}%)")
-print(f"perf gate, +20% slowdown:  {slow['verdict']} "
-      f"(delta {slow['delta_pct']}%, gate {slow['gate_pct']}%)")
-assert same["verdict"] == "no-change" and slow["verdict"] == "regression"
-
-print("\ncompare two saved rounds with: "
-      "python bench.py --compare OLD.json NEW.json "
-      "(schema: docs/bench_schema.md)")

@@ -18,6 +18,7 @@ from alink_tpu.operator.batch import (
 from alink_tpu.operator.stream import (
     OnnxModelPredictStreamOp,
     TableSourceStreamOp,
+    TorchModelPredictStreamOp,
 )
 
 
@@ -161,7 +162,8 @@ def test_onnx_predict_stream(tmp_path):
     np.testing.assert_allclose(got, ref(X.astype(np.float32)), atol=1e-5)
 
 
-def test_torch_export_predict_op(tmp_path):
+@pytest.mark.parametrize("kind", ["batch", "stream"])
+def test_torch_export_predict_op(tmp_path, kind):
     import torch
     import torch.nn as nn
 
@@ -176,11 +178,18 @@ def test_torch_export_predict_op(tmp_path):
 
     X = np.random.RandomState(4).randn(10, 3)
     t = MTable({"a": X[:, 0], "b": X[:, 1], "c": X[:, 2]})
-    op = TorchModelPredictBatchOp(
-        modelPath=path, selectedCols=["a", "b", "c"], outputCols=["score"],
-    ).link_from(TableSourceBatchOp(t))
-    assert op.schema.type_of("score") == AlinkTypes.DOUBLE
+    params = dict(modelPath=path, selectedCols=["a", "b", "c"],
+                  outputCols=["score"])
+    if kind == "batch":
+        op = TorchModelPredictBatchOp(**params).link_from(
+            TableSourceBatchOp(t))
+        assert op.schema.type_of("score") == AlinkTypes.DOUBLE
+    else:  # three micro-batches, the last one ragged
+        op = TorchModelPredictStreamOp(predictBatchSize=4, **params).link_from(
+            TableSourceStreamOp(t, chunkSize=4))
     out = op.collect()
+    assert out.num_rows == len(X)
+    assert out.schema.type_of("score") == AlinkTypes.DOUBLE
     with torch.no_grad():
         ref = model(torch.from_numpy(X.astype(np.float32))).numpy()[:, 0]
     np.testing.assert_allclose(

@@ -1,8 +1,7 @@
-"""Performance observatory (common/profiling.py + common/benchstats.py):
-XLA cost/memory capture at ProgramCache compiles, roofline attribution,
-registry-survives-eviction, profiling on/off bit-parity, the Prometheus
-gauge surface, the /api/profile endpoint, and the benchstats regression
-gate (in-process perf gate + BENCH-file compare).
+"""Performance observatory (common/profiling.py): XLA cost/memory capture
+at ProgramCache compiles, roofline attribution, registry-survives-eviction,
+profiling on/off bit-parity, the Prometheus gauge surface and the
+/api/profile endpoint.
 
 Container-safe: pipelines are built from StandardScaler + VectorAssembler
 + NaiveBayes and block-kernel mapper DAGs only (no shard_map fit paths).
@@ -11,7 +10,6 @@ order-independent in the shared process."""
 
 import json
 import os
-import time
 import uuid
 
 import numpy as np
@@ -378,171 +376,3 @@ def test_api_profile_endpoint(monkeypatch):
     assert "elastic" in body["recovery"]
     assert {"rescale_out", "rescale_in",
             "rescale_aborted"} <= set(body["recovery"]["elastic"])
-
-
-# ---------------------------------------------------------------------------
-# benchstats: in-process perf gate + BENCH-file regression compare
-# ---------------------------------------------------------------------------
-
-
-def test_trimmed_mean_and_ci():
-    from alink_tpu.common.benchstats import mean_ci, trimmed_mean
-
-    xs = [1.0, 1.0, 1.0, 1.0, 100.0]      # one interference outlier
-    assert trimmed_mean(xs, trim=0.2) == 1.0
-    m, half = mean_ci([1.0, 1.1, 0.9, 1.0, 1.0, 1.0, 1.0], trim=0.0)
-    assert m == pytest.approx(1.0, rel=0.05)
-    assert half >= 0.0
-    m1, h1 = mean_ci([5.0])
-    assert (m1, h1) == (5.0, 0.0)
-
-
-def test_perf_gate_noise_passes_and_slowdown_flagged():
-    """The CI perf-gate smoke: two same-config measurements read no-change;
-    a synthetic 20% slowdown is flagged as a significant regression."""
-    from alink_tpu.common.benchstats import perf_gate
-
-    same = perf_gate(lambda: time.sleep(0.004), lambda: time.sleep(0.004),
-                     repeats=9)
-    assert same["verdict"] == "no-change"
-    assert not same["significant"]
-
-    slow = perf_gate(lambda: time.sleep(0.004), lambda: time.sleep(0.0048),
-                     repeats=9)
-    assert slow["verdict"] == "regression"
-    assert slow["significant"]
-    assert slow["delta_pct"] > 8.0
-
-    faster = perf_gate(lambda: time.sleep(0.0048), lambda: time.sleep(0.004),
-                       repeats=9)
-    assert faster["verdict"] == "improvement"
-
-
-def test_metric_direction_classification():
-    from alink_tpu.common.benchstats import metric_direction
-
-    assert metric_direction("value") == "higher"
-    assert metric_direction("extras.softmax_mnist.samples_per_sec") == "higher"
-    assert metric_direction("extras.bert_mfu.mfu") == "higher"
-    assert metric_direction("extras.kmeans_iris.wall_clock_s") == "lower"
-    assert metric_direction("extras.serving.request_p99_ms") == "lower"
-    assert metric_direction("extras.gbdt_train.trees") is None
-    # signed noise-centered percentages must never be flagged: a relative
-    # delta between 0.9% and 2.4% overhead is meaningless
-    assert metric_direction("extras.profiling.overhead_pct") is None
-    assert metric_direction("extras.profiling.overhead_ci_pct") is None
-    assert metric_direction(
-        "extras.profiling.perf_gate.slowdown_detail.delta_pct") is None
-    # roofline efficiency (kernels extra): higher is better, but it is
-    # derived from a measured wall so it gets the wall-noise threshold
-    from alink_tpu.common.benchstats import WALL_THRESHOLD, metric_threshold
-
-    assert metric_direction("extras.kernels.sgns.efficiency_after") == "higher"
-    assert metric_threshold(
-        "extras.kernels.sgns.efficiency_after") == WALL_THRESHOLD
-    assert metric_direction("extras.kernels.attention.parity_max_diff") is None
-    assert metric_direction("extras.kernels.sgns.pallas_wall_s") == "lower"
-
-
-def test_compare_bench_files_flags_bert_regression(tmp_path):
-    """Acceptance: ``--compare`` over two archived-layout rounds flags a
-    12% drop of the headline samples/s as a significant regression, while
-    a same-config (self) compare reports no regressions."""
-    from alink_tpu.common.benchstats import compare_bench_files
-
-    def round_file(n, value, kmeans_cold_s):
-        parsed = {
-            "metric": "bert_base_finetune_throughput_per_chip",
-            "value": value,
-            "unit": "samples/sec/chip (seq128, bs32, bf16)",
-            "extras": {
-                "kmeans_iris": {"wall_clock_s": kmeans_cold_s,
-                                "wall_clock_warm_s": 0.4,
-                                "cluster_purity": 0.8933},
-                "gbdt_train": {"samples_per_sec": 1.5e6, "trees": 20,
-                               "train_accuracy": 0.9936},
-            },
-        }
-        path = tmp_path / f"round_{n}.json"
-        path.write_text(json.dumps(
-            {"n": n, "cmd": "python bench.py", "rc": 0, "tail": "",
-             "parsed": parsed}))
-        return str(path)
-
-    old = round_file(4, 2163.9, 2.7)
-    new = round_file(5, 1897.7, 2.8)
-    rep = compare_bench_files(old, new)
-    assert rep["verdict"] == "regression"
-    flagged = {e["metric"] for e in rep["regressions"]}
-    assert "value" in flagged          # the bert samples/s/chip drop
-    bert = next(e for e in rep["regressions"] if e["metric"] == "value")
-    assert bert["delta_pct"] < -10.0
-    assert bert["direction"] == "higher"
-
-    same = compare_bench_files(old, old)
-    assert same["verdict"] == "ok"
-    assert same["regressions"] == []
-
-
-def test_compare_bench_files_handles_raw_and_wrapped(tmp_path):
-    from alink_tpu.common.benchstats import compare_bench_files
-
-    raw = {"metric": "m", "value": 100.0,
-           "extras": {"w": {"samples_per_sec": 50.0, "wall_clock_s": 2.0,
-                            "note": "text", "flag": True,
-                            "trace": [1, 2, 3]}}}
-    wrapped = {"n": 2, "parsed": {
-        "metric": "m", "value": 80.0,
-        "extras": {"w": {"samples_per_sec": 50.5, "wall_clock_s": 2.1}}}}
-    p1, p2 = tmp_path / "a.json", tmp_path / "b.json"
-    p1.write_text(json.dumps(raw))
-    p2.write_text(json.dumps(wrapped))
-    rep = compare_bench_files(str(p1), str(p2))
-    by_metric = {e["metric"]: e for e in rep["regressions"]}
-    assert "value" in by_metric                       # -20% throughput
-    names = {e["metric"] for e in rep["regressions"]
-             + rep["improvements"]}
-    assert "extras.w.samples_per_sec" not in names    # +1% is noise
-    assert rep["metrics_compared"] == 3               # text/bool/list skipped
-    assert rep["platform_change"] is None             # no device evidence
-
-
-def test_compare_bench_files_platform_change_demotes_hw_metrics(tmp_path):
-    """A round pair from DIFFERENT accelerators (TPU round vs CPU
-    container) must not false-flag the hardware swap as a code regression:
-    hardware-bound perf metrics demote to the loud ``platform-change``
-    verdict, while hardware-independent quality metrics keep gating —
-    the r05 (TPU) → r06 (CPU) handover case."""
-    from alink_tpu.common.benchstats import (compare_bench_files,
-                                             round_device_kind)
-
-    def doc(kind, sps, acc):
-        return {"metric": "m", "value": sps, "extras": {
-            "bert_mfu": {"device_kind": kind},
-            "w": {"samples_per_sec": sps, "accuracy_holdout": acc}}}
-
-    tpu = tmp_path / "tpu.json"
-    cpu = tmp_path / "cpu.json"
-    tpu.write_text(json.dumps(doc("TPU v5 lite", 1900.0, 0.96)))
-    # 400x slower chip, same model quality
-    cpu.write_text(json.dumps(doc("cpu", 4.4, 0.958)))
-    assert round_device_kind(json.loads(tpu.read_text())) == "TPU v5 lite"
-    rep = compare_bench_files(str(tpu), str(cpu))
-    assert rep["platform_change"] == {"old": "TPU v5 lite", "new": "cpu"}
-    assert rep["regressions"] == []                   # hw swap ≠ regression
-    assert rep["platform_demoted"] >= 2               # value + samples/sec
-    assert rep["verdict"] == "ok"
-    # ... but a QUALITY drop still gates across the platform change
-    cpu.write_text(json.dumps(doc("cpu", 4.4, 0.55)))
-    rep = compare_bench_files(str(tpu), str(cpu))
-    assert any(e["metric"] == "extras.w.accuracy_holdout"
-               for e in rep["regressions"])
-    assert rep["verdict"] == "regression"
-    # same-platform rounds: full gating, exactly as before
-    fast = tmp_path / "fast.json"
-    slow = tmp_path / "slow.json"
-    fast.write_text(json.dumps(doc("cpu", 100.0, 0.9)))
-    slow.write_text(json.dumps(doc("cpu", 50.0, 0.9)))
-    rep = compare_bench_files(str(fast), str(slow))
-    assert rep["platform_change"] is None
-    assert any(e["metric"] == "value" for e in rep["regressions"])

@@ -652,20 +652,8 @@ def test_alk111_fires_through_server_load(fitted, tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# satellites: benchstats direction, onnx wrap program sharing
+# satellites: onnx wrap program sharing
 # ---------------------------------------------------------------------------
-
-
-def test_metric_direction_band_readouts_are_directionless():
-    from alink_tpu.common.benchstats import metric_direction
-
-    assert metric_direction("serving.precision.accuracy_delta") is None
-    assert metric_direction("serving.precision.accuracy_band") is None
-    # the surrounding precision block keeps its usual classifications
-    assert metric_direction("serving.precision.int8_rows_per_sec") == \
-        "higher"
-    assert metric_direction("serving.precision.int8_request_p99_ms") == \
-        "lower"
 
 
 def test_onnx_wrap_positional_shares_programs():

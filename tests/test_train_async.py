@@ -277,7 +277,7 @@ def test_pretrain_finetune_real_text_smoke(tmp_path):
 
 @pytest.mark.slow
 def test_pretrain_finetune_real_text_e2e(tmp_path):
-    """The metric-of-record configuration (bench_bert_quality): full
+    """The full-budget configuration: full
     reviews corpus, 5 MLM epochs, 14 fine-tune epochs — real-text holdout
     accuracy must clearly beat the 0.5 coin-flip floor."""
     from alink_tpu.dl.data import load_reviews, sst2_split

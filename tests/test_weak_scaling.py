@@ -188,8 +188,7 @@ def test_aps_gather_reference_collective_bytes_grow(mode):
 # ---------------------------------------------------------------------------
 
 def _sgns_loop_bytes(m, engine, hot=0):
-    """The canonical probe (shared with the BENCH `huge` extra — one
-    recipe, so the CI pin and the bench measure the same program)."""
+    """The probe kept beside the engine (`collective_bytes_probe`)."""
     from alink_tpu.embedding.engine import collective_bytes_probe
 
     return collective_bytes_probe(m, engine, hot_rows=hot)
