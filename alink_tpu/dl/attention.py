@@ -27,7 +27,9 @@ import jax.numpy as jnp
 
 from ..parallel.mesh import AXIS_SEQ
 from ..parallel.shardmap import axis_size, pvary, shard_map
-from .attn_pallas import flash_block_update, use_attn_pallas
+from ..common.metrics import metrics
+from .attn_pallas import (flash_block_update, fused_attention,
+                          use_attn_pallas, use_fused_attention)
 
 _NEG_INF = -1e30
 
@@ -55,6 +57,32 @@ def full_attention(
         s = jnp.where(cm[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def packed_attention(qkv: jax.Array, mask: Optional[jax.Array] = None, *,
+                     num_heads: int) -> jax.Array:
+    """The default attention of an encoder layer, from the packed projection.
+
+    qkv: (B, S, 3, H*D), q, k and v side by side, each with its heads side
+    by side; mask: (B, S) with 1 = valid key. Returns (B, S, H*D).
+
+    One algorithm, two programs: the fused core (attn_pallas.py: one kernel
+    each way, scores in VMEM only) where its gate and the call's own shapes
+    allow it (``use_fused_attention``: a one-chip TPU process, a lane-aligned
+    length from the measured threshold up, head dimension 64 or 128), else
+    :func:`full_attention`. Which one a layer was traced down is counted
+    (``attention.fused_traces`` / ``attention.xla_traces``, at trace time)."""
+    b, s, _, hd = qkv.shape
+    d = hd // num_heads
+    if use_fused_attention(s, num_heads, d):
+        from ..native.kernels import interpret_mode
+
+        metrics.incr("attention.fused_traces")
+        return fused_attention(qkv, mask, num_heads=num_heads,
+                               interpret=interpret_mode())
+    metrics.incr("attention.xla_traces")
+    q, k, v = (qkv[:, :, i].reshape(b, s, num_heads, d) for i in range(3))
+    return full_attention(q, k, v, mask).reshape(b, s, hd)
 
 
 def _online_softmax_update(o, m, l, s, v, p_dtype):

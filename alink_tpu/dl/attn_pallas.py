@@ -208,3 +208,282 @@ def flash_block_update(q, k, v, kvalid, qk_ok, o, m, l, *, scale: float,
     before a backward kernel exists."""
     return _flash()(q, k, v, kvalid, qk_ok, o, m, l, float(scale),
                      bool(interpret))
+
+
+# ---------------------------------------------------------------------------
+# fused attention core: whole sequence in one cell, forward and backward
+# ---------------------------------------------------------------------------
+
+# The default (non-blockwise, non-ring) attention of ``SelfAttention`` takes
+# the fused core from this length on: the shortest at which it won both
+# directions on the chip. One TPU v5e, the BERT-base encoder
+# (``TransformerEncoder``, default configuration, bfloat16) at 16,384 tokens a
+# step, XLA -> kernel in ms, two calls (docs/chip_calls/pr31/threshold.py;
+# PERF.md section 6, PR 31):
+#   rows x positions   forward                       forward + backward
+#   128 x 128          22.42 -> 22.06, 22.32 -> 21.95   68.42 -> 66.43, 67.56 -> 65.57
+#    64 x 256          25.72 -> 21.66, 25.56 -> 21.53   79.65 -> 64.60, 78.79 -> 63.63
+#    32 x 512          32.63 -> 21.19, 32.49 -> 21.06  109.31 -> 65.57, 108.60 -> 64.68
+#    16 x 1024         48.85 -> 24.83, 48.71 -> 24.70  163.95 -> 76.07, 163.24 -> 75.21
+# At 128 the core alone is even forward (0.488 -> 0.489 ms a layer) and what
+# the encoder gains is the copies of q, k, v that go with it. 128 is also the
+# shortest lane-aligned length, so today the rule below takes every length it
+# can hold; the constant is where a later reading would move it.
+_FUSED_MIN_SEQ = 128
+# one head's float32 score tile is S*S*4 bytes and the backward pass holds
+# four of them: 16 MB at 1024 positions, inside the limit asked for below
+_FUSED_MAX_SEQ = 1024
+_FUSED_VMEM_BYTES = 100 * 1024 * 1024
+_STAT_ROWS = 8   # sublane tile of the (heads-in-cell, S) statistics block
+
+
+def use_fused_attention(seq_len: int, num_heads: int, head_dim: int, *,
+                        causal: bool = False) -> bool:
+    """Whether the default attention takes the fused core: decided from the
+    call's own shapes and the kernel's gate, nothing else. Head dimension 64
+    (two heads fill the 128 lanes) or 128; a lane-aligned length between the
+    threshold and what one cell's VMEM holds; not causal."""
+    return (not causal
+            and head_dim in (64, 128)
+            and (num_heads * head_dim) % _LANES == 0
+            and seq_len % _LANES == 0
+            and _FUSED_MIN_SEQ <= seq_len <= _FUSED_MAX_SEQ
+            and use_attn_pallas())
+
+
+def _head_lanes(lane, h: int, d: int):
+    """Lanes of head ``h`` in a 128-lane group of ``128 // d`` heads; None
+    where one head fills the group."""
+    if d == _LANES:
+        return None
+    return (lane >= h * d) & (lane < (h + 1) * d)
+
+
+def _only(x, lanes):
+    import jax.numpy as jnp
+
+    return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
+
+
+def _pick(lanes, new, old):
+    import jax.numpy as jnp
+
+    return new if lanes is None or old is None else jnp.where(lanes, new, old)
+
+
+def _mask_fill(mrow):
+    """What a masked score is set to: the path's -1e30, or 0 for a row whose
+    keys are all masked, so that such a row attends evenly over every key as
+    ``full_attention``'s softmax of equal scores does, and its log-sum-exp
+    stays a number float32 holds."""
+    import jax.numpy as jnp
+
+    return jnp.where(mrow.max(axis=-1, keepdims=True) > 0, _NEG_INF, 0.0)
+
+
+def _rows_of(cols):
+    """Per-head column statistics, packed one head a lane in ``(S, 128)``,
+    as rows ``(8, S)``: lane-dense in memory, and the form the backward
+    kernel reads, which works on transposed scores."""
+    return cols.T[:_STAT_ROWS]
+
+
+def _fused_fwd_kernel(qkv_ref, m_ref, o_ref, lse_ref, *, d: int,
+                      scale: float):
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v = qkv_ref[0], qkv_ref[1], qkv_ref[2]          # (S, 128)
+    s_len = q.shape[0]
+    mrow = m_ref[...]                                      # (1, S)
+    fill = _mask_fill(mrow)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (s_len, _LANES), 1)
+    out, stats = None, jnp.zeros((s_len, _LANES), jnp.float32)
+    for h in range(_LANES // d):
+        lanes = _head_lanes(lane, h, d)
+        # the other head's lanes zeroed: the 128-deep contraction is this
+        # head's 64-deep one, at the same cost on the matrix unit
+        s = jax.lax.dot_general(
+            _only(q, lanes), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # (Sq, Sk)
+        s = jnp.where(mrow > 0, s, fill)
+        m = s.max(axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = p.sum(axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # (Sq, 128)
+        out = _pick(lanes, pv / l, out)
+        stats = jnp.where(lane == h, m + jnp.log(l), stats)
+    o_ref[...] = out.astype(o_ref.dtype)
+    lse_ref[...] = _rows_of(stats)
+
+
+def _fused_bwd_kernel(qkv_ref, m_ref, o_ref, do_ref, lse_ref, dqkv_ref, *,
+                      d: int, scale: float):
+    """Scores transposed, keys down and queries across: the log-sum-exp and
+    rowsum(dO o O) are rows, dV = P^T dO and dK = dS^T Q are plain products,
+    and only dQ contracts over the leading axis."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v = qkv_ref[0], qkv_ref[1], qkv_ref[2]          # (S, 128)
+    do = do_ref[...]
+    s_len, dtype = q.shape[0], q.dtype
+    mrow = m_ref[...]                                      # (1, S)
+    fill = _mask_fill(mrow)
+    # the key mask down the sublanes, equal in every lane
+    kcol = jnp.broadcast_to(mrow.astype(jnp.float32), (_LANES, s_len)).T
+    key_ok, key_ok1 = kcol > 0, kcol[:, :1] > 0            # (Sk, 128), (Sk, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (s_len, _LANES), 1)
+    dd = do.astype(jnp.float32) * o_ref[...].astype(jnp.float32)
+    delta = jnp.zeros((s_len, _LANES), jnp.float32)
+    for h in range(_LANES // d):
+        lanes = _head_lanes(lane, h, d)
+        delta = jnp.where(
+            lane == h, _only(dd, lanes).sum(axis=-1, keepdims=True), delta)
+    delta = _rows_of(delta)                                # (8, Sq)
+    lse = lse_ref[...]                                     # (8, Sq)
+    # a masked key's score does not depend on q or k (full_attention's
+    # `where`); p is 0 there but for a row with every key masked
+    k_valid = jnp.where(key_ok, k, jnp.zeros_like(k))
+    dq = dk = dv = None
+    for h in range(_LANES // d):
+        lanes = _head_lanes(lane, h, d)
+        st = jax.lax.dot_general(
+            _only(k, lanes), q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # (Sk, Sq)
+        st = jnp.where(key_ok1, st, fill)
+        pt = jnp.exp(st - lse[h:h + 1])
+        dv = _pick(lanes, jax.lax.dot_general(
+            pt.astype(dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32), dv)
+        dpt = jax.lax.dot_general(
+            _only(v, lanes), do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # (Sk, Sq)
+        dst = (pt * (dpt - delta[h:h + 1])).astype(dtype)
+        dk = _pick(lanes, jax.lax.dot_general(
+            dst, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32), dk)
+        dq = _pick(lanes, jax.lax.dot_general(
+            dst, k_valid, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32), dq)
+    dk = jnp.where(key_ok, dk * scale, 0.0)
+    for i, part in enumerate((dq * scale, dk, dv)):
+        dqkv_ref[i] = part.astype(dqkv_ref.dtype)
+
+
+def _fused_call(name: str, kernel, qkv, num_heads: int, interpret: bool,
+                ins, out):
+    """One of the two kernels over the grid (batch row b, lane group g).
+    ``ins`` and ``out`` name each operand's block: ``packed``, q, k and v (or
+    their gradients) as one (3, S, 128) block of (B, 3, S, H*D); ``slab``, o
+    or dO (S, 128) of (B, S, H*D); ``mask``, the keys' (1, S); ``stat``, the
+    cell's (8, S) statistics of (B, H*D/128, 8, S). ``out`` pairs each
+    block's name with the array's ``ShapeDtypeStruct``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, _, s_len, hd = qkv.shape
+    d = hd // num_heads
+    specs = {
+        "packed": pl.BlockSpec((None, 3, s_len, _LANES),
+                               lambda b, g: (b, 0, 0, g)),
+        "slab": pl.BlockSpec((None, s_len, _LANES), lambda b, g: (b, 0, g)),
+        "mask": pl.BlockSpec((None, 1, s_len), lambda b, g: (b, 0, 0)),
+        "stat": pl.BlockSpec((None, None, _STAT_ROWS, s_len),
+                             lambda b, g: (b, g, 0, 0)),
+    }
+    return pl.pallas_call(
+        functools.partial(kernel, d=d, scale=float(d) ** -0.5),
+        grid=(b, hd // _LANES),
+        in_specs=[specs[n] for n, _ in ins],
+        out_specs=[specs[n] for n, _ in out],
+        out_shape=[shape for _, shape in out],
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_FUSED_VMEM_BYTES),
+        interpret=interpret,
+        name=name,
+    )(*(x for _, x in ins))
+
+
+def _fused_forward(qkv, mask, num_heads: int, interpret: bool):
+    """qkv: (B, 3, S, H*D), heads side by side in each of q, k, v; mask:
+    (B, 1, S) int32. Returns o (B, S, H*D) and the rows' log-sum-exp
+    (B, H*D/128, 8, S), one head a row."""
+    import jax
+    import jax.numpy as jnp
+
+    b, _, s_len, hd = qkv.shape
+    return _fused_call(
+        "attn_pallas_fwd", _fused_fwd_kernel, qkv, num_heads, interpret,
+        [("packed", qkv), ("mask", mask)],
+        [("slab", jax.ShapeDtypeStruct((b, s_len, hd), qkv.dtype)),
+         ("stat", jax.ShapeDtypeStruct((b, hd // _LANES, _STAT_ROWS, s_len),
+                                       jnp.float32))])
+
+
+def _fused_backward(qkv, mask, o, lse, do, num_heads: int, interpret: bool):
+    import jax
+
+    return _fused_call(
+        "attn_pallas_bwd", _fused_bwd_kernel, qkv, num_heads, interpret,
+        [("packed", qkv), ("mask", mask), ("slab", o), ("slab", do),
+         ("stat", lse)],
+        [("packed", jax.ShapeDtypeStruct(qkv.shape, qkv.dtype))])[0]
+
+
+@functools.cache
+def _build_fused():
+    """The differentiable core under a jit of its own, built once: the layers
+    of a model call one function object with equal shapes, so a program's
+    trace holds the two kernels' traces and lowerings once, not once a layer
+    (the compiler inlines the calls: the compiled program is the same)."""
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+    def fused(qkv, mask, num_heads, interpret):
+        return _fused_forward(qkv, mask, num_heads, interpret)[0]
+
+    def fwd(qkv, mask, num_heads, interpret):
+        o, lse = _fused_forward(qkv, mask, num_heads, interpret)
+        return o, (qkv, mask, o, lse)
+
+    def bwd(num_heads, interpret, res, do):
+        qkv, mask, o, lse = res
+        return _fused_backward(qkv, mask, o, lse, do, num_heads,
+                               interpret), None
+
+    fused.defvjp(fwd, bwd)
+    return jax.jit(fused, static_argnums=(2, 3))
+
+
+def fused_attention(qkv, mask=None, *, num_heads: int,
+                    interpret: bool = False):
+    """The whole attention core as one kernel each way, from the packed
+    projection to the output projection's input.
+
+    qkv: (B, S, 3, H*D), the q, k and v projections side by side, each with
+    its heads side by side (what ``SelfAttention``'s ``qkv`` layer writes);
+    mask: (B, S) with 1 = valid key, or None. Returns (B, S, H*D).
+
+    One grid cell is one batch row and one 128-lane group of heads (two of
+    dimension 64, one of 128), read from ``qkv`` in place through the block
+    index maps (as (B, 3, S, H*D): the layout the compiler gives the
+    projection's result anyway, so the transpose moves nothing). The (S, S)
+    scores, their softmax and, in the backward pass, dP and dS live in VMEM
+    only. Products take the inputs' dtype with float32 accumulation, the
+    softmax is float32, P and dS are cast to the inputs' dtype for their
+    products: ``full_attention``'s arithmetic, with float32 scores. The
+    backward kernel recomputes P from q, k and the saved log-sum-exp and
+    writes dq, dk, dv into the packed gradient. The caller checks
+    :func:`use_fused_attention` first."""
+    import jax.numpy as jnp
+
+    b, s_len, three, hd = qkv.shape
+    assert three == 3, qkv.shape
+    mask = jnp.ones((b, s_len), jnp.int32) if mask is None else mask
+    return _build_fused()(qkv.transpose(0, 2, 1, 3),
+                    mask.astype(jnp.int32)[:, None, :], int(num_heads),
+                    bool(interpret))

@@ -27,7 +27,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..common.exceptions import AkIllegalArgumentException
-from .attention import blockwise_attention, full_attention, ring_attention
+from .attention import (blockwise_attention, packed_attention,
+                        ring_attention)
 
 
 @dataclass(frozen=True)
@@ -75,19 +76,21 @@ class SelfAttention(nn.Module):
         c = self.cfg
         h, d = c.num_heads, c.hidden_size // c.num_heads
         qkv = nn.DenseGeneral((3, h * d), dtype=c.dtype, name="qkv")(x)
-        q, k, v = [
-            qkv[:, :, i].reshape(x.shape[0], x.shape[1], h, d) for i in range(3)
-        ]
+
+        def heads(i):
+            return qkv[:, :, i].reshape(x.shape[0], x.shape[1], h, d)
+
         # scores, softmax and PV under one name in every operation's
-        # op_name, whichever of the three computes them
+        # op_name, whichever of the paths computes them
         with jax.named_scope("attention_core"):
             if c.use_ring_attention and self.mesh is not None:
-                o = ring_attention(q, k, v, mask, mesh=self.mesh)
+                o = ring_attention(heads(0), heads(1), heads(2), mask,
+                                   mesh=self.mesh)
             elif c.attention_block_size:
-                o = blockwise_attention(q, k, v, mask,
+                o = blockwise_attention(heads(0), heads(1), heads(2), mask,
                                         block_size=c.attention_block_size)
             else:
-                o = full_attention(q, k, v, mask)
+                o = packed_attention(qkv, mask, num_heads=h)
         o = o.reshape(x.shape[0], x.shape[1], h * d)
         return nn.DenseGeneral(c.hidden_size, dtype=c.dtype, name="out")(o)
 

@@ -111,20 +111,31 @@ _REGISTRY: Dict[str, Dict[str, Any]] = {
     },
     "dl.attn_pallas": {
         "knob": "ALINK_ATTN_PALLAS",
-        # called from jit programs GSPMD partitions (blockwise) and from a
-        # shard_map manual over the seq axis only (ring): neither can hold
-        # a Mosaic kernel on more than one device
+        # called from jit programs GSPMD partitions (the encoder's default
+        # attention, blockwise) and from a shard_map manual over the seq
+        # axis only (ring): none can hold a Mosaic kernel on more than one
+        # device
         "single_device_only": True,
         "module": "alink_tpu/dl/attn_pallas.py",
-        "entry": "flash_block_update",
+        # the whole core of the default attention, forward and backward
+        # kernels under one custom_vjp; and the block update that
+        # blockwise/ring attention call per K/V block
+        "entry": "fused_attention, flash_block_update",
         "programs": ("dl.train_step", "dl.micro_step",
                      "dl.fused_accum_step", "dl.mlm_step", "dl.mlm_micro",
-                     "dl.attention"),
-        "fallback": "lax.scan online-softmax "
-                    "(dl/attention._online_softmax_update)",
-        "contract": "blockwise/ring outputs within atol=1e-5 of the XLA "
-                    "path (fp32), knob-off byte-identical "
-                    "(tests/test_kernels.py)",
+                     "dl.attention", "dl.apply_logits"),
+        "fallback": "full_attention (dl/attention.py), also below the "
+                    "length threshold, for a length not lane-aligned, a "
+                    "head dimension other than 64 or 128, a causal call; "
+                    "lax.scan online-softmax "
+                    "(dl/attention._online_softmax_update) for the block "
+                    "update",
+        "contract": "fused core: outputs and dq, dk, dv within 2e-5 "
+                    "(fp32) and 4e-2 (bf16, values of order 1) of "
+                    "full_attention, a fully masked row included "
+                    "(tests/test_attn_fused.py); blockwise/ring outputs "
+                    "within atol=1e-5 of the XLA path (fp32), knob-off "
+                    "byte-identical (tests/test_kernels.py)",
     },
 }
 

@@ -356,7 +356,7 @@ def phase_kernels(ctx: Dict[str, Any]) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
 
-    from alink_tpu.dl.attention import blockwise_attention
+    from alink_tpu.dl.attention import blockwise_attention, packed_attention
     from alink_tpu.dl.attn_pallas import use_attn_pallas
     from alink_tpu.embedding import SkipGramConfig, train_skipgram_sharded
     from alink_tpu.embedding.sgns_pallas import use_sgns_pallas
@@ -396,10 +396,18 @@ def phase_kernels(ctx: Dict[str, Any]) -> Dict[str, Any]:
     mask = jnp.asarray(rng.integers(0, 2, (b, s)), jnp.int32).at[:, 0].set(1)
 
     def attn():
-        # a fresh jit per call: the gate is read while tracing
-        return np.asarray(jax.jit(
-            lambda q, k, v, m: blockwise_attention(
-                q, k, v, m, block_size=w.block_size))(q, k, v, mask))
+        # a fresh jit per call: the gate is read while tracing. Both of the
+        # module's kernels: the block update under blockwise attention, and
+        # the fused core of the encoder's default attention (which takes
+        # the XLA path by itself at the toy width's length)
+        def both(q, k, v, m):
+            o = blockwise_attention(q, k, v, m, block_size=w.block_size)
+            qkv = jnp.stack([q, k, v], axis=2).reshape(b, s, 3, h * hd)
+            return jnp.concatenate(
+                [o.reshape(b, s, h * hd),
+                 packed_attention(qkv, m, num_heads=h)], axis=-1)
+
+        return np.asarray(jax.jit(both)(q, k, v, mask))
 
     checks = {
         "tree.pallas_hist": ("ALINK_GBDT_PALLAS", use_pallas_hist, forest,

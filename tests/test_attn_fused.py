@@ -1,0 +1,335 @@
+"""The fused attention core (dl/attn_pallas.py ``fused_attention``) against
+``full_attention``, which stays its reference: values and gradients under the
+Pallas interpreter, the rule that selects it, an encoder with the path on and
+off, what the traced program holds, and the kernels compiled at BERT-base's
+width for a described v5e (no chip is needed or taken for that)."""
+
+import numpy as np
+import pytest
+
+
+def _reference(qkv, mask, h):
+    from alink_tpu.dl.attention import full_attention
+
+    b, s, _, hd = qkv.shape
+    q, k, v = (qkv[:, :, i].reshape(b, s, h, hd // h) for i in range(3))
+    return full_attention(q, k, v, mask).reshape(b, s, hd)
+
+
+def _inputs(rows, seq, h, d, dtype, seed=0):
+    """A packed projection, a key-padding mask whose rows end mid-block
+    (one of them fully masked where there are several), a cotangent."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    qkv = jnp.asarray(rng.normal(size=(rows, seq, 3, h * d)), dtype)
+    lens = rng.integers(1, seq, size=rows)
+    lens[0] = seq - 58                      # ends inside the last 128 keys
+    mask = (np.arange(seq)[None] < lens[:, None]).astype(np.int32)
+    if rows > 1:
+        mask[-1] = 0                        # every key of this row masked
+    w = jnp.asarray(rng.normal(size=(rows, seq, h * d)), jnp.float32)
+    return qkv, jnp.asarray(mask), w
+
+
+# float32: the two differ by summation order only; bfloat16: by one rounding
+# of P (normalised before the cast in XLA, after PV in the kernel) and by the
+# scores XLA forms in bfloat16, against values of order 1
+_TOL = {"float32": 2e-5, "bfloat16": 4e-2}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows,seq", [(1, 128), (3, 128), (1, 256), (3, 256),
+                                      (1, 512), (3, 512)])
+def test_fused_attention_matches_full_attention(dtype, rows, seq):
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl.attn_pallas import fused_attention
+
+    h, d = 2, 64
+    qkv, mask, w = _inputs(rows, seq, h, d, jnp.dtype(dtype), seed=seq + rows)
+    fused = lambda x: fused_attention(x, mask, num_heads=h, interpret=True)
+    plain = lambda x: _reference(x, mask, h)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+
+    out, ref = fused(qkv), plain(qkv)
+    assert out.shape == (rows, seq, h * d) and out.dtype == qkv.dtype
+    assert not np.isnan(f32(out)).any()
+    np.testing.assert_allclose(f32(out), f32(ref), atol=_TOL[dtype])
+
+    grad = lambda f: jax.grad(
+        lambda x: (f(x).astype(jnp.float32) * w).sum())(qkv)
+    got, want = f32(grad(fused)), f32(grad(plain))
+    assert not np.isnan(got).any()
+    for i, name in enumerate("qkv"):
+        np.testing.assert_allclose(got[:, :, i], want[:, :, i],
+                                   atol=_TOL[dtype], err_msg="d" + name)
+    if rows > 1:
+        # full_attention's `where` lets nothing through to q and k of a row
+        # whose keys are all masked; v still gets the even weights
+        assert not got[-1, :, :2].any() and not want[-1, :, :2].any()
+        assert np.abs(got[-1, :, 2]).max() > 0
+
+
+def test_fused_attention_head_dimension_128_and_no_mask():
+    import jax.numpy as jnp
+
+    from alink_tpu.dl.attn_pallas import fused_attention
+
+    qkv, _, _ = _inputs(2, 128, 3, 128, jnp.float32, seed=5)
+    out = fused_attention(qkv, None, num_heads=3, interpret=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_reference(qkv, None, 3)), atol=2e-5)
+
+
+@pytest.mark.parametrize("case,seq,heads,d,causal,expect", [
+    ("taken", 512, 12, 64, False, True),
+    ("taken_at_128_wide_heads", 512, 6, 128, False, True),
+    ("causal", 512, 12, 64, True, False),
+    ("under_the_threshold", 64, 12, 64, False, False),
+    ("not_lane_aligned", 500, 12, 64, False, False),
+    ("over_one_cells_vmem", 2048, 12, 64, False, False),
+    ("head_dimension_32", 512, 12, 32, False, False),
+    ("odd_heads_of_64", 512, 3, 64, False, False),
+    ("knob_off", 512, 12, 64, False, False),
+    ("more_than_one_device", 512, 12, 64, False, False),
+    ("cpu_backend", 512, 12, 64, False, False),
+])
+def test_fused_attention_selection(monkeypatch, case, seq, heads, d, causal,
+                                   expect):
+    """Decided from the call's shapes and the registry's gate: unset, the
+    knob is on exactly in a one-device TPU process."""
+    import jax
+
+    from alink_tpu.dl.attn_pallas import use_fused_attention
+
+    monkeypatch.delenv("ALINK_ATTN_PALLAS", raising=False)
+    monkeypatch.setattr(jax, "default_backend",
+                        lambda: "cpu" if case == "cpu_backend" else "tpu")
+    monkeypatch.setattr(jax, "device_count",
+                        lambda: 8 if case == "more_than_one_device" else 1)
+    if case == "knob_off":
+        monkeypatch.setenv("ALINK_ATTN_PALLAS", "0")
+    assert use_fused_attention(seq, heads, d, causal=causal) is expect
+
+
+def test_threshold_is_a_lane_aligned_length_the_benchmark_cells_pass():
+    from alink_tpu.dl import attn_pallas
+
+    assert attn_pallas._FUSED_MIN_SEQ % 128 == 0
+    assert attn_pallas._FUSED_MIN_SEQ <= 512 <= attn_pallas._FUSED_MAX_SEQ
+
+
+def _tiny_encoder(seq):
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl.modules import BertConfig, TransformerEncoder
+
+    cfg = BertConfig.tiny(hidden_size=128, num_heads=2, intermediate_size=256,
+                          max_position=seq, dtype=jnp.float32)
+    model = TransformerEncoder(cfg)
+    rng = np.random.default_rng(7)
+    ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (3, seq)), jnp.int32)
+    lens = np.array([seq, seq - 58, 17])
+    mask = jnp.asarray((np.arange(seq)[None] < lens[:, None]).astype(np.int32))
+    return cfg, model, ids, mask, jax.random.PRNGKey(0)
+
+
+def test_encoder_agrees_with_the_path_on_and_off(monkeypatch):
+    """The same parameters through ``TransformerEncoder`` with the default
+    attention on the fused core and on XLA: logits and every parameter's
+    gradient; and each traced layer counted down its path."""
+    import jax
+
+    from alink_tpu.common.metrics import metrics
+    from alink_tpu.dl import attn_pallas
+
+    cfg, model, ids, mask, key = _tiny_encoder(attn_pallas._FUSED_MIN_SEQ)
+    monkeypatch.setenv("ALINK_ATTN_PALLAS", "0")
+    params = model.init(key, ids, mask)["params"]
+
+    def run():
+        before = {n: metrics.counter("attention." + n)
+                  for n in ("fused_traces", "xla_traces")}
+        logits = model.apply({"params": params}, ids, mask)
+        grads = jax.grad(lambda p: (model.apply(
+            {"params": p}, ids, mask) ** 2).sum())(params)
+        grew = {n: metrics.counter("attention." + n) - v
+                for n, v in before.items()}
+        return logits, grads, grew
+
+    off_logits, off_grads, off_grew = run()
+    monkeypatch.setenv("ALINK_ATTN_PALLAS", "1")
+    on_logits, on_grads, on_grew = run()
+    # two traces (apply, grad) of two layers each, all down one path
+    assert off_grew == {"fused_traces": 0, "xla_traces": 2 * cfg.num_layers}
+    assert on_grew == {"fused_traces": 2 * cfg.num_layers, "xla_traces": 0}
+    np.testing.assert_allclose(np.asarray(on_logits), np.asarray(off_logits),
+                               atol=1e-5)
+    flat_on = jax.tree_util.tree_leaves_with_path(on_grads)
+    flat_off = jax.tree_util.tree_leaves(off_grads)
+    assert len(flat_on) == len(flat_off) > 10
+    for (path, g), g_off in zip(flat_on, flat_off):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(g_off), atol=1e-4,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def _all_eqns(jaxpr, into_kernels=False):
+    """Every equation of a jaxpr and of the jaxprs inside it; a kernel's own
+    body is left out unless asked for."""
+    from jax.extend import core as jcore
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call" and not into_kernels:
+            continue
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    yield from _all_eqns(sub.jaxpr, into_kernels)
+                elif isinstance(sub, jcore.Jaxpr):
+                    yield from _all_eqns(sub, into_kernels)
+
+
+def _shapes_in(jaxpr, into_kernels=False):
+    return {tuple(v.aval.shape)
+            for eqn in _all_eqns(jaxpr, into_kernels)
+            for v in list(eqn.invars) + list(eqn.outvars)
+            if hasattr(getattr(v, "aval", None), "shape")}
+
+
+def test_fused_path_traces_no_score_shaped_value(monkeypatch):
+    """The encoder's forward-and-backward program holds a (B, H, S, S)
+    value on the XLA path and none on the fused one, where scores exist one
+    head at a time, (S, S), inside the kernels."""
+    import jax
+
+    from alink_tpu.dl import attn_pallas
+
+    seq = attn_pallas._FUSED_MIN_SEQ
+    cfg, model, ids, mask, key = _tiny_encoder(seq)
+    monkeypatch.setenv("ALINK_ATTN_PALLAS", "0")
+    params = model.init(key, ids, mask)["params"]
+    score = (ids.shape[0], cfg.num_heads, seq, seq)
+    loss = lambda p: (model.apply({"params": p}, ids, mask) ** 2).sum()
+
+    assert score in _shapes_in(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    monkeypatch.setenv("ALINK_ATTN_PALLAS", "1")
+    fused = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+    assert score not in _shapes_in(fused, into_kernels=True)
+    assert (seq, seq) in _shapes_in(fused, into_kernels=True)
+    calls = [e.params["name"] for e in _all_eqns(fused)
+             if e.primitive.name == "pallas_call"]
+    assert sorted(set(calls)) == ["attn_pallas_bwd", "attn_pallas_fwd"]
+    assert len(calls) == 2 * cfg.num_layers
+
+
+def test_blockwise_and_ring_settings_keep_their_paths(monkeypatch):
+    """``attention_block_size`` and ``use_ring_attention`` do not pass
+    through the selection: no layer of theirs is counted down either path."""
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.common.metrics import metrics
+    from alink_tpu.dl.modules import BertConfig, TransformerEncoder
+
+    monkeypatch.setenv("ALINK_ATTN_PALLAS", "1")
+    cfg = BertConfig.tiny(hidden_size=128, num_heads=2, intermediate_size=256,
+                          max_position=256, attention_block_size=128,
+                          dtype=jnp.float32)
+    model = TransformerEncoder(cfg)
+    ids = jnp.ones((2, 256), jnp.int32)
+    before = metrics.counters("attention.")
+    jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids))
+    assert metrics.counters("attention.") == before
+
+
+# ---------------------------------------------------------------------------
+# compiled for the chip, without the chip
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e host: the TPU compiler is installed
+    here and compiles for a chip that is not attached. Made in a fixture, so
+    that only the worker this file goes to loads the TPU's library."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("rows,seq,heads,d", [
+    (32, 512, 12, 64),     # the fine-tune cell's step
+    (1, 512, 12, 64),      # the served ladder's first rung
+    (256, 512, 12, 64),    # and its last
+    (8, 1024, 12, 64),     # the longest a cell's VMEM is asked to hold
+    (8, 256, 6, 128),      # one head a lane group
+])
+def test_fused_kernels_compile_for_v5e_at_real_width(one_chip, rows, seq,
+                                                     heads, d):
+    """Mosaic proper: tiling, VMEM and the kernel's own lowering at the
+    shapes the benchmark's cells run, forward and backward."""
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl.attn_pallas import fused_attention
+
+    qkv = jax.ShapeDtypeStruct((rows, seq, 3, heads * d), jnp.bfloat16,
+                               sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=one_chip)
+    fwd = lambda x, m: fused_attention(x, m, num_heads=heads)
+    both = jax.grad(lambda x, m: fwd(x, m).astype(jnp.float32).sum())
+    for f, kernels in ((fwd, 1), (both, 2)):
+        text = jax.jit(f).lower(qkv, mask).compile().as_text()
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == kernels
+        assert "attn_pallas" in text
+
+
+def test_no_copy_of_the_packed_projection_round_the_kernels(one_chip):
+    """A layer's projection, core and output projection, forward and
+    backward, compiled for the chip: the kernels read the projection's
+    result and write its gradient in the layout the compiler gives them
+    anyway, (B, 3, S, H*D), so no copy or transpose of a q/k/v-sized tensor
+    stands between the products and the kernels."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl.attn_pallas import fused_attention
+
+    b, s, h, d = 32, 512, 12, 64
+    bf16 = jnp.bfloat16
+
+    def layer(x, w, bias, wo, mask):
+        qkv = jax.lax.dot_general(x, w.astype(bf16),
+                                  (((2,), (0,)), ((), ()))) + bias.astype(bf16)
+        return jnp.dot(fused_attention(qkv, mask, num_heads=h),
+                       wo.astype(bf16))
+
+    shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+    args = (shape((b, s, h * d), bf16), shape((h * d, 3, h * d), jnp.float32),
+            shape((3, h * d), jnp.float32),
+            shape((h * d, h * d), jnp.float32), shape((b, s), jnp.int32))
+    grad = jax.grad(lambda *a: layer(*a).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2, 3))
+    moved = re.compile(
+        r"= bf16\[(32,3,512,768|32,512,3,768|32,512,2304)\]\S* "
+        r"(copy|transpose)\(")
+    for f in (layer, grad):
+        text = jax.jit(f).lower(*args).compile().as_text()
+        assert "attn_pallas" in text
+        assert not moved.search(text), moved.search(text).group(0)

@@ -345,7 +345,8 @@ def test_every_kernel_lowers_for_the_tpu_platform(monkeypatch):
     import jax
     import jax.numpy as jnp
 
-    from alink_tpu.dl.attention import blockwise_attention, ring_attention
+    from alink_tpu.dl.attention import (blockwise_attention, packed_attention,
+                                        ring_attention)
     from alink_tpu.embedding.sgns_pallas import sgns_block_grads
     from alink_tpu.parallel.mesh import AXIS_SEQ, make_mesh
     from alink_tpu.tree.pallas_hist import pallas_histogram
@@ -370,6 +371,13 @@ def test_every_kernel_lowers_for_the_tpu_platform(monkeypatch):
     mesh = make_mesh({AXIS_SEQ: 4}, devices=jax.devices()[:4])
     lowers(lambda q, k, v, m: ring_attention(q, k, v, m, mesh=mesh),
            q, q, q, mask)
+    # the fused core of the default attention, from the packed projection
+    # (tests/test_attn_fused.py compiles it for a described v5e besides)
+    qkv = jnp.zeros((2, 256, 3, 12 * 64), jnp.bfloat16)
+    packed = lambda x, m: packed_attention(x, m, num_heads=12)
+    lowers(packed, qkv, mask)
+    lowers(jax.grad(lambda *a: packed(*a).astype(jnp.float32).sum()),
+           qkv, mask)
 
     v = jnp.zeros((256, 128), jnp.float32)
     lowers(sgns_block_grads, v, v, jnp.zeros((256, 5, 128), jnp.float32))
