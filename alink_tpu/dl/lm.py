@@ -3,11 +3,20 @@
 The block is the decoder block of today's open models: pre-norm residual
 stream, RMS norm, grouped query heads with a per-head RMS norm on queries and
 keys, rotary positions, a SiLU-gated feed-forward and an untied head. What
-mixes positions is named per layer in the configuration's ``layer_types``;
-``retention`` (:mod:`alink_tpu.dl.retention`) is the kind there is, so the
-sequence's memory is a fixed-size state per layer, not a cache that grows.
-Its gate is ``logsigmoid(a W_g + b_g)``, one scalar a key/value head and
-position (``g_proj``, the only linear of the block with a bias).
+mixes positions is named per layer in the configuration's ``layer_types``:
+
+- ``retention`` (:mod:`alink_tpu.dl.retention`): a fixed-size state a layer.
+  Its gate is ``logsigmoid(a W_g + b_g)``, one scalar a key/value head and
+  position (``g_proj``, the only linear of the block with a bias).
+- ``kda`` (:mod:`alink_tpu.dl.kda`): the delta rule with a per-channel gate
+  behind short convolutions; a fixed-size state and the convolutions' tails.
+- ``mla`` (:mod:`alink_tpu.dl.mla`): softmax attention over a compressed
+  cache that grows with the sequence, one latent a position.
+
+What follows the mixer is named per layer in ``ffn_types``: the ``dense``
+SiLU-gated feed-forward, or ``experts`` (:mod:`alink_tpu.dl.moe`), the routed
+experts this process holds and the shared expert. ``from_hf`` derives both
+patterns from a checkpoint's ``model_type``.
 
 Parameters keep the checkpoint's layout (HF: a linear's weight is
 ``(out, in)``) and its bfloat16 on the device; nothing is transposed or
@@ -21,10 +30,13 @@ through the program cache:
 - ``lm.decode_step``: one new token of every row through all layers and
   the head.
 
-The state cache (:class:`StateCache`) is allocated once for ``slots``
-sequences, handed to each program as donated buffers and taken back updated;
-a batch's first chunk starts from zeros, so a slot holds nothing of the
-sequence that used it last.
+The cache manager (:class:`StateCache`) holds, slot for slot, what each layer
+keeps of a sequence: a recurrent state of fixed size, a latent cache of
+``positions`` positions with its length, an expert layer's count of the
+assignments it served. It is allocated once for ``slots`` sequences, handed
+to each program as donated buffers and taken back updated; a batch's first
+chunk starts every state, length and count from zero, so a slot holds nothing
+of the sequence that used it last.
 """
 
 from __future__ import annotations
@@ -40,16 +52,23 @@ from ..common.tracing import step_annotation, trace_span
 from .retention import (einsum_f32, phi_dim, retention_chunk,
                         retention_step)
 
-MIXERS = ("retention",)
+MIXERS = ("retention", "kda", "mla")
+FFNS = ("dense", "experts")
 # prompt positions one call of the prefill program takes: the chunk's scores
 # are (rows, heads, chunk, chunk) and the FFN's intermediate (rows, chunk,
 # intermediate), and both fit beside sixteen rows' state at this size
 PREFILL_CHUNK = 256
+# of a stack with kda, mla or expert layers: one chunk of the delta rule's WY
+# form (four sub-blocks of its gate's bound), and small enough that 128 rows'
+# projections, sorted expert inputs and scores fit beside 128 rows' state
+HYBRID_PREFILL_CHUNK = 64
 _STEP_BUCKETS = (0.0005, 0.001, 0.002, 0.004, 0.006, 0.008, 0.010, 0.012,
                  0.014, 0.016, 0.018, 0.020, 0.022, 0.024, 0.026, 0.028, 0.030,
                  0.035, 0.040, 0.050, 0.065, 0.080, 0.1, 0.15, 0.25, 0.5, 1.0,
                  2.5, 10.0)
 _ROW_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0)
+_POSITION_BUCKETS = tuple(float(2 ** i) for i in range(4, 21))
+_LOAD_BUCKETS = (1.0, 1.25, 1.5, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0, 16.0, 64.0, 1024.0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,21 +85,36 @@ class CausalLMConfig:
     retention_eps: float = 1e-6
     layer_types: Tuple[str, ...] = ()
     dtype: str = "bfloat16"
+    # what follows each layer's mixer; empty: dense everywhere
+    ffn_types: Tuple[str, ...] = ()
+    # kda
+    conv_kernel: int = 4
+    kda_lower_bound: float = -5.0
+    # mla
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # experts: the router's outputs, and the experts [lo, hi) held here
+    num_experts: int = 0
+    experts_held: Tuple[int, int] = (0, 0)
+    num_experts_per_tok: int = 0
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    moe_intermediate_size: int = 0
+    shared_intermediate_size: int = 0
 
     @classmethod
     def from_hf(cls, hf: Dict[str, Any], **over) -> "CausalLMConfig":
-        """From an HF ``config.json``; ``layer_types`` defaults to retention
-        in every layer (the checkpoint family this block was written for
-        publishes the dense block's keys and no key of its mixer)."""
+        """From an HF ``config.json``, by its ``model_type``: ``brumby``
+        (retention in every layer unless ``layer_types`` says otherwise: the
+        family publishes the dense block's keys and no key of its mixer) or
+        ``bailing_hybrid`` (:func:`_bailing_hybrid_fields`)."""
         if hf.get("hidden_act", "silu") != "silu":
             raise NotImplementedError(f"hidden_act {hf['hidden_act']!r}")
         n = int(hf["num_hidden_layers"])
         heads = int(hf["num_attention_heads"])
-        kind = tuple(hf.get("layer_types") or ("retention",) * n)
-        bad = sorted(set(kind) - set(MIXERS))
-        if bad or len(kind) != n:
-            raise NotImplementedError(
-                f"layer_types {bad or kind}: the block has {MIXERS}")
         fields = dict(
             vocab_size=int(hf["vocab_size"]), hidden_size=int(hf["hidden_size"]),
             intermediate_size=int(hf["intermediate_size"]), num_hidden_layers=n,
@@ -88,51 +122,219 @@ class CausalLMConfig:
             num_key_value_heads=int(hf.get("num_key_value_heads", heads)),
             head_dim=int(hf.get("head_dim", int(hf["hidden_size"]) // heads)),
             rms_norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
-            rope_theta=float(hf.get("rope_theta", 1e4)),
-            retention_eps=float(hf.get("retention_eps", 1e-6)),
-            layer_types=kind)
+            rope_theta=float(hf.get("rope_theta", 1e4)))
+        model_type = hf.get("model_type")
+        if model_type == "brumby":
+            kind = tuple(hf.get("layer_types") or ("retention",) * n)
+            if set(kind) != {"retention"} or len(kind) != n:
+                raise NotImplementedError(
+                    f"layer_types {kind}: a brumby block has retention alone")
+            fields.update(retention_eps=float(hf.get("retention_eps", 1e-6)),
+                          layer_types=kind)
+        elif model_type == "bailing_hybrid":
+            fields.update(_bailing_hybrid_fields(hf, n))
+        else:
+            raise NotImplementedError(
+                f"model_type {model_type!r}: the block is written for "
+                f"'brumby' and 'bailing_hybrid'")
         fields.update(over)
         return cls(**fields)
 
+    def __post_init__(self):
+        n = self.num_hidden_layers
+        bad = (set(self.layer_types) - set(MIXERS)) | (set(self.ffn_types)
+                                                       - set(FFNS))
+        if bad or len(self.layer_types) != n or len(self.ffn_types) not in (0, n):
+            raise NotImplementedError(
+                f"layer_types {self.layer_types} and ffn_types "
+                f"{self.ffn_types} for {n} layers: the block has {MIXERS} "
+                f"and {FFNS}")
+
+    def ffn_type(self, i: int) -> str:
+        return self.ffn_types[i] if self.ffn_types else "dense"
+
+    @property
+    def prefill_chunk(self) -> int:
+        plain = set(self.layer_types) <= {"retention"} and not self.ffn_types
+        return PREFILL_CHUNK if plain else HYBRID_PREFILL_CHUNK
+
     @property
     def state_shape(self) -> Tuple[int, int, int]:
-        """One sequence's state in one layer: ``(Hkv, P, D)``."""
+        """One sequence's retention state in one layer: ``(Hkv, P, D)``."""
         return (self.num_key_value_heads, phi_dim(self.head_dim), self.head_dim)
 
-    def state_bytes_per_slot(self) -> int:
-        hkv, p, d = self.state_shape
-        return self.num_hidden_layers * hkv * p * (d + 1) * 4
+    @property
+    def latent_width(self) -> int:
+        """What an mla layer caches of a position: the latent and the
+        shared rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def layer_state(self, i: int, slots: int, positions: int
+                    ) -> Tuple[Tuple[Tuple[int, ...], str], ...]:
+        """Shape and dtype of what layer ``i`` keeps for ``slots``
+        sequences, the mixer's entries first."""
+        heads, d = self.num_attention_heads, self.head_dim
+        kind = self.layer_types[i]
+        if kind == "retention":
+            hkv, p, _ = self.state_shape
+            out = [((slots, hkv, p, d), "float32"), ((slots, hkv, p), "float32")]
+        elif kind == "kda":
+            out = [((slots, heads, d, d), "float32"),
+                   ((slots, self.conv_kernel - 1, 3 * heads * d), "float32")]
+        else:
+            out = [((slots, positions, self.latent_width), self.dtype),
+                   ((slots,), "int32")]
+        if self.ffn_type(i) == "experts":
+            lo, hi = self.experts_held
+            out.append(((slots, hi - lo), "int32"))
+        return tuple(out)
+
+    def state_bytes_per_slot(self, kinds=("retention", "kda")) -> int:
+        """The recurrent states of one sequence in all layers of ``kinds``:
+        what does not grow with its length."""
+        total = 0
+        for i, kind in enumerate(self.layer_types):
+            if kind in kinds:
+                total += sum(4 * int(np.prod(shape[1:]))
+                             for shape, _ in self.layer_state(i, 1, 0)[:2])
+        return total
+
+    def latent_bytes_per_position(self) -> int:
+        return (self.layer_types.count("mla") * self.latent_width
+                * np.dtype(self.dtype).itemsize)
+
+
+def _bailing_hybrid_fields(hf: Dict[str, Any], n: int) -> Dict[str, Any]:
+    """The ``bailing_hybrid`` keys: layer ``i`` is mla where ``(i + 1) %
+    layer_group_size == 0`` and kda elsewhere; the first
+    ``first_k_dense_replace`` layers have the dense feed-forward, the rest
+    experts. ``experts_held`` (``[lo, hi)``, this process's share of a
+    checkpoint; all of them where the key is absent) is this repo's key. A
+    setting the block does not compute is refused, not ignored."""
+    want = dict(q_lora_rank=None, scoring_func="sigmoid", topk_method="noaux_tc",
+                norm_topk_prob=True, use_qk_norm=True, rope_interleave=True,
+                kda_safe_gate=True, no_kda_lora=True, linear_silu=True,
+                num_shared_experts=1, group_norm_size=1,
+                moe_router_enable_expert_bias=True,
+                gated_attention_proj_granularity_type="head_wise")
+    bad = {k: hf[k] for k, v in want.items() if k in hf and hf[k] != v}
+    if bad:
+        raise NotImplementedError(f"bailing_hybrid with {bad}: the block "
+                                  f"computes {({k: want[k] for k in bad})}")
+    for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+        at = [i for i, v in enumerate((hf.get(key) or ())[:n]) if v]
+        if at:      # one entry a published layer; the first n are the kept ones
+            raise NotImplementedError(
+                f"{key} is nonzero in layers {at}: the clamped SwiGLU is not "
+                f"written down here")
+    group = int(hf["layer_group_size"])
+    dense = int(hf.get("first_k_dense_replace", 0))
+    experts = int(hf["num_experts"])
+    held = tuple(int(v) for v in hf.get("experts_held") or (0, experts))
+    groups = int(hf.get("n_group", 1))
+    if not (0 <= held[0] < held[1] <= experts) or experts % groups:
+        raise ValueError(f"experts_held {held} of {experts} experts in "
+                         f"{groups} groups")
+    return dict(
+        layer_types=tuple("mla" if (i + 1) % group == 0 else "kda"
+                          for i in range(n)),
+        ffn_types=tuple("dense" if i < dense else "experts" for i in range(n)),
+        conv_kernel=int(hf.get("short_conv_kernel_size", 4)),
+        kda_lower_bound=float(hf.get("kda_lower_bound", -5)),
+        kv_lora_rank=int(hf["kv_lora_rank"]),
+        qk_nope_head_dim=int(hf["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(hf["qk_rope_head_dim"]),
+        v_head_dim=int(hf["v_head_dim"]), num_experts=experts,
+        experts_held=held, num_experts_per_tok=int(hf["num_experts_per_tok"]),
+        n_group=groups, topk_group=int(hf.get("topk_group", 1)),
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        moe_intermediate_size=int(hf["moe_intermediate_size"]),
+        shared_intermediate_size=int(hf.get(
+            "moe_shared_expert_intermediate_size", hf["moe_intermediate_size"])))
+
+
+def _mixer_shapes(cfg: CausalLMConfig, kind: str) -> Dict[str, Tuple[int, ...]]:
+    """A mixer's tensors by their name inside ``self_attn``; a name without
+    ``.weight`` or ``.bias`` is a bare parameter."""
+    h, d = cfg.hidden_size, cfg.head_dim
+    hq, hkv = cfg.num_attention_heads, cfg.num_key_value_heads
+    if kind == "retention":
+        return {"q_proj.weight": (hq * d, h), "k_proj.weight": (hkv * d, h),
+                "v_proj.weight": (hkv * d, h), "g_proj.weight": (hkv, h),
+                "g_proj.bias": (hkv,), "q_norm.weight": (d,),
+                "k_norm.weight": (d,), "o_proj.weight": (h, hq * d)}
+    if kind == "kda":
+        wide, conv = (hq * d, h), (hq * d, 1, cfg.conv_kernel)
+        return {"q_proj.weight": wide, "k_proj.weight": wide,
+                "v_proj.weight": wide, "q_conv1d.weight": conv,
+                "k_conv1d.weight": conv, "v_conv1d.weight": conv,
+                "f_proj.weight": wide, "dt_bias": (hq * d,), "A_log": (hq,),
+                "b_proj.weight": (hq, h), "g_proj.weight": wide,
+                "o_norm.weight": (d,), "o_proj.weight": (h, hq * d)}
+    r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    return {"q_proj.weight": (hq * (dn + dr), h),
+            "kv_a_proj_with_mqa.weight": (r + dr, h),
+            "kv_a_layernorm.weight": (r,),
+            "kv_b_proj.weight": (hq * (dn + dv), r),
+            "g_proj.weight": (hq, h), "o_proj.weight": (h, hq * dv)}
+
+
+def _ffn_shapes(cfg: CausalLMConfig, kind: str) -> Dict[str, Tuple[int, ...]]:
+    h = cfg.hidden_size
+
+    def swiglu(prefix: str, f: int):
+        return {f"{prefix}gate_proj.weight": (f, h),
+                f"{prefix}up_proj.weight": (f, h),
+                f"{prefix}down_proj.weight": (h, f)}
+
+    if kind == "dense":
+        return swiglu("", cfg.intermediate_size)
+    out = {"gate.weight": (cfg.num_experts, h),
+           "gate.expert_bias": (cfg.num_experts,)}
+    for e in range(*cfg.experts_held):
+        out.update(swiglu(f"experts.{e}.", cfg.moe_intermediate_size))
+    out.update(swiglu("shared_experts.", cfg.shared_intermediate_size))
+    return out
 
 
 def tensor_shapes(cfg: CausalLMConfig) -> Dict[str, Tuple[int, ...]]:
     """HF tensor name to shape, for every tensor of the model."""
-    h, f, d = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim
-    hq, hkv = cfg.num_attention_heads, cfg.num_key_value_heads
+    h = cfg.hidden_size
     out = {"model.embed_tokens.weight": (cfg.vocab_size, h),
            "model.norm.weight": (h,), "lm_head.weight": (cfg.vocab_size, h)}
-    per_layer = {"input_layernorm": (h,), "self_attn.q_proj": (hq * d, h),
-                 "self_attn.k_proj": (hkv * d, h), "self_attn.v_proj": (hkv * d, h),
-                 "self_attn.g_proj": (hkv, h), "self_attn.q_norm": (d,),
-                 "self_attn.k_norm": (d,), "self_attn.o_proj": (h, hq * d),
-                 "post_attention_layernorm": (h,), "mlp.gate_proj": (f, h),
-                 "mlp.up_proj": (f, h), "mlp.down_proj": (h, f)}
-    for i in range(cfg.num_hidden_layers):
-        for name, shape in per_layer.items():
-            out[f"model.layers.{i}.{name}.weight"] = shape
-        out[f"model.layers.{i}.self_attn.g_proj.bias"] = (hkv,)
+    for i, kind in enumerate(cfg.layer_types):
+        p = f"model.layers.{i}."
+        out[p + "input_layernorm.weight"] = (h,)
+        out[p + "post_attention_layernorm.weight"] = (h,)
+        for name, shape in _mixer_shapes(cfg, kind).items():
+            out[p + "self_attn." + name] = shape
+        for name, shape in _ffn_shapes(cfg, cfg.ffn_type(i)).items():
+            out[p + "mlp." + name] = shape
     return out
 
 
 def tree_path(hf_name: str) -> Tuple:
     """Where an HF tensor lives in the parameter tree: ``("embed_tokens",)``,
-    ``("norm",)``, ``("lm_head",)`` or ``("layers", i, leaf)``; a bias is
-    the leaf ``<linear>_bias``."""
+    ``("norm",)``, ``("lm_head",)`` or ``("layers", i, leaf)``. A linear's
+    leaf is its name, its bias ``<linear>_bias``, a bare parameter
+    (``A_log``) its own name; the shared expert's leaves are
+    ``shared_<linear>``, and expert ``e``'s lie under ``("layers", i,
+    "experts", e, leaf)`` until :func:`_stack_experts` joins them."""
     parts = hf_name.split(".")
     if parts[0] == "lm_head":
         return ("lm_head",)
     if parts[1] != "layers":
         return (parts[1],)
-    leaf = parts[-2] + ("_bias" if parts[-1] == "bias" else "")
+    if parts[-1] in ("weight", "bias"):
+        leaf = parts[-2] + ("_bias" if parts[-1] == "bias" else "")
+        inside = parts[3:-2]
+    else:
+        leaf, inside = parts[-1], parts[3:-1]
+    if inside[-1:] == ["shared_experts"]:
+        leaf = "shared_" + leaf
+    if inside[-2:-1] == ["experts"]:
+        return ("layers", int(parts[2]), "experts", int(inside[-1]), leaf)
     return ("layers", int(parts[2]), leaf)
 
 
@@ -152,14 +354,58 @@ def params_from_tensors(cfg: CausalLMConfig, tensors) -> Dict[str, Any]:
         path = tree_path(name)
         node = tree
         for key in path[:-1]:
-            node = node[key]
+            node = node[key] if isinstance(node, list) else node.setdefault(key, {})
         node[path[-1]] = arr
         seen.add(name)
     missing = sorted(set(want) - seen)
     if missing:
         raise ValueError(f"checkpoint lacks {len(missing)} tensors, first "
                          f"{missing[:3]}")
+    if "experts" in cfg.ffn_types:
+        _stack_experts(cfg, tree)
     return tree
+
+
+def _build_stack_experts():
+    import jax
+    import jax.numpy as jnp
+
+    def run(gate, up, down):
+        """Lists of one layer's expert matrices, ``(out, in)`` each, to the
+        grouped products' operands: ``(E, H, 2F)`` and ``(E, F, H)``."""
+        return (jnp.stack([jnp.concatenate([g.T, u.T], axis=1)
+                           for g, u in zip(gate, up)]),
+                jnp.stack([d.T for d in down]))
+
+    return jax.jit(run)
+
+
+def _stack_experts(cfg: CausalLMConfig, tree) -> None:
+    """Each expert layer's ``experts`` (a dict by expert of three matrices)
+    replaced by ``experts_gate_up`` and ``experts_down``, a layer at a time
+    and each waited for, so that one layer's experts alone exist twice
+    (dispatched one after another without waiting, every layer's result is
+    allocated before the first layer's inputs are let go: 16.5 GB of live
+    buffers on a 16.9 GB chip in a checkout's first run, PERF.md PR 32)."""
+    import jax
+
+    from ..common.jitcache import cached_jit
+
+    lo, hi = cfg.experts_held
+    stack = cached_jit("lm.stack_experts", _build_stack_experts,
+                       key_extra=(hi - lo, cfg.moe_intermediate_size,
+                                  cfg.hidden_size))
+    for layer in tree["layers"]:
+        by_expert = layer.pop("experts", None)
+        if by_expert is None:
+            continue
+        held = [by_expert[e] for e in range(lo, hi)]
+        del by_expert
+        layer["experts_gate_up"], layer["experts_down"] = stack(
+            *([e[name] for e in held]
+              for name in ("gate_proj", "up_proj", "down_proj")))
+        del held
+        jax.block_until_ready(layer["experts_down"])
 
 
 # -- the block ---------------------------------------------------------------
@@ -208,31 +454,150 @@ def _mixer_inputs(cfg: CausalLMConfig, layer, a, pos):
     return q, k, v, log_g
 
 
-def _ffn(cfg: CausalLMConfig, layer, x):
+def _swiglu(n, gate, up, down):
     import jax
 
+    n = n.astype(gate.dtype)
+    return _linear(jax.nn.silu(_linear(n, gate)) * _linear(n, up), down)
+
+
+def _ffn(cfg: CausalLMConfig, layer, x):
     n = _rms_norm(x, layer["post_attention_layernorm"], cfg.rms_norm_eps)
-    n = n.astype(layer["gate_proj"].dtype)
-    hidden = jax.nn.silu(_linear(n, layer["gate_proj"])) * _linear(n, layer["up_proj"])
-    return x + _linear(hidden, layer["down_proj"])
+    return x + _swiglu(n, layer["gate_proj"], layer["up_proj"],
+                       layer["down_proj"])
 
 
-def _block(cfg: CausalLMConfig, layer, x, pos, valid, S, z, *, chunk: bool):
-    """One layer over a chunk ``x (B,T,H)`` or a step ``x (B,H)``; the
-    residual stream is float32."""
+def _experts_ffn(cfg: CausalLMConfig, layer, x, valid, load):
+    """The expert layer over ``x (B,T,H)``: the routed experts held here,
+    the shared expert, and each row's count a held expert added to
+    ``load (B,E)``."""
+    import jax
     import jax.numpy as jnp
 
-    a = _rms_norm(x, layer["input_layernorm"], cfg.rms_norm_eps)
-    q, k, v, log_g = _mixer_inputs(cfg, layer, a, pos)
+    from . import moe
+
+    B, T, H = x.shape
+    n = _rms_norm(x, layer["post_attention_layernorm"], cfg.rms_norm_eps)
+    flat = n.reshape(B * T, H)
+    with jax.named_scope(moe.ROUTE_SCOPE):
+        logits = jnp.einsum("nh,eh->ne", flat, layer["gate"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        idx, w = moe.route(logits, layer["expert_bias"], n_group=cfg.n_group,
+                           topk_group=cfg.topk_group,
+                           top_k=cfg.num_experts_per_tok,
+                           scale=cfg.routed_scaling_factor)
+        local, added = moe.held_load(idx.reshape(B, T, -1), valid,
+                                     cfg.experts_held)
+    y = moe.routed_experts(flat, local.reshape(B * T, -1), w, added.sum(axis=0),
+                           layer["experts_gate_up"], layer["experts_down"],
+                           dtype=jnp.dtype(cfg.dtype))
+    shared = _swiglu(n, layer["shared_gate_proj"], layer["shared_up_proj"],
+                     layer["shared_down_proj"])
+    return x + y.reshape(B, T, H) + shared, load + added
+
+
+def _kda_mixer(cfg: CausalLMConfig, layer, a, valid, S, tail, *, chunk: bool):
+    """``a (B,T,H)`` of a chunk or ``(B,H)`` of a step through one kda
+    layer's mixer; ``tail`` is the three convolutions' inputs, q, k and v
+    side by side."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import kda
+
+    heads, d = cfg.num_attention_heads, cfg.head_dim
+    lead = a.shape[:-1]
+    a = a.astype(layer["q_proj"].dtype)
+    qkv = jnp.concatenate([_linear(a, layer[p]) for p in
+                           ("q_proj", "k_proj", "v_proj")], axis=-1)
+    w = jnp.concatenate([layer[p][:, 0] for p in
+                         ("q_conv1d", "k_conv1d", "v_conv1d")], axis=0)
+    conv = kda.short_conv if chunk else kda.short_conv_step
+    qkv, tail = conv(qkv, w, tail, valid)
+    q, k, v = (x.reshape(*lead, heads, d) for x in
+               jnp.split(jax.nn.silu(qkv), 3, axis=-1))
+    unit = lambda x: x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+    q, k = unit(q) * d ** -0.5, unit(k)
+    f32 = jnp.float32
+    rate = jnp.exp(layer["A_log"].astype(f32))[:, None]
+    g = cfg.kda_lower_bound * jax.nn.sigmoid(rate * (
+        _linear(a, layer["f_proj"]).reshape(*lead, heads, d)
+        + layer["dt_bias"].astype(f32).reshape(heads, d)))
+    beta = jax.nn.sigmoid(_linear(a, layer["b_proj"]))
     if chunk:
-        o, S, z = retention_chunk(q, k, v, log_g, valid, S, z,
-                                  eps=cfg.retention_eps,
-                                  dtype=jnp.dtype(cfg.dtype))
+        o, S = kda.kda_chunk(q, k, v, g, beta, valid, S,
+                             lower_bound=cfg.kda_lower_bound,
+                             dtype=jnp.dtype(cfg.dtype))
     else:
-        o, S, z = retention_step(q, k, v, log_g, valid, S, z,
-                                 eps=cfg.retention_eps)
-    x = x + _linear(o.reshape(*x.shape[:-1], -1), layer["o_proj"])
-    return _ffn(cfg, layer, x), S, z
+        o, S = kda.kda_step(q, k, v, g, beta, valid, S)
+    o = _rms_norm(o, layer["o_norm"], cfg.rms_norm_eps) * jax.nn.sigmoid(
+        _linear(a, layer["g_proj"]).reshape(*lead, heads, d))
+    return _linear(o.reshape(*lead, heads * d), layer["o_proj"]), S, tail
+
+
+def _mla_mixer(cfg: CausalLMConfig, layer, a, pos, valid, latent, length):
+    """``a (B,T,H)`` through one mla layer's mixer: the positions' latents
+    written to the cache, then attention over it in the absorbed form."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import mla
+
+    B, T, _ = a.shape
+    heads = cfg.num_attention_heads
+    r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    a = a.astype(layer["q_proj"].dtype)
+    q = _linear(a, layer["q_proj"]).reshape(B, T, heads, dn + dr)
+    q_r = mla.rope_interleaved(q[..., dn:], pos, cfg.rope_theta)
+    kv = _linear(a, layer["kv_a_proj_with_mqa"])
+    new = jnp.concatenate(
+        [_rms_norm(kv[..., :r], layer["kv_a_layernorm"], cfg.rms_norm_eps),
+         mla.rope_interleaved(kv[..., r:], pos, cfg.rope_theta)], axis=-1)
+    start = length
+    latent, length = mla.cache_write(latent, length, new, valid)
+    w_kvb = layer["kv_b_proj"].reshape(heads, dn + dv, r)
+    o = mla.attend(q[..., :dn], q_r, latent, start, w_kvb[:, :dn], w_kvb[:, dn:],
+                   scale=(dn + dr) ** -0.5, dtype=jnp.dtype(cfg.dtype))
+    o = o * jax.nn.sigmoid(_linear(a, layer["g_proj"]))[..., None]
+    return _linear(o.reshape(B, T, heads * dv), layer["o_proj"]), latent, length
+
+
+def _block(cfg: CausalLMConfig, i: int, layer, x, pos, valid, state, *,
+           chunk: bool):
+    """Layer ``i`` over a chunk ``x (B,T,H)`` or a step ``x (B,H)``, with
+    what the layer keeps of each row (:meth:`CausalLMConfig.layer_state`);
+    the residual stream is float32."""
+    import jax.numpy as jnp
+
+    kind, ffn = cfg.layer_types[i], cfg.ffn_type(i)
+    # the mla mixer and the expert layer take a step as a chunk of one position
+    one = (lambda t: t) if chunk else (lambda t: t[:, None])
+    back = (lambda t: t) if chunk else (lambda t: t[:, 0])
+    a = _rms_norm(x, layer["input_layernorm"], cfg.rms_norm_eps)
+    if kind == "retention":
+        S, z = state[:2]
+        q, k, v, log_g = _mixer_inputs(cfg, layer, a, pos)
+        if chunk:
+            o, S, z = retention_chunk(q, k, v, log_g, valid, S, z,
+                                      eps=cfg.retention_eps,
+                                      dtype=jnp.dtype(cfg.dtype))
+        else:
+            o, S, z = retention_step(q, k, v, log_g, None, S, z,
+                                     eps=cfg.retention_eps)
+        x = x + _linear(o.reshape(*x.shape[:-1], -1), layer["o_proj"])
+        kept = (S, z)
+    elif kind == "kda":
+        y, S, tail = _kda_mixer(cfg, layer, a, valid, *state[:2], chunk=chunk)
+        x, kept = x + y, (S, tail)
+    else:
+        y, latent, length = _mla_mixer(cfg, layer, one(a), one(pos), one(valid),
+                                       *state[:2])
+        x, kept = x + back(y), (latent, length)
+    if ffn == "dense":
+        return _ffn(cfg, layer, x), kept
+    x, load = _experts_ffn(cfg, layer, one(x), one(valid), state[2])
+    return back(x), kept + (load,)
 
 
 def _greedy(cfg: CausalLMConfig, params, x):
@@ -250,16 +615,27 @@ def _greedy(cfg: CausalLMConfig, params, x):
 
 
 def _rows_of(state, rows: int):
-    """The first ``rows`` slots of every layer's ``(S, z)``."""
-    return [(S[:rows], z[:rows]) for S, z in state]
+    """The first ``rows`` slots of what every layer keeps."""
+    return [tuple(a[:rows] for a in kept) for kept in state]
 
 
 def _rows_back(state, new, rows: int):
     """``new`` written over the first ``rows`` slots (the whole buffer where
     the rung fills it, so that no slice is cut)."""
-    return [(Sn, zn) if S.shape[0] == rows
-            else (S.at[:rows].set(Sn), z.at[:rows].set(zn))
-            for (S, z), (Sn, zn) in zip(state, new)]
+    return [tuple(b if a.shape[0] == rows else a.at[:rows].set(b)
+                  for a, b in zip(kept, kept_new))
+            for kept, kept_new in zip(state, new)]
+
+
+def _fresh(cfg: CausalLMConfig, i: int, kept, first):
+    """What layer ``i`` keeps, emptied where ``first`` says a batch begins:
+    states, lengths and counts to zero. A latent cache is not rewritten:
+    its length says how much of it is the sequence's."""
+    import jax.numpy as jnp
+
+    skip = 1 if cfg.layer_types[i] == "mla" else 0
+    return kept[:skip] + tuple(jnp.where(first, jnp.zeros((), a.dtype), a)
+                               for a in kept[skip:])
 
 
 def _build_prefill_chunk(cfg: CausalLMConfig, rows: int):
@@ -274,11 +650,11 @@ def _build_prefill_chunk(cfg: CausalLMConfig, rows: int):
         valid = pos >= 0
         x = jnp.take(params["embed_tokens"], tokens, axis=0).astype(jnp.float32)
         new = []
-        for layer, (S, z) in zip(params["layers"], _rows_of(state, rows)):
-            S, z = jnp.where(first, 0.0, S), jnp.where(first, 0.0, z)
-            x, S, z = _block(cfg, layer, x, jnp.maximum(pos, 0), valid, S, z,
-                             chunk=True)
-            new.append((S, z))
+        for i, (layer, kept) in enumerate(zip(params["layers"],
+                                              _rows_of(state, rows))):
+            x, kept = _block(cfg, i, layer, x, jnp.maximum(pos, 0), valid,
+                             _fresh(cfg, i, kept, first), chunk=True)
+            new.append(kept)
         at = jnp.take_along_axis(
             x, jnp.maximum(last_idx, 0)[:, None, None], axis=1)[:, 0]
         hidden = jnp.where((last_idx >= 0)[:, None], at, hidden)
@@ -298,11 +674,16 @@ def _build_decode_step(cfg: CausalLMConfig, rows: int):
     import jax.numpy as jnp
 
     def run(params, state, tok, pos):
+        """tok, pos ``(rows,)``; pos < 0: a row beyond the batch's, which
+        writes no state it could read and asks no expert."""
+        valid = pos >= 0
         x = jnp.take(params["embed_tokens"], tok, axis=0).astype(jnp.float32)
         new = []
-        for layer, (S, z) in zip(params["layers"], _rows_of(state, rows)):
-            x, S, z = _block(cfg, layer, x, pos, None, S, z, chunk=False)
-            new.append((S, z))
+        for i, (layer, kept) in enumerate(zip(params["layers"],
+                                              _rows_of(state, rows))):
+            x, kept = _block(cfg, i, layer, x, jnp.maximum(pos, 0), valid, kept,
+                             chunk=False)
+            new.append(kept)
         tok, logprob = _greedy(cfg, params, x)
         return _rows_back(state, new, rows), tok, logprob
 
@@ -310,22 +691,30 @@ def _build_decode_step(cfg: CausalLMConfig, rows: int):
 
 
 class StateCache:
-    """The retention state of ``slots`` sequences: per layer one ``S``
-    ``(slots, Hkv, P, D)`` and one ``z`` ``(slots, Hkv, P)``, float32,
-    allocated once. A program takes the buffers donated (:meth:`take`) and
-    what it returns is kept (:meth:`put`), so there is one copy."""
+    """What ``slots`` sequences keep in every layer, allocated once: per
+    layer a tuple of arrays with the slots leading, as
+    :meth:`CausalLMConfig.layer_state` lays them out (retention ``(S, z)``;
+    kda ``(S, convolution tails)``; mla ``(latent cache of `positions`
+    positions, its length)``; then an expert layer's count a held expert).
+    A program takes the buffers donated (:meth:`take`) and what it returns
+    is kept (:meth:`put`), so there is one copy."""
 
-    def __init__(self, cfg: CausalLMConfig, slots: int):
+    def __init__(self, cfg: CausalLMConfig, slots: int, positions: int = 0):
         import jax.numpy as jnp
 
-        hkv, p, d = cfg.state_shape
-        self.slots = int(slots)
-        self._state = [(jnp.zeros((self.slots, hkv, p, d), jnp.float32),
-                        jnp.zeros((self.slots, hkv, p), jnp.float32))
-                       for _ in range(cfg.num_hidden_layers)]
+        self.slots, self.positions = int(slots), int(positions)
+        self._state = [tuple(jnp.zeros(shape, dtype) for shape, dtype in
+                             cfg.layer_state(i, self.slots, self.positions))
+                       for i in range(cfg.num_hidden_layers)]
         metrics.set_gauge("lm.state_slots", self.slots)
         metrics.set_gauge("lm.state_bytes",
                           self.slots * cfg.state_bytes_per_slot())
+        if set(cfg.layer_types) - {"retention"}:
+            metrics.set_gauge("lm.kda_state_bytes",
+                              self.slots * cfg.state_bytes_per_slot(("kda",)))
+            metrics.set_gauge("lm.latent_cache_positions", self.positions)
+            metrics.set_gauge("lm.latent_cache_bytes", self.slots * self.positions
+                              * cfg.latent_bytes_per_position())
         self.use(0)
 
     def use(self, rows: int) -> None:
@@ -340,18 +729,34 @@ class StateCache:
     def put(self, state) -> None:
         self._state = state
 
+    def peek(self):
+        """The buffers as they lie, for a read between programs."""
+        if self._state is None:
+            raise RuntimeError("the state cache is out with a running program")
+        return self._state
+
 
 class CausalLM:
-    """The model placed on the device with its state cache: greedy
-    generation for batches of token-id prompts."""
+    """The model placed on the device with its cache manager: greedy
+    generation for batches of token-id prompts. ``positions``: what a slot's
+    latent cache holds, prompt and new tokens together (a stack without mla
+    layers keeps none)."""
 
     def __init__(self, cfg: CausalLMConfig, params, *, slots: int,
-                 prefill_chunk: int = PREFILL_CHUNK):
+                 positions: int = 0, prefill_chunk: int = 0):
         from ..common.jitcache import bucket_rows
 
         self.cfg, self.params = cfg, params
-        self.prefill_chunk = int(prefill_chunk)
-        self.cache = StateCache(cfg, bucket_rows(slots))
+        self.prefill_chunk = int(prefill_chunk) or cfg.prefill_chunk
+        # of the last call of generate(), where the stack has expert layers:
+        # (rows, expert layers, experts held), each row's assignments served
+        self.expert_load = None
+        self._grows = "mla" in cfg.layer_types
+        if self._grows and positions < 1:
+            raise ValueError("a stack with mla layers needs `positions`, the "
+                             "length of a slot's latent cache")
+        self.cache = StateCache(cfg, bucket_rows(slots),
+                                positions if self._grows else 0)
 
     def _program(self, kernel_id: str, builder, rows: int):
         from ..common.jitcache import cached_jit
@@ -367,10 +772,13 @@ class CausalLM:
         n = len(prompts)
         ids = np.zeros((n, max_new_tokens), np.int32)
         logprobs = np.zeros((n, max_new_tokens), np.float32)
+        loads = []
         for s in range(0, n, self.cache.slots):
             part = prompts[s:s + self.cache.slots]
-            ids[s:s + len(part)], logprobs[s:s + len(part)] = \
+            ids[s:s + len(part)], logprobs[s:s + len(part)], load = \
                 self._generate_batch(part, max_new_tokens)
+            loads.append(load)
+        self.expert_load = None if loads[0] is None else np.concatenate(loads)
         return ids, logprobs
 
     def _generate_batch(self, prompts, max_new: int):
@@ -382,13 +790,44 @@ class CausalLM:
         rows = min(bucket_rows(n), self.cache.slots)
         lens = np.asarray([len(p) for p in prompts]
                           + [len(prompts[-1])] * (rows - n), np.int32)
+        if self._grows and int(lens.max()) + max_new - 1 > self.cache.positions:
+            raise ValueError(
+                f"a prompt of {int(lens.max())} tokens and {max_new} new ones "
+                f"pass the latent cache's {self.cache.positions} positions")
         self.cache.use(n)
+        load = None
         try:
             tok, logprob = self._prefill(prompts, lens, rows)
             out = self._decode(tok, logprob, lens, n, rows, max_new)
+            if "experts" in self.cfg.ffn_types:
+                load = self._count_assignments(
+                    n, int(lens[:n].sum()) + n * (max_new - 1))
         finally:
             self.cache.use(0)
-        return out[0][:n], out[1][:n]
+        return out[0][:n], out[1][:n], load
+
+    def _count_assignments(self, n: int, tokens: int) -> np.ndarray:
+        """One read a batch, after its last step: per row and expert layer
+        the assignments each held expert served, ``(rows, expert layers,
+        experts held)``, returned; from them the counters: the assignments
+        the batch's tokens made, those held here, and each expert layer's
+        largest load over its mean."""
+        import jax.numpy as jnp
+
+        by_row = np.asarray(jnp.stack(
+            [kept[-1][:n] for kept, ffn in zip(self.cache.peek(),
+                                               self.cfg.ffn_types)
+             if ffn == "experts"], axis=1))
+        loads = by_row.sum(axis=0)
+        metrics.incr("moe.assignments",
+                     tokens * self.cfg.num_experts_per_tok * len(loads))
+        metrics.incr("moe.assignments_held", int(loads.sum()))
+        for load in loads:
+            if load.sum():
+                metrics.observe("moe.expert_load_max_over_mean",
+                                float(load.max() / load.mean()),
+                                buckets=_LOAD_BUCKETS)
+        return by_row
 
     def _prefill(self, prompts, lens: np.ndarray, rows: int):
         import jax.numpy as jnp
@@ -402,7 +841,8 @@ class CausalLM:
                 p = prompts[min(r, n - 1)]
                 tokens[r, :len(p)] = p
             pos = np.arange(chunks * T, dtype=np.int32)[None, :]
-            pos = np.where(pos < lens[:, None], pos, -1).astype(np.int32)
+            live = (np.arange(rows) < n)[:, None]    # rows beyond n: padding
+            pos = np.where((pos < lens[:, None]) & live, pos, -1).astype(np.int32)
             prog = self._program("lm.prefill_chunk", _build_prefill_chunk, rows)
             hidden = jnp.zeros((rows, self.cfg.hidden_size), jnp.float32)
             for c in range(chunks):
@@ -425,6 +865,7 @@ class CausalLM:
         The host waits for step ``i - 1`` while step ``i`` runs, so the time
         between two steps' ends is a step's and the device never waits."""
         toks, logprobs = [tok], [logprob]
+        live = np.arange(rows) < n
         with trace_span("lm.decode", rows=n, steps=max_new - 1):
             prog = self._program("lm.decode_step", _build_decode_step, rows)
             t_last = time.perf_counter()
@@ -432,7 +873,7 @@ class CausalLM:
                 with step_annotation("lm.decode_step", i):
                     state, tok, logprob = prog(
                         self.params, self.cache.take(), tok,
-                        (lens + i - 1).astype(np.int32))
+                        np.where(live, lens + i - 1, -1).astype(np.int32))
                     self.cache.put(state)
                     toks.append(tok)
                     logprobs.append(logprob)
@@ -442,6 +883,10 @@ class CausalLM:
                                 buckets=_STEP_BUCKETS)
                 metrics.observe("lm.step_slots_in_use", float(n),
                                 buckets=_ROW_BUCKETS)
+                if self._grows:
+                    metrics.observe("lm.step_latent_positions",
+                                    float(lens[:n].mean()) + i,
+                                    buckets=_POSITION_BUCKETS)
                 t_last = now
             ids = np.stack([np.asarray(t) for t in toks], axis=1)
             lps = np.stack([np.asarray(l) for l in logprobs], axis=1)
@@ -449,7 +894,8 @@ class CausalLM:
         return ids, lps
 
 
-def load_causal_lm(path: str, *, slots: int) -> Tuple[CausalLM, List[str]]:
+def load_causal_lm(path: str, *, slots: int, positions: int = 0
+                   ) -> Tuple[CausalLM, List[str]]:
     """The model of an HF-layout checkpoint directory (``config.json``,
     sharded safetensors, ``vocab.txt``) on the first device in bfloat16, and
     its vocabulary. Each tensor goes from the memory-mapped file to the
@@ -470,5 +916,5 @@ def load_causal_lm(path: str, *, slots: int) -> Tuple[CausalLM, List[str]]:
         (name, jax.device_put(bf16_cast(arr), device))
         for name, arr in iter_safetensors(path)))
     jax.block_until_ready(params)
-    return (CausalLM(cfg, params, slots=slots),
+    return (CausalLM(cfg, params, slots=slots, positions=positions),
             load_vocab_file(os.path.join(path, "vocab.txt")))

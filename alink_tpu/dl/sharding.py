@@ -14,7 +14,15 @@ conventions):
 - the causal LM's leaves (dl/lm.py), which keep the checkpoint's (out, in)
   layout: q/k/v/gate/up_proj shard `out` (heads, FFN columns) over `model`,
   o/down_proj shard `in`, embed_tokens and lm_head shard V; g_proj (one
-  output a key/value head), its bias and the norms are replicated
+  output a key/value head), its bias and the norms are replicated. Of the
+  kda and mla layers: f_proj, b_proj, kv_b_proj and the shared expert's
+  gate/up shard `out` (heads, columns), the shared expert's down `in`, the
+  short convolutions, dt_bias and A_log their channels (heads); the latent
+  projection kv_a_proj_with_mqa, the router and its bias are replicated, and
+  so is g_proj, whose name the three mixers share. The stacked experts
+  (experts_gate_up, experts_down: (E, in, out)) shard E over `expert`, an
+  axis `make_dl_mesh` does not build: a mesh that has it comes from
+  `parallel.mesh.make_mesh`, and on any other the experts are replicated
 - everything else replicated
 Batch dims of activations shard over `data`; sequence over `seq` when ring
 attention is enabled.
@@ -27,7 +35,8 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
-from ..parallel.mesh import AXIS_DATA, AXIS_MODEL, AXIS_SEQ, make_mesh
+from ..parallel.mesh import (AXIS_DATA, AXIS_EXPERT, AXIS_MODEL, AXIS_SEQ,
+                             make_mesh)
 
 
 def make_dl_mesh(dp: int = 0, tp: int = 1, sp: int = 1, devices=None):
@@ -61,12 +70,21 @@ def _spec_for(path: str, shape) -> "jax.sharding.PartitionSpec":
         return P(AXIS_MODEL, None)
     if leaf in _LM_SHARD_IN and nd == 2:
         return P(None, AXIS_MODEL)
+    if leaf in _LM_SHARD_CHANNELS and nd in (1, 3):
+        return P(AXIS_MODEL, *([None] * (nd - 1)))
+    if leaf in _LM_SHARD_EXPERT and nd == 3:
+        return P(AXIS_EXPERT, None, None)
     return P()
 
 
 _LM_SHARD_OUT = frozenset({"q_proj", "k_proj", "v_proj", "gate_proj", "up_proj",
-                           "embed_tokens", "lm_head"})
-_LM_SHARD_IN = frozenset({"o_proj", "down_proj"})
+                           "embed_tokens", "lm_head", "f_proj", "b_proj",
+                           "kv_b_proj", "shared_gate_proj", "shared_up_proj"})
+_LM_SHARD_IN = frozenset({"o_proj", "down_proj", "shared_down_proj"})
+# a value a channel (a head's): the short convolutions (C, 1, K), dt_bias, A_log
+_LM_SHARD_CHANNELS = frozenset({"q_conv1d", "k_conv1d", "v_conv1d", "dt_bias",
+                                "A_log"})
+_LM_SHARD_EXPERT = frozenset({"experts_gate_up", "experts_down"})
 
 
 def sharding_for(path: str, shape, mesh):

@@ -12,15 +12,20 @@ reserved ones: ``predictionCol`` (STRING), the continuation's word pieces
 joined into text; ``predictionDetailCol`` (STRING, optional), one JSON object
 ``{"prompt_tokens": n, "ids": [maxNewTokens ids], "logprobs": [maxNewTokens
 log-probabilities, each of the id chosen at that step under the model's own
-distribution]}``.
+distribution]}``; of a model with expert layers also ``"expert_load"``: per
+expert layer, how many of the row's tokens' assignments each expert held in
+this process served.
 
 The op keeps its mapper, the mapper its placed parameters and its state cache
 (``stateSlots`` sequences): a ``LocalPredictor`` or ``ModelServer`` loads the
 model in its first batch and never again. A table of more rows than
 ``stateSlots`` is generated in groups of that many. Rows of one group may
 differ in prompt length; none of a row's answer depends on its neighbours.
-A prompt of any length is taken whole: the model's memory is a state of fixed
-size, and the prefill program runs once per ``dl.lm.PREFILL_CHUNK`` positions.
+A prompt of any length is taken whole where the model's memory is a state of
+fixed size: the prefill program runs once per chunk of positions
+(``dl.lm.PREFILL_CHUNK``). A model with layers whose cache grows with the
+sequence (mla) has ``cachePositions`` positions a slot, sized at load; a row
+whose prompt and new tokens pass them is refused.
 """
 
 from __future__ import annotations
@@ -53,6 +58,11 @@ class HasCausalLMParams(HasSelectedCol, HasPredictionCol,
         "stateSlots", int, default=16, validator=MinValidator(1),
         desc="sequences the state cache holds, so the rows generated "
         "together; rounded up to a rung of the row ladder")
+    CACHE_POSITIONS = ParamInfo(
+        "cachePositions", int, default=4096, validator=MinValidator(1),
+        desc="positions a slot's latent cache holds, prompt and new tokens "
+        "together, where the model has layers whose cache grows (mla); "
+        "allocated at load, and a longer row is refused")
 
 
 class CausalLMGenerateMapper(Mapper, HasCausalLMParams):
@@ -69,7 +79,8 @@ class CausalLMGenerateMapper(Mapper, HasCausalLMParams):
 
         with trace_span("lm.load_model"):
             self._lm, vocab = load_causal_lm(
-                self.get(self.MODEL_PATH), slots=self.get(self.STATE_SLOTS))
+                self.get(self.MODEL_PATH), slots=self.get(self.STATE_SLOTS),
+                positions=self.get(self.CACHE_POSITIONS))
             self._tokenizer = Tokenizer.from_list(vocab)
         metrics.incr("lm.model_loads")
 
@@ -115,12 +126,16 @@ class CausalLMGenerateMapper(Mapper, HasCausalLMParams):
             with trace_span("lm.detokenize", rows=t.num_rows):
                 out_cols[pred] = [self._decode(row) for row in ids]
                 if detail:
+                    load = self._lm.expert_load
                     out_cols[detail] = [
-                        json.dumps({"prompt_tokens": len(p),
-                                    "ids": row.tolist(),
-                                    "logprobs": np.round(
-                                        lp.astype(np.float64), 6).tolist()})
-                        for p, row, lp in zip(prompts, ids, logprobs)]
+                        json.dumps(dict(
+                            {"prompt_tokens": len(p), "ids": row.tolist(),
+                             "logprobs": np.round(
+                                 lp.astype(np.float64), 6).tolist()},
+                            **({} if load is None
+                               else {"expert_load": load[i].tolist()})))
+                        for i, (p, row, lp) in enumerate(zip(prompts, ids,
+                                                             logprobs))]
         return self._append_result(t, out_cols, out_types)
 
 
