@@ -22,6 +22,9 @@ per-character form as its plain reference and compares with
   in the vocabulary once (``word -> ids``, a dict that is dropped when the
   call returns: no text and no id outlives a call), and counts
   ``tokenizer.words`` and ``tokenizer.word_memo_hits`` once per call.
+  ``encode_slices`` is the same call handed out a run of rows at a time, so
+  that a caller can start on the first rows while the rest are encoded;
+  the memo and the counts stay the whole call's.
 """
 
 from __future__ import annotations
@@ -30,7 +33,7 @@ import collections
 import re
 import sys
 import unicodedata
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -251,24 +254,43 @@ class Tokenizer:
         """Batch encode -> dict of (n, max_len) int32 arrays, each row what
         ``encode`` gives. Every distinct word of the call meets the
         vocabulary once (the memo lives as long as the call)."""
+        enc, = self.encode_slices(texts, pairs, max_len,
+                                  rows=max(len(texts), 1))
+        return enc
+
+    def encode_slices(
+        self, texts: Sequence[str], pairs: Optional[Sequence[str]] = None,
+        max_len: int = 128, *, rows: int,
+    ) -> Iterator[Dict[str, np.ndarray]]:
+        """``encode_batch`` a run of ``rows`` rows at a time: yields what
+        ``encode_batch`` gives for ``texts[0:rows]``, ``texts[rows:2*rows]``
+        and so on (the last run may be shorter; no text, one empty run), each
+        encoded when it is asked for. The memo is the whole call's, so a word
+        met in one run is not looked up again in a later one, and the two
+        counters are added once, before the last run is handed out."""
         n = len(texts)
-        ids = np.full((n, max_len), self.vocab[PAD], np.int32)
-        mask = np.zeros((n, max_len), np.int32)
-        types = np.zeros((n, max_len), np.int32)
+        pad = self.vocab[PAD]
         memo: _WordMemo = {}
         words = 0
-        for i, t in enumerate(texts):
-            p = pairs[i] if pairs is not None else None
-            row, n_a, n_words = self._encode_row(
-                str(t), p if p is None else str(p), max_len, memo)
-            ids[i, :len(row)] = row
-            mask[i, :len(row)] = 1
-            types[i, n_a:len(row)] = 1
-            words += n_words
-        metrics.incr("tokenizer.words", words)
-        metrics.incr("tokenizer.word_memo_hits", words - len(memo))
-        return {"input_ids": ids, "attention_mask": mask,
-                "token_type_ids": types}
+        for s in range(0, max(n, 1), rows):
+            m = min(rows, n - s)
+            ids = np.full((m, max_len), pad, np.int32)
+            mask = np.zeros((m, max_len), np.int32)
+            types = np.zeros((m, max_len), np.int32)
+            for i in range(m):
+                p = pairs[s + i] if pairs is not None else None
+                row, n_a, n_words = self._encode_row(
+                    str(texts[s + i]), p if p is None else str(p), max_len,
+                    memo)
+                ids[i, :len(row)] = row
+                mask[i, :len(row)] = 1
+                types[i, n_a:len(row)] = 1
+                words += n_words
+            if s + rows >= n:
+                metrics.incr("tokenizer.words", words)
+                metrics.incr("tokenizer.word_memo_hits", words - len(memo))
+            yield {"input_ids": ids, "attention_mask": mask,
+                   "token_type_ids": types}
 
     # -- persistence -------------------------------------------------------
     def to_list(self) -> List[str]:

@@ -39,8 +39,8 @@ import contextlib
 import dataclasses
 from dataclasses import dataclass
 from functools import partial
-from typing import (Any, Callable, Dict, Iterator, List, NamedTuple, Optional,
-                    Sequence, Tuple)
+from typing import (Any, Callable, Dict, Iterable, Iterator, List, NamedTuple,
+                    Optional, Sequence, Tuple, Union)
 
 import numpy as np
 
@@ -906,22 +906,64 @@ def train_model(
         return jax.device_get(params), history
 
 
-def _batched_apply(fn, params, inputs: Dict[str, np.ndarray], mesh, in_shard,
-                   bs: int) -> np.ndarray:
+# Rows of one slice of a predict whose producer can hand its rows out a part
+# at a time (the BERT mapper tokenises them): the forward of one slice runs on
+# the device while the host makes the next. A rung of the default bucket
+# ladder, so a slice is a program a server has warmed. Read on the chip at
+# 32, 64 and 128 (PERF.md section 6, PR 33): the smaller slice leaves a
+# shorter last forward that nothing hides, the larger costs less device time
+# a row; 32 gave the shortest cycle of a served batch of 256.
+PREDICT_SLICE = 32
+# Forwards dispatched and not yet read back: enough that the device always
+# has the next one queued, few enough that a long table's inputs and results
+# do not pile up on the device.
+_SLICES_OUT = 4
+
+PredictInputs = Dict[str, np.ndarray]
+
+
+def _batched_apply(fn, params,
+                   inputs: Union[PredictInputs, Iterable[PredictInputs]],
+                   mesh, in_shard, bs: int) -> np.ndarray:
+    """``fn`` over the rows of ``inputs``, at most ``bs`` rows a call, rows
+    in order. ``inputs`` is one dict of row-aligned arrays or an iterable of
+    such dicts. Nothing is waited for inside the loop: a chunk is padded,
+    placed and dispatched, its copy back is started, and only then is the
+    next chunk cut (or asked of the iterable, which may do host work for it
+    under the forward just dispatched). Results are read in order at the
+    end, or from the oldest on once ``_SLICES_OUT`` are out."""
+    import collections
+
     import jax
 
     from ..common.jitcache import bucket_rows, bucketing_enabled
+    from ..common.metrics import metrics
     from ..parallel.mesh import AXIS_DATA
 
     dp = mesh.shape.get(AXIS_DATA, 1)
-    names = sorted(inputs)
-    n = inputs[names[0]].shape[0]
-    outs = []
-    for s in range(0, n, bs):
-        # one span per chunk: pad, place, call, and the copy back that
-        # waits for the device
+
+    def chunks():
+        for part in ((inputs,) if isinstance(inputs, dict) else inputs):
+            names = sorted(part)
+            for s in range(0, part[names[0]].shape[0], bs):
+                yield names, [np.asarray(part[k][s:s + bs]) for k in names]
+
+    out_q: collections.deque = collections.deque()
+    outs: List[np.ndarray] = []
+
+    def read_oldest():
+        out, m = out_q.popleft()
+        # its own span, never inside the dispatch's or the producer's: the
+        # host waits here for the forward (and has nothing else to do)
         with trace_span("dl.predict.apply"):
-            chunk = [np.asarray(inputs[k][s:s + bs]) for k in names]
+            outs.append(np.asarray(out)[:m])
+
+    rows = m = 0
+    # the loop asks for the next chunk when this one's forward is on its way
+    for names, chunk in chunks():
+        if len(out_q) == _SLICES_OUT:
+            read_oldest()
+        with trace_span("dl.predict.apply"):
             m = chunk[0].shape[0]
             # pad up the bucket ladder (then to the data-axis multiple) and
             # trim after — the forward pass is row-wise, so repeated-last-row
@@ -933,7 +975,15 @@ def _batched_apply(fn, params, inputs: Dict[str, np.ndarray], mesh, in_shard,
                 chunk = _pad_tail(chunk, target)
             batch = {k: jax.device_put(v, in_shard(v))
                      for k, v in zip(names, chunk)}
-            outs.append(np.asarray(fn(params, batch))[:m])
+            out = fn(params, batch)
+            out.copy_to_host_async()
+            out_q.append((out, m))
+        rows += m
+    while out_q:
+        read_oldest()
+    metrics.incr("predict.rows", rows)
+    # every chunk but the last was dispatched before the last was asked for
+    metrics.incr("predict.rows_dispatched_ahead", rows - m)
     return np.concatenate(outs, axis=0)
 
 
@@ -985,11 +1035,18 @@ def prepare_params(model, params, *, mesh=None,
 
 
 def predict_model(
-    model, params, inputs: Dict[str, np.ndarray], *, mesh=None,
+    model, params,
+    inputs: Union[PredictInputs, Iterable[PredictInputs]], *, mesh=None,
     batch_size: int = 256, seq_axis: Optional[int] = 1,
     precision: Optional[str] = None,
 ) -> np.ndarray:
     """Batched inference returning logits (n, out_dim).
+
+    ``inputs`` is a dict of row-aligned arrays, or an iterable of such dicts,
+    one per slice of the rows (of ``PREDICT_SLICE`` rows, by convention). A
+    slice is asked of the iterable only after the forward of the slice before
+    it has been dispatched, so whatever the iterable does to make a slice (a
+    generator that tokenises) runs on the host while the device works.
 
     ``precision`` applies the serving quantization policy to the encoder:
     ``int8`` quantizes every >=2-D float parameter per-channel (weight-only

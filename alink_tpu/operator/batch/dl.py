@@ -474,17 +474,31 @@ class BertTextModelMapper(RichModelMapper):
         return self.meta.get("labelType", AlinkTypes.STRING)
 
     def predict_block(self, t: MTable):
-        from ...dl.train import predict_model, prepare_params
+        from ...dl.train import PREDICT_SLICE, predict_model, prepare_params
 
         meta = self.meta
         text_col = self.get(self.TEXT_COL) or meta["textCol"]
         pair_col = self.get(self.TEXT_PAIR_COL) or meta.get("textPairCol")
-        with trace_span("bert.tokenize", rows=t.num_rows):
-            texts = [str(v) for v in t.col(text_col)]
-            pairs = [str(v) for v in t.col(pair_col)] if pair_col else None
-            enc = self.tokenizer.encode_batch(
-                texts, pairs, max_len=int(meta["maxSeqLength"])
-            )
+        n = t.num_rows
+        encoded = self.tokenizer.encode_slices(
+            t.col(text_col), t.col(pair_col) if pair_col else None,
+            max_len=int(meta["maxSeqLength"]), rows=PREDICT_SLICE)
+
+        def slices():
+            # predict_model asks for a slice once the forward of the one
+            # before is on its way: the rows are tokenised under it. One
+            # span a slice, a leaf beside dl.predict.apply
+            for s in range(0, n, PREDICT_SLICE):
+                with trace_span("bert.tokenize",
+                                rows=min(PREDICT_SLICE, n - s)):
+                    enc = next(encoded)
+                yield enc
+            # asked once more when the last slice's forward is on its way:
+            # the call's memo (tens of thousands of words a batch) is dropped
+            # under that forward, not after the answer
+            with trace_span("bert.tokenize", rows=0):
+                encoded.close()
+
         # the first predict applies the policy and places the parameters;
         # from then on they stay where the forward program takes them (only
         # another default mesh places them again) and the host tree goes
@@ -492,7 +506,7 @@ class BertTextModelMapper(RichModelMapper):
             self.model, self.params if self._placed is None else self._placed,
             precision=self._policy)
         self.params = None
-        logits = predict_model(self.model, self._placed, enc)
+        logits = predict_model(self.model, self._placed, slices())
         with trace_span("bert.postprocess"):
             if meta["regression"]:
                 return (logits[:, 0].astype(np.float64), AlinkTypes.DOUBLE,

@@ -344,3 +344,36 @@ def test_word_memo_does_not_outlive_a_call():
     _same_arrays(second, _ref_encode_batch(tok, ["the fox"], max_len=8))
     assert not [v for v in vars(tok).values()
                 if isinstance(v, dict) and "the" in v and v is not tok.vocab]
+
+
+# ---------------------------------------------------------------------------
+# encode_slices: encode_batch handed out a run of rows at a time
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("rows", [1, 7, 64, 1000])
+@pytest.mark.parametrize("paired", [False, True])
+def test_encode_slices_concatenated_equal_encode_batch(paired, rows):
+    texts = load_reviews(limit=150)
+    pairs = load_sst2()[0][:150] if paired else None
+    tok = Tokenizer.build(texts, vocab_size=600)
+    w0, h0 = _word_counters()
+    whole = tok.encode_batch(texts, pairs, max_len=48)
+    w1, h1 = _word_counters()
+    assert w1 - w0 > h1 - h0 > 0          # words repeat inside the call
+    slices = tok.encode_slices(texts, pairs, max_len=48, rows=rows)
+    first = next(slices)                  # nothing is encoded before it is asked
+    assert _word_counters() == ((w1, h1) if rows < 150 else
+                                (2 * w1 - w0, 2 * h1 - h0))
+    got = [first] + list(slices)
+    assert [len(g["input_ids"]) for g in got] \
+        == [min(rows, 150 - s) for s in range(0, 150, rows)]
+    _same_arrays({k: np.concatenate([g[k] for g in got]) for k in whole}, whole)
+    # one memo for the whole call: the counters grow as one whole call's do
+    w2, h2 = _word_counters()
+    assert (w2 - w1, h2 - h1) == (w1 - w0, h1 - h0)
+
+
+def test_encode_slices_of_no_text_is_one_empty_run():
+    tok = Tokenizer.from_list(_SMALL_VOCAB)
+    (only,) = tok.encode_slices([], max_len=8, rows=4)
+    assert only["input_ids"].shape == (0, 8)
