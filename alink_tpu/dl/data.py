@@ -256,3 +256,24 @@ def sst2_split(seed: int = 0, holdout: float = 0.2,
     hold, train = perm[:n_hold], perm[n_hold:]
     return ([texts[i] for i in train], y[train],
             [texts[i] for i in hold], y[hold])
+
+
+# ---------------------------------------------------------------------------
+# Packing documents into rows of a fixed length
+# ---------------------------------------------------------------------------
+
+
+def pack_rows(docs: "List[List[int]]", seq_len: int, eod_id: int) -> np.ndarray:
+    """Token-id documents laid end to end, ``eod_id`` after each, and cut
+    into rows of ``seq_len`` tokens: ``(rows, seq_len)`` int32 with no
+    padding. What is left after the last whole row is not trained on. A row
+    is attended whole: a document sees what precedes it in its row."""
+    n = sum(len(d) + 1 for d in docs)
+    flat = np.empty(n, np.int32)
+    at = 0
+    for d in docs:
+        flat[at:at + len(d)] = d
+        flat[at + len(d)] = eod_id
+        at += len(d) + 1
+    rows = n // seq_len
+    return flat[:rows * seq_len].reshape(rows, seq_len)

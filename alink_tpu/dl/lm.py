@@ -68,7 +68,7 @@ _STEP_BUCKETS = (0.0005, 0.001, 0.002, 0.004, 0.006, 0.008, 0.010, 0.012,
                  2.5, 10.0)
 _ROW_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0)
 _POSITION_BUCKETS = tuple(float(2 ** i) for i in range(4, 21))
-_LOAD_BUCKETS = (1.0, 1.25, 1.5, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0, 16.0, 64.0, 1024.0)
+LOAD_BUCKETS = (1.0, 1.25, 1.5, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0, 16.0, 64.0, 1024.0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,13 +104,24 @@ class CausalLMConfig:
     routed_scaling_factor: float = 1.0
     moe_intermediate_size: int = 0
     shared_intermediate_size: int = 0
+    # what differs between the families that share the mla and expert layers:
+    # a gate a head on the mla layer's output (bailing_hybrid), and the name
+    # the checkpoint gives the router's bias
+    mla_head_gate: bool = True
+    router_bias_name: str = "expert_bias"
+    # training an expert layer: the weight of the sequence-wise balance term
+    # and the step of the router's bias (DeepSeek-V3 section 4.2), unless the
+    # checkpoint's config.json says otherwise
+    balance_alpha: float = 1e-4
+    bias_update_rate: float = 1e-3
 
     @classmethod
     def from_hf(cls, hf: Dict[str, Any], **over) -> "CausalLMConfig":
         """From an HF ``config.json``, by its ``model_type``: ``brumby``
         (retention in every layer unless ``layer_types`` says otherwise: the
-        family publishes the dense block's keys and no key of its mixer) or
-        ``bailing_hybrid`` (:func:`_bailing_hybrid_fields`)."""
+        family publishes the dense block's keys and no key of its mixer),
+        ``bailing_hybrid`` (:func:`_bailing_hybrid_fields`) or
+        ``deepseek_v3`` (:func:`_deepseek_v3_fields`)."""
         if hf.get("hidden_act", "silu") != "silu":
             raise NotImplementedError(f"hidden_act {hf['hidden_act']!r}")
         n = int(hf["num_hidden_layers"])
@@ -120,7 +131,7 @@ class CausalLMConfig:
             intermediate_size=int(hf["intermediate_size"]), num_hidden_layers=n,
             num_attention_heads=heads,
             num_key_value_heads=int(hf.get("num_key_value_heads", heads)),
-            head_dim=int(hf.get("head_dim", int(hf["hidden_size"]) // heads)),
+            head_dim=int(hf.get("head_dim") or int(hf["hidden_size"]) // heads),
             rms_norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
             rope_theta=float(hf.get("rope_theta", 1e4)))
         model_type = hf.get("model_type")
@@ -133,10 +144,12 @@ class CausalLMConfig:
                           layer_types=kind)
         elif model_type == "bailing_hybrid":
             fields.update(_bailing_hybrid_fields(hf, n))
+        elif model_type == "deepseek_v3":
+            fields.update(_deepseek_v3_fields(hf, n))
         else:
             raise NotImplementedError(
                 f"model_type {model_type!r}: the block is written for "
-                f"'brumby' and 'bailing_hybrid'")
+                f"'brumby', 'bailing_hybrid' and 'deepseek_v3'")
         fields.update(over)
         return cls(**fields)
 
@@ -230,11 +243,8 @@ def _bailing_hybrid_fields(hf: Dict[str, Any], n: int) -> Dict[str, Any]:
     group = int(hf["layer_group_size"])
     dense = int(hf.get("first_k_dense_replace", 0))
     experts = int(hf["num_experts"])
-    held = tuple(int(v) for v in hf.get("experts_held") or (0, experts))
     groups = int(hf.get("n_group", 1))
-    if not (0 <= held[0] < held[1] <= experts) or experts % groups:
-        raise ValueError(f"experts_held {held} of {experts} experts in "
-                         f"{groups} groups")
+    held = _held(hf, experts, groups)
     return dict(
         layer_types=tuple("mla" if (i + 1) % group == 0 else "kda"
                           for i in range(n)),
@@ -251,6 +261,53 @@ def _bailing_hybrid_fields(hf: Dict[str, Any], n: int) -> Dict[str, Any]:
         moe_intermediate_size=int(hf["moe_intermediate_size"]),
         shared_intermediate_size=int(hf.get(
             "moe_shared_expert_intermediate_size", hf["moe_intermediate_size"])))
+
+
+def _held(hf: Dict[str, Any], experts: int, groups: int) -> Tuple[int, int]:
+    """``experts_held`` (``[lo, hi)``, this process's share of a checkpoint;
+    all of them where the key is absent): this repo's key."""
+    held = tuple(int(v) for v in hf.get("experts_held") or (0, experts))
+    if not (0 <= held[0] < held[1] <= experts) or experts % groups:
+        raise ValueError(f"experts_held {held} of {experts} experts in "
+                         f"{groups} groups")
+    return held
+
+
+def _deepseek_v3_fields(hf: Dict[str, Any], n: int) -> Dict[str, Any]:
+    """The ``deepseek_v3`` keys (Moonlight-16B-A3B publishes under them):
+    latent attention in every layer with no gate on its heads, the dense
+    feed-forward in the first ``first_k_dense_replace`` layers and experts in
+    the rest, ``n_shared_experts`` shared experts as one of their summed
+    width, the router's bias ``e_score_correction_bias``. A setting the block
+    does not compute is refused, not ignored."""
+    want = dict(q_lora_rank=None, scoring_func="sigmoid", topk_method="noaux_tc",
+                norm_topk_prob=True, rope_scaling=None, moe_layer_freq=1,
+                num_nextn_predict_layers=0, attention_bias=False,
+                tie_word_embeddings=False)
+    bad = {k: hf[k] for k, v in want.items() if k in hf and hf[k] != v}
+    if bad:
+        raise NotImplementedError(f"deepseek_v3 with {bad}: the block "
+                                  f"computes {({k: want[k] for k in bad})}")
+    dense = int(hf.get("first_k_dense_replace", 0))
+    experts = int(hf["n_routed_experts"])
+    groups = int(hf.get("n_group", 1))
+    dn, dr = int(hf["qk_nope_head_dim"]), int(hf["qk_rope_head_dim"])
+    return dict(
+        head_dim=dn + dr, layer_types=("mla",) * n,
+        ffn_types=tuple("dense" if i < dense else "experts" for i in range(n)),
+        kv_lora_rank=int(hf["kv_lora_rank"]), qk_nope_head_dim=dn,
+        qk_rope_head_dim=dr, v_head_dim=int(hf["v_head_dim"]),
+        num_experts=experts, experts_held=_held(hf, experts, groups),
+        num_experts_per_tok=int(hf["num_experts_per_tok"]), n_group=groups,
+        topk_group=int(hf.get("topk_group", 1)),
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        moe_intermediate_size=int(hf["moe_intermediate_size"]),
+        shared_intermediate_size=int(hf.get("n_shared_experts", 1))
+        * int(hf["moe_intermediate_size"]),
+        mla_head_gate=False, router_bias_name="e_score_correction_bias",
+        **{field: float(hf[key]) for field, key in (
+            ("balance_alpha", "aux_loss_alpha"),
+            ("bias_update_rate", "bias_update_rate")) if key in hf})
 
 
 def _mixer_shapes(cfg: CausalLMConfig, kind: str) -> Dict[str, Tuple[int, ...]]:
@@ -273,11 +330,14 @@ def _mixer_shapes(cfg: CausalLMConfig, kind: str) -> Dict[str, Tuple[int, ...]]:
                 "o_norm.weight": (d,), "o_proj.weight": (h, hq * d)}
     r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim, cfg.v_head_dim)
-    return {"q_proj.weight": (hq * (dn + dr), h),
-            "kv_a_proj_with_mqa.weight": (r + dr, h),
-            "kv_a_layernorm.weight": (r,),
-            "kv_b_proj.weight": (hq * (dn + dv), r),
-            "g_proj.weight": (hq, h), "o_proj.weight": (h, hq * dv)}
+    out = {"q_proj.weight": (hq * (dn + dr), h),
+           "kv_a_proj_with_mqa.weight": (r + dr, h),
+           "kv_a_layernorm.weight": (r,),
+           "kv_b_proj.weight": (hq * (dn + dv), r),
+           "o_proj.weight": (h, hq * dv)}
+    if cfg.mla_head_gate:
+        out["g_proj.weight"] = (hq, h)
+    return out
 
 
 def _ffn_shapes(cfg: CausalLMConfig, kind: str) -> Dict[str, Tuple[int, ...]]:
@@ -291,7 +351,7 @@ def _ffn_shapes(cfg: CausalLMConfig, kind: str) -> Dict[str, Tuple[int, ...]]:
     if kind == "dense":
         return swiglu("", cfg.intermediate_size)
     out = {"gate.weight": (cfg.num_experts, h),
-           "gate.expert_bias": (cfg.num_experts,)}
+           "gate." + cfg.router_bias_name: (cfg.num_experts,)}
     for e in range(*cfg.experts_held):
         out.update(swiglu(f"experts.{e}.", cfg.moe_intermediate_size))
     out.update(swiglu("shared_experts.", cfg.shared_intermediate_size))
@@ -318,7 +378,8 @@ def tree_path(hf_name: str) -> Tuple:
     """Where an HF tensor lives in the parameter tree: ``("embed_tokens",)``,
     ``("norm",)``, ``("lm_head",)`` or ``("layers", i, leaf)``. A linear's
     leaf is its name, its bias ``<linear>_bias``, a bare parameter
-    (``A_log``) its own name; the shared expert's leaves are
+    (``A_log``) its own name, but the router's bias, ``expert_bias`` whatever
+    the family calls it; the shared expert's leaves are
     ``shared_<linear>``, and expert ``e``'s lie under ``("layers", i,
     "experts", e, leaf)`` until :func:`_stack_experts` joins them."""
     parts = hf_name.split(".")
@@ -331,6 +392,8 @@ def tree_path(hf_name: str) -> Tuple:
         inside = parts[3:-2]
     else:
         leaf, inside = parts[-1], parts[3:-1]
+        if leaf == "e_score_correction_bias":
+            leaf = "expert_bias"
     if inside[-1:] == ["shared_experts"]:
         leaf = "shared_" + leaf
     if inside[-2:-1] == ["experts"]:
@@ -467,6 +530,24 @@ def _ffn(cfg: CausalLMConfig, layer, x):
                        layer["down_proj"])
 
 
+def _route(cfg: CausalLMConfig, layer, flat, bias):
+    """The router over ``flat (N,H)`` float32: its logits ``(N,E)``, the
+    experts each token chose ``(N,K)`` and their weights."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import moe
+
+    with jax.named_scope(moe.ROUTE_SCOPE):
+        logits = jnp.einsum("nh,eh->ne", flat, layer["gate"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        idx, w = moe.route(logits, bias, n_group=cfg.n_group,
+                           topk_group=cfg.topk_group,
+                           top_k=cfg.num_experts_per_tok,
+                           scale=cfg.routed_scaling_factor)
+    return logits, idx, w
+
+
 def _experts_ffn(cfg: CausalLMConfig, layer, x, valid, load):
     """The expert layer over ``x (B,T,H)``: the routed experts held here,
     the shared expert, and each row's count a held expert added to
@@ -479,13 +560,8 @@ def _experts_ffn(cfg: CausalLMConfig, layer, x, valid, load):
     B, T, H = x.shape
     n = _rms_norm(x, layer["post_attention_layernorm"], cfg.rms_norm_eps)
     flat = n.reshape(B * T, H)
+    _, idx, w = _route(cfg, layer, flat, layer["expert_bias"])
     with jax.named_scope(moe.ROUTE_SCOPE):
-        logits = jnp.einsum("nh,eh->ne", flat, layer["gate"].astype(jnp.float32),
-                            precision=jax.lax.Precision.HIGHEST)
-        idx, w = moe.route(logits, layer["expert_bias"], n_group=cfg.n_group,
-                           topk_group=cfg.topk_group,
-                           top_k=cfg.num_experts_per_tok,
-                           scale=cfg.routed_scaling_factor)
         local, added = moe.held_load(idx.reshape(B, T, -1), valid,
                                      cfg.experts_held)
     y = moe.routed_experts(flat, local.reshape(B * T, -1), w, added.sum(axis=0),
@@ -494,6 +570,35 @@ def _experts_ffn(cfg: CausalLMConfig, layer, x, valid, load):
     shared = _swiglu(n, layer["shared_gate_proj"], layer["shared_up_proj"],
                      layer["shared_down_proj"])
     return x + y.reshape(B, T, H) + shared, load + added
+
+
+def _experts_ffn_whole(cfg: CausalLMConfig, layer, x, bias, piece: int):
+    """The expert layer over whole sequences ``x (B,T,H)`` with the router's
+    bias handed in (training keeps it beside the parameters): the layer's
+    output, each row's balance term ``(B,)`` and how many assignments each of
+    the router's outputs got ``(E,)``, held here or not."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import moe
+
+    B, T, H = x.shape
+    n = _rms_norm(x, layer["post_attention_layernorm"], cfg.rms_norm_eps)
+    flat = n.reshape(B * T, H)
+    logits, idx, w = _route(cfg, layer, flat, bias)
+    with jax.named_scope(moe.ROUTE_SCOPE):
+        idx = idx.reshape(B, T, -1)
+        by_row = moe.load_counts(idx, cfg.num_experts)               # (B,E)
+        balance = moe.seq_balance(logits.reshape(B, T, -1), by_row,
+                                  top_k=cfg.num_experts_per_tok)
+        local, held = moe.held_load(idx, jnp.ones((B, T), bool),
+                                    cfg.experts_held)
+    y = moe.routed_experts(flat, local.reshape(B * T, -1), w, held.sum(axis=0),
+                           layer["experts_gate_up"], layer["experts_down"],
+                           dtype=jnp.dtype(cfg.dtype), piece=piece)
+    shared = _swiglu(n, layer["shared_gate_proj"], layer["shared_up_proj"],
+                     layer["shared_down_proj"])
+    return x + y.reshape(B, T, H) + shared, balance, by_row.sum(axis=0)
 
 
 def _kda_mixer(cfg: CausalLMConfig, layer, a, valid, S, tail, *, chunk: bool):
@@ -535,10 +640,60 @@ def _kda_mixer(cfg: CausalLMConfig, layer, a, valid, S, tail, *, chunk: bool):
     return _linear(o.reshape(*lead, heads * d), layer["o_proj"]), S, tail
 
 
+def _mla_inputs(cfg: CausalLMConfig, layer, a, pos):
+    """What an mla layer makes of ``a (B,T,H)``: the queries' two parts
+    (the second rotated), and each position's cache entry, the normed latent
+    and the rotated shared key side by side."""
+    import jax.numpy as jnp
+
+    from . import mla
+
+    B, T, _ = a.shape
+    r, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = _linear(a, layer["q_proj"]).reshape(B, T, cfg.num_attention_heads,
+                                            dn + dr)
+    q_r = mla.rope_interleaved(q[..., dn:], pos, cfg.rope_theta)
+    kv = _linear(a, layer["kv_a_proj_with_mqa"])
+    new = jnp.concatenate(
+        [_rms_norm(kv[..., :r], layer["kv_a_layernorm"], cfg.rms_norm_eps),
+         mla.rope_interleaved(kv[..., r:], pos, cfg.rope_theta)], axis=-1)
+    return q[..., :dn], q_r, new
+
+
+def _mla_output(cfg: CausalLMConfig, layer, a, o):
+    """The heads' outputs ``o (B,T,heads,Dv)``, gated a head where the
+    family has the gate, through ``o_proj``."""
+    import jax
+
+    if cfg.mla_head_gate:
+        o = o * jax.nn.sigmoid(_linear(a, layer["g_proj"]))[..., None]
+    return _linear(o.reshape(*o.shape[:2], -1), layer["o_proj"])
+
+
 def _mla_mixer(cfg: CausalLMConfig, layer, a, pos, valid, latent, length):
     """``a (B,T,H)`` through one mla layer's mixer: the positions' latents
     written to the cache, then attention over it in the absorbed form."""
-    import jax
+    import jax.numpy as jnp
+
+    from . import mla
+
+    heads = cfg.num_attention_heads
+    r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    a = a.astype(layer["q_proj"].dtype)
+    q_n, q_r, new = _mla_inputs(cfg, layer, a, pos)
+    start = length
+    latent, length = mla.cache_write(latent, length, new, valid)
+    w_kvb = layer["kv_b_proj"].reshape(heads, dn + dv, r)
+    o = mla.attend(q_n, q_r, latent, start, w_kvb[:, :dn], w_kvb[:, dn:],
+                   scale=(dn + dr) ** -0.5, dtype=jnp.dtype(cfg.dtype))
+    return _mla_output(cfg, layer, a, o), latent, length
+
+
+def _mla_mixer_whole(cfg: CausalLMConfig, layer, a, pos):
+    """``a (B,T,H)``, whole sequences with no cache (training), through one
+    mla layer's mixer in the expanded form: keys ``[k_n, k_r]`` and values a
+    head from the latent, then the causal core in blocks."""
     import jax.numpy as jnp
 
     from . import mla
@@ -548,19 +703,14 @@ def _mla_mixer(cfg: CausalLMConfig, layer, a, pos, valid, latent, length):
     r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim, cfg.v_head_dim)
     a = a.astype(layer["q_proj"].dtype)
-    q = _linear(a, layer["q_proj"]).reshape(B, T, heads, dn + dr)
-    q_r = mla.rope_interleaved(q[..., dn:], pos, cfg.rope_theta)
-    kv = _linear(a, layer["kv_a_proj_with_mqa"])
-    new = jnp.concatenate(
-        [_rms_norm(kv[..., :r], layer["kv_a_layernorm"], cfg.rms_norm_eps),
-         mla.rope_interleaved(kv[..., r:], pos, cfg.rope_theta)], axis=-1)
-    start = length
-    latent, length = mla.cache_write(latent, length, new, valid)
-    w_kvb = layer["kv_b_proj"].reshape(heads, dn + dv, r)
-    o = mla.attend(q[..., :dn], q_r, latent, start, w_kvb[:, :dn], w_kvb[:, dn:],
-                   scale=(dn + dr) ** -0.5, dtype=jnp.dtype(cfg.dtype))
-    o = o * jax.nn.sigmoid(_linear(a, layer["g_proj"]))[..., None]
-    return _linear(o.reshape(B, T, heads * dv), layer["o_proj"]), latent, length
+    q_n, q_r, new = _mla_inputs(cfg, layer, a, pos)
+    kv = _linear(new[..., :r], layer["kv_b_proj"]).reshape(B, T, heads, dn + dv)
+    k_r = jnp.broadcast_to(new[..., None, r:], (B, T, heads, dr))
+    o = mla.causal_core(jnp.concatenate([q_n, q_r], axis=-1),
+                        jnp.concatenate([kv[..., :dn], k_r], axis=-1),
+                        kv[..., dn:], scale=(dn + dr) ** -0.5,
+                        dtype=jnp.dtype(cfg.dtype))
+    return _mla_output(cfg, layer, a, o)
 
 
 def _block(cfg: CausalLMConfig, i: int, layer, x, pos, valid, state, *,
@@ -826,7 +976,7 @@ class CausalLM:
             if load.sum():
                 metrics.observe("moe.expert_load_max_over_mean",
                                 float(load.max() / load.mean()),
-                                buckets=_LOAD_BUCKETS)
+                                buckets=LOAD_BUCKETS)
         return by_row
 
     def _prefill(self, prompts, lens: np.ndarray, rows: int):
@@ -918,3 +1068,213 @@ def load_causal_lm(path: str, *, slots: int, positions: int = 0
     jax.block_until_ready(params)
     return (CausalLM(cfg, params, slots=slots, positions=positions),
             load_vocab_file(os.path.join(path, "vocab.txt")))
+
+
+# -- training ------------------------------------------------------------------
+
+# sorted rows of one expert that the training step takes through its matrices
+# at a time: 16,384 tokens give a held expert 1,536 under an even router, two
+# pieces of which a quarter is padding; a piece of 2,048 would be one
+TRAIN_EXPERT_PIECE = 1024
+# positions whose logits are live at once in the training loss (4,096 x 20,480
+# float32 are 335 MB, and as much again for their gradient), and whose
+# intermediates are live at once in the dense feed-forward (4,096 x 11,264
+# float32 are 185 MB, several times over in its backward pass)
+TRAIN_LOSS_PIECE = 4096
+
+
+def _compute_dtype(cfg: CausalLMConfig, layer):
+    """A layer's matrices in the dtype its products run in; the norms'
+    scales and the router stay as they are kept (float32)."""
+    import jax.numpy as jnp
+
+    dtype = jnp.dtype(cfg.dtype)
+    return {k: v.astype(dtype) if v.ndim >= 2 and k != "gate" else v
+            for k, v in layer.items()}
+
+
+def _pieces(n: int, piece: int) -> int:
+    """The largest count of positions at or under ``piece`` that divides
+    ``n``."""
+    import math
+
+    piece = min(piece, n)
+    return piece if n % piece == 0 else math.gcd(n, piece)
+
+
+def _ffn_by_piece(cfg: CausalLMConfig, layer, x, piece: int):
+    """:func:`_ffn` over ``x (B,T,H)``, ``piece`` positions at a time, each
+    piece's intermediates computed again in the backward pass."""
+    import jax
+
+    B, T, H = x.shape
+    piece = _pieces(B * T, piece)
+    out = jax.lax.map(jax.checkpoint(lambda part: _ffn(cfg, layer, part)),
+                      x.reshape(B * T // piece, piece, H))
+    return out.reshape(B, T, H)
+
+
+def _layer_whole(cfg: CausalLMConfig, i: int, layer, bias, x, pos):
+    """Layer ``i`` over whole sequences ``x (B,T,H)`` float32, no cache."""
+    layer = _compute_dtype(cfg, layer)
+    a = _rms_norm(x, layer["input_layernorm"], cfg.rms_norm_eps)
+    x = x + _mla_mixer_whole(cfg, layer, a, pos)
+    if cfg.ffn_type(i) == "dense":
+        return _ffn_by_piece(cfg, layer, x, TRAIN_LOSS_PIECE), None, None
+    return _experts_ffn_whole(cfg, layer, x, bias, TRAIN_EXPERT_PIECE)
+
+
+def _token_losses(cfg: CausalLMConfig, params, x, targets, piece: int):
+    """The cross-entropy of every position's logits against its target:
+    ``x (N,H)`` float32, targets ``(N,)``; ``piece`` positions' logits at a
+    time, each piece's computed again in the backward pass."""
+    import jax
+    import jax.numpy as jnp
+
+    N, H = x.shape
+    piece = _pieces(N, piece)
+    head = params["lm_head"].astype(jnp.dtype(cfg.dtype))
+
+    @jax.checkpoint
+    def part(norm, head, h, tgt):
+        with jax.named_scope("lm_head"):
+            logits = _linear(_rms_norm(h, norm, cfg.rms_norm_eps), head)
+        with jax.named_scope("loss"):
+            at = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
+            return jax.nn.logsumexp(logits, axis=-1) - at
+
+    out = jax.lax.map(lambda xs: part(params["norm"], head, *xs),
+                      (x.reshape(N // piece, piece, H),
+                       targets.reshape(N // piece, piece)))
+    return out.reshape(N)
+
+
+def train_rows(cfg: CausalLMConfig, params, bias, tokens):
+    """Whole rows of tokens ``(B,T)`` through the stack, a layer
+    rematerialised at a time. ``bias`` ``(expert layers, E)``: the router's
+    bias of each. Returns each row's loss ``(B,)``: the mean over its
+    positions but the last of the cross-entropy against the next token, plus
+    ``cfg.balance_alpha`` times the mean over the expert layers of the row's
+    balance term; and the assignments each router output got, ``(expert
+    layers, E)`` int32."""
+    import jax
+    import jax.numpy as jnp
+    from functools import partial
+
+    if set(cfg.layer_types) != {"mla"}:
+        raise NotImplementedError(
+            f"layer_types {sorted(set(cfg.layer_types))}: the training step "
+            f"is written for mla layers (retention and kda have no backward "
+            f"pass)")
+    B, T = tokens.shape
+    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    x = jnp.take(params["embed_tokens"], tokens, axis=0).astype(jnp.float32)
+    balances, counts, e = [], [], 0
+    for i, layer in enumerate(params["layers"]):
+        dense = cfg.ffn_type(i) == "dense"
+        x, balance, count = jax.checkpoint(
+            partial(_layer_whole, cfg, i))(
+                layer, None if dense else bias[e], x, pos)
+        if not dense:
+            balances.append(balance)
+            counts.append(count)
+            e += 1
+    targets = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
+    ce = _token_losses(cfg, params, x.reshape(B * T, -1),
+                       targets.reshape(-1), TRAIN_LOSS_PIECE).reshape(B, T)
+    rows = ce[:, :-1].sum(axis=1) / (T - 1)
+    if balances:
+        rows = rows + cfg.balance_alpha * jnp.stack(balances).mean(axis=0)
+    return rows, (jnp.stack(counts) if counts
+                  else jnp.zeros((0, max(cfg.num_experts, 1)), jnp.int32))
+
+
+@dataclasses.dataclass(frozen=True)
+class CausalLMTrainer:
+    """The decoder stack as :func:`alink_tpu.dl.train.train_model` takes a
+    model: ``apply`` over the variables ``{"params": the parameter tree
+    without the routers' biases, "router": {"expert_bias": (expert layers, E)
+    float32, "load": (expert layers, E) int32}}`` and a batch ``tokens
+    (B,T)`` gives each row's loss (the loop's loss ``"rows"``). The
+    ``router`` collection is state a rule updates and no gradient: asked for
+    as ``mutable``, it comes back with each bias moved by
+    :func:`alink_tpu.dl.moe.bias_step` from this step's assignments, and with
+    those assignments added to ``load``, which the op reads once an epoch."""
+
+    cfg: CausalLMConfig
+
+    def apply(self, variables, tokens, deterministic=True, rngs=None,
+              mutable=()):
+        import jax
+
+        from . import moe
+
+        router = variables["router"]
+        rows, counts = train_rows(self.cfg, variables["params"],
+                                  router["expert_bias"], tokens)
+        if not mutable:
+            return rows
+        with jax.named_scope(moe.ROUTE_SCOPE):
+            bias = jax.vmap(lambda b, c: moe.bias_step(
+                b, c, self.cfg.bias_update_rate))(
+                router["expert_bias"], counts) if counts.shape[0] \
+                else router["expert_bias"]
+        return rows, {"router": {"expert_bias": bias,
+                                 "load": router["load"] + counts}}
+
+
+def training_variables(cfg: CausalLMConfig, path: str) -> Dict[str, Any]:
+    """The variables :class:`CausalLMTrainer` takes, from an HF-layout
+    checkpoint directory: float32 master weights on the host (the stacked
+    experts on the device, where they are joined), the routers' biases taken
+    out of the parameter tree into the ``router`` collection."""
+    from .pretrained import iter_safetensors
+
+    params = params_from_tensors(cfg, (
+        (name, np.asarray(arr, np.float32))
+        for name, arr in iter_safetensors(path)))
+    biases = [layer.pop("expert_bias") for layer in params["layers"]
+              if "expert_bias" in layer]
+    e = max(cfg.num_experts, 1)
+    bias = np.stack(biases) if biases else np.zeros((0, e), np.float32)
+    return {"params": params,
+            "router": {"expert_bias": bias.astype(np.float32),
+                       "load": np.zeros(bias.shape, np.int32)}}
+
+
+def hf_tensors(cfg: CausalLMConfig, variables):
+    """A trained state's tensors under their HF names, a layer at a time
+    (``(shard, [(name, array)])``: the embedding, each layer, the final norm
+    and head), the stacked experts taken apart again and each router's bias
+    back under the family's name."""
+    params, bias = variables["params"], variables["router"]["expert_bias"]
+    yield [("model.embed_tokens.weight", params["embed_tokens"])]
+    lo, hi = cfg.experts_held
+    f, e = cfg.moe_intermediate_size, 0
+    for i, layer in enumerate(params["layers"]):
+        p = f"model.layers.{i}."
+        out = []
+        names = {**_mixer_shapes(cfg, cfg.layer_types[i]),
+                 **{"mlp." + k: v for k, v in
+                    _ffn_shapes(cfg, cfg.ffn_type(i)).items()}}
+        for name in names:
+            full = p + (name if name.startswith("mlp.") else "self_attn." + name)
+            path = tree_path(full)
+            if len(path) == 5:                   # ("layers", i, "experts", e, leaf)
+                at, leaf = path[3] - lo, path[4]
+                if leaf == "down_proj":
+                    arr = np.asarray(layer["experts_down"][at]).T
+                else:
+                    gu = np.asarray(layer["experts_gate_up"][at])
+                    arr = (gu[:, :f] if leaf == "gate_proj" else gu[:, f:]).T
+            elif path[-1] == "expert_bias":
+                arr = bias[e]
+            else:
+                arr = layer[path[-1]]
+            out.append((full, np.asarray(arr)))
+        for norm in ("input_layernorm", "post_attention_layernorm"):
+            out.append((p + norm + ".weight", np.asarray(layer[norm])))
+        e += cfg.ffn_type(i) == "experts"
+        yield out
+    yield [("model.norm.weight", params["norm"]),
+           ("lm_head.weight", params["lm_head"])]

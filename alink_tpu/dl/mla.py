@@ -17,14 +17,24 @@ c_s`` out to a head's values. The same function serves a chunk of a prompt
 (``T`` queries a row, the chunk's own positions already written) and a decode
 step (``T = 1``): nothing is ever expanded to keys and values a head.
 
+Training has no cache and whole sequences, so there the layer runs in its
+expanded form: keys ``[k_n, k_r]`` and values a head, and
+:func:`causal_core` over them: blocks of queries against blocks of keys with
+a running maximum and sum, no block above the diagonal, per row and head only
+the output and the log-sum kept, a block's probabilities computed again in the
+backward pass. Nothing with two sequence-length dimensions is written.
+
 Rotary positions here are the interleaved pairs (``rope_interleave``).
 """
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
+from ..common.metrics import metrics
 from .retention import einsum_f32
 
 MLA_SCOPE = "mla_core"
@@ -32,6 +42,9 @@ MLA_SCOPE = "mla_core"
 # taken a group at a time (a prompt chunk of 128 rows against 2,176 cached
 # positions would hold 2.3 GB of scores at once)
 _SCORE_BYTES = 256 << 20
+# positions a block of :func:`causal_core`: the scores of one block pair are
+# (rows, heads, block, block) float32, 134 MB at 2 rows of 16 heads
+CAUSAL_BLOCK = 1024
 
 
 def rope_interleaved(x, pos, theta: float):
@@ -99,3 +112,108 @@ def attend(q_n, q_r, latent, start, w_kb, w_vb, *, scale: float, dtype):
     split = lambda x: x.reshape(B // group, group, *x.shape[1:])
     out = jax.lax.map(core, tuple(split(x) for x in (q_n, q_r, latent, start)))
     return out.reshape(B, T, H, -1)
+
+
+def causal_core(q, k, v, *, scale: float, dtype):
+    """Causal softmax attention of whole sequences, in blocks.
+
+    q, k ``(B,T,H,D)``, v ``(B,T,H,Dv)`` float32 or ``dtype``; position
+    ``t`` sees ``s <= t``. Returns ``(B,T,H,Dv)`` float32. ``T`` need not be
+    a multiple of ``CAUSAL_BLOCK``: the padding lies after every real
+    position, so no real query sees it."""
+    B, T, H, _ = q.shape
+    block = min(CAUSAL_BLOCK, T)
+    pad = (-T) % block
+    heads_first = lambda x: jnp.pad(
+        x.astype(dtype), ((0, 0), (0, pad), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    metrics.incr("attention.causal_block_traces")
+    with jax.named_scope(MLA_SCOPE):
+        o = _causal(heads_first(q), heads_first(k), heads_first(v),
+                    float(scale), block)
+    return o.transpose(0, 2, 1, 3)[:, :T]
+
+
+def _block_of(x, i, block):
+    return jax.lax.dynamic_slice_in_dim(x, i * block, block, axis=2)
+
+
+def _scores(q_i, k_j, i, j, scale, block):
+    """The masked scores of query block ``i`` against key block ``j``."""
+    s = einsum_f32("bhqd,bhkd->bhqk", q_i, k_j) * scale
+    at = jnp.arange(block)
+    seen = (j * block + at)[None, :] <= (i * block + at)[:, None]
+    return jnp.where(seen, s, -jnp.inf)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _causal(q, k, v, scale, block):
+    return _causal_fwd(q, k, v, scale, block)[0]
+
+
+def _causal_fwd(q, k, v, scale, block):
+    B, H, T, _ = q.shape
+    Dv = v.shape[-1]
+
+    def rows(i):
+        q_i = _block_of(q, i, block)
+
+        def keys(j, carry):
+            m, l, acc = carry
+            s = _scores(q_i, _block_of(k, j, block), i, j, scale, block)
+            m_new = jnp.maximum(m, s.max(-1))
+            p = jnp.exp(s - m_new[..., None])
+            fix = jnp.exp(m - m_new)
+            acc = acc * fix[..., None] + einsum_f32(
+                "bhqk,bhkd->bhqd", p.astype(v.dtype), _block_of(v, j, block))
+            return m_new, l * fix + p.sum(-1), acc
+
+        m, l, acc = jax.lax.fori_loop(0, i + 1, keys, (
+            jnp.full((B, H, block), -jnp.inf, jnp.float32),
+            jnp.zeros((B, H, block), jnp.float32),
+            jnp.zeros((B, H, block, Dv), jnp.float32)))
+        return acc / l[..., None], m + jnp.log(l)
+
+    o, lse = jax.lax.map(rows, jnp.arange(T // block))           # (nb,B,H,..)
+    o = jnp.moveaxis(o, 0, 2).reshape(B, H, T, Dv)
+    lse = jnp.moveaxis(lse, 0, 2).reshape(B, H, T)
+    return o, (q, k, v, o, lse)
+
+
+def _causal_bwd(scale, block, kept, do):
+    q, k, v, o, lse = kept
+    B, H, T, D = q.shape
+    nb = T // block
+    delta = (o * do).sum(-1)                                     # (B,H,T)
+    do = do.astype(v.dtype)
+
+    def keys(dq, j):
+        k_j, v_j = _block_of(k, j, block), _block_of(v, j, block)
+
+        def rows(i, carry):
+            dq, dk_j, dv_j = carry
+            q_i, do_i = _block_of(q, i, block), _block_of(do, i, block)
+            s = _scores(q_i, k_j, i, j, scale, block)
+            p = jnp.exp(s - _block_of(lse, i, block)[..., None])
+            dv_j = dv_j + einsum_f32("bhqk,bhqd->bhkd", p.astype(v.dtype), do_i)
+            dp = einsum_f32("bhqd,bhkd->bhqk", do_i, v_j)
+            ds = (p * (dp - _block_of(delta, i, block)[..., None])
+                  * scale).astype(q.dtype)
+            dk_j = dk_j + einsum_f32("bhqk,bhqd->bhkd", ds, q_i)
+            dq_i = _block_of(dq, i, block) + einsum_f32(
+                "bhqk,bhkd->bhqd", ds, k_j)
+            return (jax.lax.dynamic_update_slice_in_dim(dq, dq_i, i * block, 2),
+                    dk_j, dv_j)
+
+        dq, dk_j, dv_j = jax.lax.fori_loop(j, nb, rows, (
+            dq, jnp.zeros(k_j.shape, jnp.float32),
+            jnp.zeros(v_j.shape, jnp.float32)))
+        return dq, (dk_j, dv_j)
+
+    dq, (dk, dv) = jax.lax.scan(keys, jnp.zeros(q.shape, jnp.float32),
+                                jnp.arange(nb))
+    whole = lambda x: jnp.moveaxis(x, 0, 2).reshape(B, H, T, -1)
+    return (dq.astype(q.dtype), whole(dk).astype(k.dtype),
+            whole(dv).astype(v.dtype))
+
+
+_causal.defvjp(_causal_fwd, _causal_bwd)

@@ -496,9 +496,34 @@ def save_bert_checkpoint(params, cfg, path: str, vocab: "list[str]") -> None:
         f.write("\n".join(vocab) + "\n")
 
 
+def write_safetensors_shards(path: str, shards) -> int:
+    """An HF-layout directory's safetensors from ``shards``, an iterable of
+    lists of ``(name, array)``: one file a list, written as it arrives, and
+    the index that names each tensor's file. Returns the bytes of tensors."""
+    files, total = [], 0
+    for tensors in shards:
+        tensors = dict(tensors)
+        files.append(sorted(tensors))
+        _write_safetensors(os.path.join(path, f".shard-{len(files)}"), tensors)
+        total += sum(int(a.nbytes) for a in tensors.values())
+    weight_map = {}
+    for i, names in enumerate(files):
+        fname = f"model-{i + 1:05d}-of-{len(files):05d}.safetensors"
+        os.replace(os.path.join(path, f".shard-{i + 1}"),
+                   os.path.join(path, fname))
+        weight_map.update({n: fname for n in names})
+    with open(os.path.join(path, SAFETENSORS_INDEX), "w") as f:
+        json.dump({"metadata": {"total_size": total},
+                   "weight_map": weight_map}, f)
+    return total
+
+
 def _write_safetensors(path: str, tensors: Dict[str, np.ndarray]) -> None:
+    import ml_dtypes
+
     _DT = {np.dtype(np.float32): "F32", np.dtype(np.float64): "F64",
-           np.dtype(np.int64): "I64", np.dtype(np.int32): "I32"}
+           np.dtype(np.int64): "I64", np.dtype(np.int32): "I32",
+           np.dtype(ml_dtypes.bfloat16): "BF16"}
     header: Dict[str, Any] = {}
     off = 0
     bufs = []

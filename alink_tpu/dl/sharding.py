@@ -23,6 +23,10 @@ conventions):
   (experts_gate_up, experts_down: (E, in, out)) shard E over `expert`, an
   axis `make_dl_mesh` does not build: a mesh that has it comes from
   `parallel.mesh.make_mesh`, and on any other the experts are replicated
+- the training state of the causal LM (dl/lm.CausalLMTrainer): the `router`
+  collection (`expert_bias`, `load`: (expert layers, E)) is replicated, as
+  the router is; the optimizer's moments carry their parameter's leaf name at
+  the end of their path, so they take their parameter's spec
 - everything else replicated
 Batch dims of activations shard over `data`; sequence over `seq` when ring
 attention is enabled.

@@ -59,7 +59,9 @@ class TrainConfig:
     early_stopping_patience: int = 0  # 0 = off
     eval_ratio: float = 0.0  # fraction of rows held out for eval
     seed: int = 0
-    loss: str = "auto"  # auto | softmax | mse
+    # auto | softmax | mse | gaussian_nll | rows (the model's output is each
+    # row's loss already: a language model sums its own over positions)
+    loss: str = "auto"
     log_every: int = 0
     # mid-training checkpoint/resume (dl/checkpoint.py); None disables
     checkpoint_dir: "str | None" = None
@@ -137,6 +139,9 @@ def _loss_fn(kind: str, regression: bool, weighted: "bool | str" = False):
             mu, log_sigma = logits[..., 0], logits[..., 1]
             sigma2 = jnp.exp(2.0 * log_sigma)
             return log_sigma + 0.5 * (y.astype(jnp.float32) - mu) ** 2 / sigma2
+    elif kind == "rows":
+        def per_row(rows, y):
+            return rows
     else:
         raise ValueError(f"unknown loss {kind!r}")
 
@@ -495,10 +500,20 @@ def train_model(
     regression: bool = False,
     seq_axis: Optional[int] = 1,
     init_params=None,
+    on_epoch: Optional[Callable[[int, Any], None]] = None,
 ) -> Tuple[Any, Dict[str, Any]]:
     """Train a flax module. `inputs` maps arg names -> (n, ...) arrays; the
     module is called as model.apply(params, **inputs_batch, deterministic=...).
     Returns (params, history).
+
+    Anything with a flax module's ``apply`` (and a ``repr`` that names its
+    configuration) trains the same way when ``init_params`` hands its
+    variables in: :class:`alink_tpu.dl.lm.CausalLMTrainer` is one, with
+    ``cfg.loss = "rows"`` and ``y`` unused. Collections beside ``"params"``
+    are state the step hands back updated by the model's own rule, donated
+    with the rest. ``on_epoch(epoch, variables)`` is called at the end of
+    every epoch, inside its span and after its one synchronisation, with the
+    variables as they lie on the device: the place for one read an epoch.
 
     ``cfg.accum_steps`` > 1 runs the ordered-chunk gradient schedule (see
     :func:`make_accum_programs`). In a multi-process cluster
@@ -574,11 +589,15 @@ def train_model(
     # device batch shape snaps onto the bucket ladder (rungs are multiples
     # of 8; pad rows carry zero loss-weight) so a batch-size sweep across
     # jobs shares compiled programs — and within a job, the ragged tail
-    # batch reuses the full-batch program instead of tracing a second shape
+    # batch reuses the full-batch program instead of tracing a second shape.
+    # A rung that would at least double the rows is not taken: every step
+    # would pay for the padding what one more compiled program costs once
+    # (2 rows of 8,192 positions on a ladder that starts at 8 are four
+    # steps' work)
     padded_bs = bs
     if bucketing_enabled():
         b = bucket_rows(bs)
-        if b % unit == 0:
+        if b % unit == 0 and b < 2 * bs:
             padded_bs = b
     if n_train >= bs:
         steps_per_epoch = -(-n_train // bs)  # tail rows now train too
@@ -631,6 +650,9 @@ def train_model(
     from ..common.metrics import metrics as _metrics
     from ..common.tracing import set_process_identity
     import time as _time
+
+    _metrics.set_gauge("train.state_bytes", sum(
+        int(a.nbytes) for a in jax.tree.leaves((params, opt_state))))
 
     if num_shards > 1:
         # label this rank's spans so a 2-process drill stitches into one
@@ -870,6 +892,8 @@ def train_model(
                     "dl.train", step=step, loss=lv,
                     samples_per_sec=(step - start_step) * bs / max(elapsed, 1e-9))
 
+            if on_epoch is not None:
+                on_epoch(epoch, params)
             if save_ckpt:
                 ckpt.save(step, jax.device_get(params), jax.device_get(opt_state),
                           {"step": step, "epoch": epoch})

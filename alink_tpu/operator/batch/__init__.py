@@ -86,7 +86,7 @@ from .modelpredict import (
     TorchModelPredictBatchOp,
     export_stablehlo,
 )
-from .lm import CausalLMGenerateBatchOp
+from .lm import CausalLMGenerateBatchOp, CausalLMTrainBatchOp
 from .clustering import (
     GeoKMeansPredictBatchOp,
     GeoKMeansTrainBatchOp,

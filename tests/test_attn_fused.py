@@ -333,3 +333,32 @@ def test_no_copy_of_the_packed_projection_round_the_kernels(one_chip):
         text = jax.jit(f).lower(*args).compile().as_text()
         assert "attn_pallas" in text
         assert not moved.search(text), moved.search(text).group(0)
+
+
+def test_causal_block_core_compiles_for_v5e_at_the_training_cells_size(
+        one_chip, monkeypatch):
+    """``dl/mla.causal_core`` (the decoder's training step, PR 34), forward
+    and backward, at the Moonlight cell's shapes: 2 rows of 8,192 positions,
+    16 heads, queries and keys 192 wide, values 128. It fits the chip, and no
+    tensor of the compiled program has two dimensions of the sequence
+    length: nothing score-shaped is written whole."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl import mla
+
+    # the bfloat16 products as the chip runs them, not the CPU's widened ones
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, t, h, dq, dv = 2, 8192, 16, 192, 128
+    shape = lambda d: jax.ShapeDtypeStruct((b, t, h, d), jnp.float32,
+                                           sharding=one_chip)
+    core = lambda q, k, v: mla.causal_core(q, k, v, scale=dq ** -0.5,
+                                           dtype=jnp.bfloat16)
+    grad = jax.grad(lambda *a: core(*a).sum(), argnums=(0, 1, 2))
+    for f in (core, grad):
+        compiled = jax.jit(f).lower(shape(dq), shape(dq), shape(dv)).compile()
+        assert not re.search(r"\[[\d,]*8192,[\d,]*8192[\d,]*\]",
+                             compiled.as_text())
+        assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30

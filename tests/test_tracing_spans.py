@@ -33,6 +33,8 @@ SERVED_BATCH = {
 MODEL_LOAD = {"mapper.load_model", "dl.predict.place_params"}
 FIT = {"train.tokenize", "train.ingest_checkpoint", "train.place_state",
        "train.epoch", "train.export_model"}
+# what CausalLMTrainBatchOp opens beside those five
+LM_FIT = {"train.pack"}
 # the generator's batch (CausalLMGenerateBatchOp), and its one load
 GENERATE = {"lm.tokenize", "lm.prefill", "lm.decode", "lm.detokenize",
             "lm.load_model"}
@@ -343,14 +345,14 @@ def test_every_span_name_is_documented():
             src = f.read()
         found |= set(re.findall(r'trace_span\(\s*"([^"]+)"', src))
     found -= {"kmeans.fit"}             # trace_span's own docstring
-    assert found == SERVED_BATCH | MODEL_LOAD | FIT | GENERATE | OTHER
+    assert found == SERVED_BATCH | MODEL_LOAD | FIT | LM_FIT | GENERATE | OTHER
     with open(os.path.join(ROOT, "docs", "observability.md")) as f:
         docs = f.read()
     with open(os.path.join(ROOT, "PERF.md")) as f:
         perf = f.read()
     assert [n for n in sorted(found) if f"`{n}`" not in docs] == []
-    on_the_benchmarks_paths = SERVED_BATCH | MODEL_LOAD | FIT | GENERATE | {
-        "dag.run", "serving.warmup", "serving.request"}
+    on_the_benchmarks_paths = SERVED_BATCH | MODEL_LOAD | FIT | LM_FIT \
+        | GENERATE | {"dag.run", "serving.warmup", "serving.request"}
     assert [n for n in sorted(on_the_benchmarks_paths) if f"`{n}`" not in perf] == []
     gone = re.compile(r"trace\.span_s|trace\.spans\b|executor\.node_wall"
                       r"|executor\.schedule\b")
