@@ -487,3 +487,269 @@ def fused_attention(qkv, mask=None, *, num_heads: int,
     return _build_fused()(qkv.transpose(0, 2, 1, 3),
                     mask.astype(jnp.int32)[:, None, :], int(num_heads),
                     bool(interpret))
+
+
+# ---------------------------------------------------------------------------
+# causal core in blocks: whole sequences, q and k of one width, v of another
+# ---------------------------------------------------------------------------
+
+# Positions a block, of queries and of keys alike; a length the kernels take
+# is a multiple of it. One TPU v5e, one layer's core of 2 rows x 16 heads x
+# 8,192 positions, 192 / 128 wide, bfloat16, ms a call (PERF.md section 6,
+# PR 35; docs/chip_calls/pr35/tune.py): forward 22.1 / 11.3 / 7.35 at blocks
+# of 256 / 512 / 1,024 (XLA's loops 23.0), forward + backward 41.7 / 26.3 /
+# 21.9 (64.8). A larger block reads k and v fewer times and pays the
+# accumulator's correction less often; 2,048 would hold 16 MB a float32 tile
+# and compute 25% over the causal half (the diagonal blocks are computed
+# whole: 12.5% at 1,024). Taking the forward block's keys 512 or 256 at a
+# time read slower (+4 and +9 ms).
+_CAUSAL_BLOCK = 1024
+# the backward kernel keeps dq of one row and head, (T, D) float32, in VMEM
+# while the key blocks pass: 16,384 x 256 lanes x 4 bytes is 16.8 MB
+_CAUSAL_MAX_SEQ = 16384
+
+
+def use_causal_attention(seq_len: int, qk_dim: int, v_dim: int) -> bool:
+    """Whether ``dl/mla.causal_core`` takes the fused causal core: decided
+    from the call's own shapes and the kernel's gate, as
+    :func:`use_fused_attention` is. A length that is a multiple of the
+    kernels' block and that the backward kernel's VMEM holds; queries and
+    keys of whole or half lane groups, values of whole ones, two groups at
+    most."""
+    return (seq_len % _CAUSAL_BLOCK == 0
+            and seq_len <= _CAUSAL_MAX_SEQ
+            and qk_dim % (_LANES // 2) == 0 and v_dim % _LANES == 0
+            and max(qk_dim, v_dim) <= 2 * _LANES
+            and use_attn_pallas())
+
+
+_NT = (((1,), (1,)), ((), ()))      # a . b^T
+_NN = (((1,), (0,)), ((), ()))      # a . b
+_TN = (((0,), (0,)), ((), ()))      # a^T . b
+
+
+def _dot(a, b, dims):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _index(shape, axis: int):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
+                       acc_ref, *, scale: float):
+    """Query block ``i`` against key block ``j <= i``, the key blocks in
+    turn: the running maximum, sum and weighted values of the query block
+    stay in VMEM scratch; the diagonal block is the last and the only one
+    masked."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    i, j = pl.program_id(2), pl.program_id(3)
+    block = q_ref.shape[0]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def update(diagonal: bool):
+        s = _dot(q_ref[...], k_ref[...], _NT) * scale          # (Q, K)
+        if diagonal:
+            s = jnp.where(_index(s.shape, 1) <= _index(s.shape, 0), s,
+                          -jnp.inf)
+        m = m_ref[...]                                          # (Q, 1)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        fix = jnp.exp(m - m_new)
+        l_ref[...] = l_ref[...] * fix + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * fix + _dot(
+            p.astype(v_ref.dtype), v_ref[...], _NN)
+        m_ref[...] = m_new
+
+    pl.when(j < i)(lambda: update(False))
+
+    @pl.when(j == i)
+    def _():
+        update(True)
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse = m_ref[...] + jnp.log(l)
+        # the rows' log-sum as a row: lane-dense in memory, and the form
+        # the backward kernel reads, which works on transposed scores
+        lse_ref[...] = _rows_of(jnp.broadcast_to(lse, (block, _LANES)))
+
+
+def _causal_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                       scale: float):
+    """Key block ``j`` against query block ``i >= j``, the query blocks in
+    turn, scores transposed (keys down, queries across) as in
+    :func:`_fused_bwd_kernel`: P is computed once for dv, dk and dq. dk and
+    dv of the key block gather in VMEM scratch over the query blocks; dq of
+    the whole row and head gathers there over the key blocks, a query
+    block's rows complete once its diagonal block has passed."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    j, i = pl.program_id(2), pl.program_id(3)
+    block, dtype = k_ref.shape[0], k_ref.dtype
+    rows = pl.ds(pl.multiple_of(i * block, block), block)
+
+    @pl.when((j == 0) & (i == 0))
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+    def update(diagonal: bool):
+        q, k, do = q_ref[...], k_ref[...], do_ref[...]
+        st = _dot(k, q, _NT) * scale                           # (K, Q)
+        if diagonal:
+            st = jnp.where(_index(st.shape, 0) <= _index(st.shape, 1), st,
+                           -jnp.inf)
+        pt = jnp.exp(st - lse_ref[:1])
+        dv = _dot(pt.astype(dtype), do, _NN)                   # (K, Dv)
+        dpt = _dot(v_ref[...], do, _NT)                        # (K, Q)
+        dst = (pt * (dpt - delta_ref[...]) * scale).astype(dtype)
+        dk = _dot(dst, q, _NN)                                 # (K, D)
+        dq = dq_acc[rows] + _dot(dst, k, _TN)                  # (Q, D)
+        if diagonal:        # the key block's first query block, and the
+            dk_acc[...] = dk        # last key block of these queries
+            dv_acc[...] = dv
+            dq_ref[...] = dq.astype(dq_ref.dtype)
+        else:
+            dk_acc[...] += dk
+            dv_acc[...] += dv
+            dq_acc[rows] = dq
+
+    pl.when(i > j)(lambda: update(False))
+    pl.when(i == j)(lambda: update(True))
+
+    @pl.when(i == pl.num_programs(3) - 1)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _causal_call(name: str, kernel, grid, ins, out, scratch, scale: float,
+                 interpret: bool):
+    """One of the two kernels over (row, head, outer block, inner block),
+    the inner blocks in turn. ``ins`` and ``out`` pair each operand's block
+    (its trailing two dimensions and the index map of the two block
+    indices) with the array or its ``ShapeDtypeStruct``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    spec = lambda dims, at: pl.BlockSpec(
+        (None, None) + dims, lambda b, h, x, y: (b, h) + at(x, y))
+    return pl.pallas_call(
+        functools.partial(kernel, scale=scale),
+        grid=grid,
+        in_specs=[spec(*s) for s, _ in ins],
+        out_specs=[spec(*s) for s, _ in out],
+        out_shape=[shape for _, shape in out],
+        scratch_shapes=[pltpu.VMEM(dims, dt) for dims, dt in scratch],
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_FUSED_VMEM_BYTES),
+        interpret=interpret,
+        name=name,
+    )(*(x for _, x in ins))
+
+
+def _causal_forward(q, k, v, scale: float, interpret: bool):
+    """q, k (B, H, T, D), v (B, H, T, Dv). Returns o (B, H, T, Dv) float32
+    and the rows' log-sum (B, H, 8, T), eight equal rows."""
+    import jax
+    import jax.numpy as jnp
+
+    b, h, t, d = q.shape
+    dv, blk = v.shape[-1], _CAUSAL_BLOCK
+    n = t // blk
+    mine = lambda i, j: (i, 0)
+    # a key block above the diagonal is not computed: it names the last
+    # block needed, so nothing is fetched for it
+    seen = lambda i, j: (jnp.minimum(j, i), 0)
+    f32 = jnp.float32
+    return _causal_call(
+        "mla_causal_fwd", _causal_fwd_kernel, (b, h, n, n),
+        [(((blk, d), mine), q), (((blk, d), seen), k),
+         (((blk, dv), seen), v)],
+        [(((blk, dv), mine), jax.ShapeDtypeStruct((b, h, t, dv), f32)),
+         (((_STAT_ROWS, blk), lambda i, j: (0, i)),
+          jax.ShapeDtypeStruct((b, h, _STAT_ROWS, t), f32))],
+        [((blk, 1), f32), ((blk, 1), f32), ((blk, dv), f32)],
+        scale, interpret)
+
+
+def _causal_backward(q, k, v, do, lse, delta, scale: float, interpret: bool):
+    """``do`` (B, H, T, Dv) in the inputs' dtype, ``lse`` as the forward
+    kernel wrote it, ``delta`` (B, H, 1, T). Returns dq, dk, dv."""
+    import jax
+    import jax.numpy as jnp
+
+    b, h, t, d = q.shape
+    dv, blk = v.shape[-1], _CAUSAL_BLOCK
+    n = t // blk
+    mine = lambda j, i: (j, 0)
+    # a query block above the diagonal names the key block's first
+    live = lambda j, i: (jnp.maximum(i, j), 0)
+    stat = lambda j, i: (0, jnp.maximum(i, j))
+    f32 = jnp.float32
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+    return _causal_call(
+        "mla_causal_bwd", _causal_bwd_kernel, (b, h, n, n),
+        [(((blk, d), live), q), (((blk, d), mine), k), (((blk, dv), mine), v),
+         (((blk, dv), live), do), (((_STAT_ROWS, blk), stat), lse),
+         (((1, blk), stat), delta)],
+        [(((blk, d), mine), like(q)), (((blk, d), mine), like(k)),
+         (((blk, dv), mine), like(v))],
+        [((t, d), f32), ((blk, d), f32), ((blk, dv), f32)],
+        scale, interpret)
+
+
+@functools.cache
+def _build_causal():
+    """The differentiable causal core under a jit of its own, built once, as
+    :func:`_build_fused` is: the layers of a model and their rematerialised
+    copies trace and lower the two kernels once."""
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+    def causal(q, k, v, scale, interpret):
+        return _causal_forward(q, k, v, scale, interpret)[0]
+
+    def fwd(q, k, v, scale, interpret):
+        o, lse = _causal_forward(q, k, v, scale, interpret)
+        return o, (q, k, v, o, lse)
+
+    def bwd(scale, interpret, kept, do):
+        q, k, v, o, lse = kept
+        delta = (o * do).sum(-1)[:, :, None, :]                # (B, H, 1, T)
+        return _causal_backward(q, k, v, do.astype(v.dtype), lse, delta,
+                                scale, interpret)
+
+    causal.defvjp(fwd, bwd)
+    return jax.jit(causal, static_argnums=(3, 4))
+
+
+def causal_attention(q, k, v, *, scale: float, interpret: bool = False):
+    """Causal softmax attention of whole sequences as one kernel each way.
+
+    q, k ``(B, H, T, D)``, v ``(B, H, T, Dv)``, heads first, in one dtype;
+    position ``t`` sees ``s <= t``. Returns ``(B, H, T, Dv)`` float32. The
+    arithmetic of ``dl/mla._causal``, the XLA block loops this replaces on
+    the chip: products in the inputs' dtype with float32 accumulation;
+    scores, maximum, sum and log-sum in float32; P and dS cast to the
+    inputs' dtype for their products; no block above the diagonal computed;
+    the output and the log-sum kept for the backward pass, which computes a
+    block's probabilities again. A block's scores, P, dP and dS live in VMEM
+    only. The caller checks :func:`use_causal_attention` first."""
+    return _build_causal()(q, k, v, float(scale), bool(interpret))

@@ -22,7 +22,11 @@ expanded form: keys ``[k_n, k_r]`` and values a head, and
 :func:`causal_core` over them: blocks of queries against blocks of keys with
 a running maximum and sum, no block above the diagonal, per row and head only
 the output and the log-sum kept, a block's probabilities computed again in the
-backward pass. Nothing with two sequence-length dimensions is written.
+backward pass. Nothing with two sequence-length dimensions is written. In a
+one-chip TPU process, at a length that is a multiple of their block, that is
+the two kernels of ``attn_pallas.causal_attention``, which keep a block's
+scores in VMEM; everywhere else it is the XLA loops of :func:`_causal` here,
+which write a block's scores to memory and are the kernels' reference.
 
 Rotary positions here are the interleaved pairs (``rope_interleave``).
 """
@@ -35,6 +39,8 @@ import jax
 import jax.numpy as jnp
 
 from ..common.metrics import metrics
+from ..native.kernels import interpret_mode
+from .attn_pallas import causal_attention, use_causal_attention
 from .retention import einsum_f32
 
 MLA_SCOPE = "mla_core"
@@ -118,18 +124,33 @@ def causal_core(q, k, v, *, scale: float, dtype):
     """Causal softmax attention of whole sequences, in blocks.
 
     q, k ``(B,T,H,D)``, v ``(B,T,H,Dv)`` float32 or ``dtype``; position
-    ``t`` sees ``s <= t``. Returns ``(B,T,H,Dv)`` float32. ``T`` need not be
-    a multiple of ``CAUSAL_BLOCK``: the padding lies after every real
-    position, so no real query sees it."""
-    B, T, H, _ = q.shape
+    ``t`` sees ``s <= t``. Returns ``(B,T,H,Dv)`` float32.
+
+    One algorithm, two programs, chosen by the call's own shapes and the
+    kernel's gate (``attn_pallas.use_causal_attention``): in a one-chip TPU
+    process, at a length that is a multiple of the kernels' block,
+    ``attn_pallas.causal_attention``, one kernel each way with a block's
+    scores in VMEM only; everywhere else (the CPU, a mesh, any other length)
+    XLA's loops over blocks of ``CAUSAL_BLOCK`` below, for which ``T`` need
+    not be a multiple of the block: the padding lies after every real
+    position, so no real query sees it. Which one a layer was traced down is
+    counted (``attention.causal_fused_traces`` /
+    ``attention.causal_block_traces``)."""
+    B, T, H, D = q.shape
+    fused = use_causal_attention(T, D, v.shape[-1])
     block = min(CAUSAL_BLOCK, T)
-    pad = (-T) % block
+    pad = 0 if fused else (-T) % block
     heads_first = lambda x: jnp.pad(
         x.astype(dtype), ((0, 0), (0, pad), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
-    metrics.incr("attention.causal_block_traces")
+    q, k, v = heads_first(q), heads_first(k), heads_first(v)
     with jax.named_scope(MLA_SCOPE):
-        o = _causal(heads_first(q), heads_first(k), heads_first(v),
-                    float(scale), block)
+        if fused:
+            metrics.incr("attention.causal_fused_traces")
+            o = causal_attention(q, k, v, scale=scale,
+                                 interpret=interpret_mode())
+        else:
+            metrics.incr("attention.causal_block_traces")
+            o = _causal(q, k, v, float(scale), block)
     return o.transpose(0, 2, 1, 3)[:, :T]
 
 

@@ -118,21 +118,30 @@ _REGISTRY: Dict[str, Dict[str, Any]] = {
         "single_device_only": True,
         "module": "alink_tpu/dl/attn_pallas.py",
         # the whole core of the default attention, forward and backward
-        # kernels under one custom_vjp; and the block update that
-        # blockwise/ring attention call per K/V block
-        "entry": "fused_attention, flash_block_update",
+        # kernels under one custom_vjp; the causal core of whole sequences in
+        # blocks (latent attention's expanded form, q/k and v of unequal
+        # widths), likewise; and the block update that blockwise/ring
+        # attention call per K/V block
+        "entry": "fused_attention, causal_attention, flash_block_update",
         "programs": ("dl.train_step", "dl.micro_step",
                      "dl.fused_accum_step", "dl.mlm_step", "dl.mlm_micro",
                      "dl.attention", "dl.apply_logits"),
         "fallback": "full_attention (dl/attention.py), also below the "
                     "length threshold, for a length not lane-aligned, a "
                     "head dimension other than 64 or 128, a causal call; "
-                    "lax.scan online-softmax "
+                    "the XLA block loops of dl/mla._causal for the causal "
+                    "core, also for a length that is not a multiple of the "
+                    "kernels' block or over what the backward kernel's "
+                    "VMEM holds, q/k not of whole or half lane groups, v "
+                    "not of whole ones; lax.scan online-softmax "
                     "(dl/attention._online_softmax_update) for the block "
                     "update",
         "contract": "fused core: outputs and dq, dk, dv within 2e-5 "
                     "(fp32) and 4e-2 (bf16, values of order 1) of "
-                    "full_attention, a fully masked row included "
+                    "full_attention, a fully masked row included; causal "
+                    "core: output within 2e-5 and dq, dk, dv within 5e-5 "
+                    "(fp32) of materialised scores, all within 4e-2 (bf16, "
+                    "values of order 1) of dl/mla._causal "
                     "(tests/test_attn_fused.py); blockwise/ring outputs "
                     "within atol=1e-5 of the XLA path (fp32), knob-off "
                     "byte-identical (tests/test_kernels.py)",

@@ -2,7 +2,10 @@
 ``full_attention``, which stays its reference: values and gradients under the
 Pallas interpreter, the rule that selects it, an encoder with the path on and
 off, what the traced program holds, and the kernels compiled at BERT-base's
-width for a described v5e (no chip is needed or taken for that)."""
+width for a described v5e (no chip is needed or taken for that). Then the
+fused causal core (``causal_attention``, the decoder's training step) the same
+way: against materialised scores and against ``dl/mla``'s block loops, its
+rule, its counters, and its kernels compiled at the Moonlight cell's size."""
 
 import numpy as np
 import pytest
@@ -248,6 +251,184 @@ def test_blockwise_and_ring_settings_keep_their_paths(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the causal core in blocks (dl/mla.causal_core on the chip)
+# ---------------------------------------------------------------------------
+
+
+def _causal_inputs(cells, seq, d, dv, dtype, seed):
+    """Heads-first q, k, v and a cotangent, every (row, head) cell its own
+    draw."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    draw = lambda w, dt: jnp.asarray(rng.normal(size=(*cells, seq, w)), dt)
+    return draw(d, dtype), draw(d, dtype), draw(dv, dtype), \
+        draw(dv, jnp.float32)
+
+
+def _materialised_causal(q, k, v, scale):
+    import jax
+    import jax.numpy as jnp
+
+    s = jnp.einsum("bhtd,bhsd->bhts", q, k) * scale
+    t = jnp.arange(q.shape[2])
+    p = jax.nn.softmax(jnp.where(t[None, :] <= t[:, None], s, -jnp.inf), -1)
+    return jnp.einsum("bhts,bhsd->bhtd", p, v)
+
+
+@pytest.fixture
+def toy_causal_blocks(monkeypatch):
+    """The kernels' block at a toy size; the jitted core is built anew for
+    it and again for what follows."""
+    from alink_tpu.dl import attn_pallas
+
+    def sized(block):
+        monkeypatch.setattr(attn_pallas, "_CAUSAL_BLOCK", block)
+        attn_pallas._build_causal.cache_clear()
+
+    yield sized
+    attn_pallas._build_causal.cache_clear()
+
+
+_CAUSAL_CASES = [
+    # cells (rows, heads), length, q/k width, v width, block
+    ("several_blocks", (2, 3), 48, 48, 32, 16),
+    ("one_block", (2, 3), 16, 48, 32, 16),
+    ("one_cell_many_blocks", (1, 1), 128, 48, 32, 16),
+    ("the_cells_widths", (1, 2), 64, 192, 128, 32),
+]
+
+
+@pytest.mark.parametrize("case,cells,seq,d,dv,block", _CAUSAL_CASES)
+def test_causal_kernels_match_materialised_scores(toy_causal_blocks, case,
+                                                  cells, seq, d, dv, block):
+    """Forward and the three gradients in float32, under the interpreter,
+    at unequal widths for q/k and v: the diagonal inside a block, blocks
+    wholly under it, none above it."""
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl.attn_pallas import causal_attention
+
+    toy_causal_blocks(block)
+    q, k, v, g = _causal_inputs(cells, seq, d, dv, jnp.float32, seed=seq + d)
+    with jax.default_matmul_precision("highest"):
+        out, pull = jax.vjp(lambda *a: causal_attention(
+            *a, scale=0.2, interpret=True), q, k, v)
+        want, pull_want = jax.vjp(
+            lambda *a: _materialised_causal(*a, 0.2), q, k, v)
+        assert out.shape == (*cells, seq, dv) and out.dtype == jnp.float32
+        np.testing.assert_allclose(out, want, atol=2e-5)
+        for name, got, ref in zip(("dq", "dk", "dv"), pull(g), pull_want(g)):
+            np.testing.assert_allclose(got, ref, atol=5e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("case,cells,seq,d,dv,block", _CAUSAL_CASES)
+def test_causal_kernels_match_the_block_loops_in_bfloat16(
+        toy_causal_blocks, case, cells, seq, d, dv, block):
+    """The same precisions as ``dl/mla._causal`` (bfloat16 products with
+    float32 sums, float32 softmax, P and dS cast to bfloat16), so the two
+    differ by summation order: the ``dl.attn_pallas`` contract's 4e-2 at
+    values of order 1."""
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl import mla
+    from alink_tpu.dl.attn_pallas import causal_attention
+
+    toy_causal_blocks(block)
+    q, k, v, g = _causal_inputs(cells, seq, d, dv, jnp.bfloat16, seed=seq)
+    scale = d ** -0.5
+    out, pull = jax.vjp(lambda *a: causal_attention(
+        *a, scale=scale, interpret=True), q, k, v)
+    want, pull_want = jax.vjp(lambda *a: mla._causal(*a, scale, block),
+                              q, k, v)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+    np.testing.assert_allclose(f32(out), f32(want), atol=_TOL["bfloat16"])
+    for name, got, ref in zip(("dq", "dk", "dv"), pull(g), pull_want(g)):
+        assert got.dtype == jnp.bfloat16
+        np.testing.assert_allclose(f32(got), f32(ref), atol=_TOL["bfloat16"],
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("case,seq,d,dv,expect", [
+    ("the_training_cell", 8192, 192, 128, True),
+    ("one_block", 1024, 192, 128, True),
+    ("equal_widths", 2048, 128, 128, True),
+    ("not_a_multiple_of_the_block", 8192 + 512, 192, 128, False),
+    ("shorter_than_a_block", 40, 24, 16, False),
+    ("dq_of_a_row_and_head_over_vmem", 32768, 192, 128, False),
+    ("values_not_lane_aligned", 8192, 192, 64, False),
+    ("queries_a_third_of_a_lane_group", 8192, 160, 128, False),
+    ("knob_off", 8192, 192, 128, False),
+    ("two_devices", 8192, 192, 128, False),
+    ("cpu_backend", 8192, 192, 128, False),
+])
+def test_causal_attention_selection(monkeypatch, case, seq, d, dv, expect):
+    """Decided from the call's shapes and the registry's gate, as the
+    encoder's rule is: a mesh and the CPU keep XLA's block loops."""
+    import jax
+
+    from alink_tpu.dl.attn_pallas import use_causal_attention
+
+    monkeypatch.delenv("ALINK_ATTN_PALLAS", raising=False)
+    monkeypatch.setattr(jax, "default_backend",
+                        lambda: "cpu" if case == "cpu_backend" else "tpu")
+    monkeypatch.setattr(jax, "device_count",
+                        lambda: 2 if case == "two_devices" else 1)
+    if case == "knob_off":
+        monkeypatch.setenv("ALINK_ATTN_PALLAS", "0")
+    assert use_causal_attention(seq, d, dv) is expect
+
+
+@pytest.mark.parametrize("gate,seq,expect", [
+    ("1", 48, {"causal_fused_traces": 2, "causal_block_traces": 0}),
+    ("1", 40, {"causal_fused_traces": 0, "causal_block_traces": 2}),
+    ("0", 48, {"causal_fused_traces": 0, "causal_block_traces": 2}),
+])
+def test_causal_core_takes_and_counts_its_program(monkeypatch,
+                                                  toy_causal_blocks, gate,
+                                                  seq, expect):
+    """``dl/mla.causal_core`` down the kernels and down the loops: the same
+    values and gradients, and each trace (forward, gradient) counted down the
+    path it took, a length that is not a multiple of the kernels' block down
+    the loops whatever the gate says."""
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.common.metrics import metrics
+    from alink_tpu.dl import mla
+
+    toy_causal_blocks(16)
+    monkeypatch.setattr(mla, "CAUSAL_BLOCK", 16)
+    rng = np.random.default_rng(seq)
+    q, k = (jnp.asarray(rng.normal(size=(2, seq, 2, 64)), jnp.float32)
+            for _ in range(2))
+    v, g = (jnp.asarray(rng.normal(size=(2, seq, 2, 128)), jnp.float32)
+            for _ in range(2))
+    core = lambda *a: mla.causal_core(*a, scale=0.125, dtype=jnp.float32)
+
+    def run():
+        before = {n: metrics.counter("attention." + n) for n in expect}
+        with jax.default_matmul_precision("highest"):
+            out = core(q, k, v)
+            grads = jax.grad(lambda *a: (core(*a) * g).sum(),
+                             argnums=(0, 1, 2))(q, k, v)
+        return out, grads, {n: metrics.counter("attention." + n) - c
+                            for n, c in before.items()}
+
+    monkeypatch.setenv("ALINK_ATTN_PALLAS", "0")
+    want, want_grads, _ = run()
+    monkeypatch.setenv("ALINK_ATTN_PALLAS", gate)
+    out, grads, grew = run()
+    assert grew == expect
+    assert out.shape == (2, seq, 2, 128) and out.dtype == jnp.float32
+    np.testing.assert_allclose(out, want, atol=2e-5)
+    for got, ref in zip(grads, want_grads):
+        np.testing.assert_allclose(got, ref, atol=5e-5)
+
+
+# ---------------------------------------------------------------------------
 # compiled for the chip, without the chip
 # ---------------------------------------------------------------------------
 
@@ -362,3 +543,57 @@ def test_causal_block_core_compiles_for_v5e_at_the_training_cells_size(
         assert not re.search(r"\[[\d,]*8192,[\d,]*8192[\d,]*\]",
                              compiled.as_text())
         assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_causal_kernels_compile_for_v5e_at_the_training_cells_size(
+        one_chip, monkeypatch):
+    """``causal_attention``, forward and gradient, at the Moonlight cell's
+    shapes (heads first: 2 rows, 16 heads, 8,192 positions, queries and keys
+    192 wide, values 128) through Mosaic proper: the compiled programs hold
+    the kernels by the names the trace reader knows, nothing with two
+    dimensions of the length, no block of scores, and less scratch than the
+    program of XLA's block loops."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl import mla
+    from alink_tpu.dl.attn_pallas import causal_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # einsum_f32
+    b, t, h, dq, dv = 2, 8192, 16, 192, 128
+    # as the layer hands them over, (B, T, H*D) float32: XLA lays a 192-wide
+    # entry parameter out its own way and would copy it for the kernel, the
+    # layer's transposes write what the kernel reads
+    shape = lambda d: jax.ShapeDtypeStruct((b, t, h * d), jnp.float32,
+                                           sharding=one_chip)
+    args = (shape(dq), shape(dq), shape(dv))
+    scale = dq ** -0.5
+    heads_first = lambda x: x.reshape(b, t, h, -1).astype(
+        jnp.bfloat16).transpose(0, 2, 1, 3)
+
+    def both(core):
+        fwd = lambda *a: core(*map(heads_first, a)).transpose(
+            0, 2, 1, 3).reshape(b, t, -1)
+        return fwd, jax.grad(lambda *a: fwd(*a).sum(), argnums=(0, 1, 2))
+
+    kernels = both(lambda *a: causal_attention(*a, scale=scale))
+    loops = both(lambda *a: mla._causal(*a, scale, mla.CAUSAL_BLOCK))
+    # a kernel writes dq, dk and dv whole in bfloat16 where XLA fuses the
+    # loops' casts into the transposes back: that much more may stand
+    room = (0, b * t * h * (2 * dq + dv) * 2)
+    for f, loop, names, more in zip(kernels, loops, (
+            ["mla_causal_fwd"], ["mla_causal_fwd", "mla_causal_bwd"]), room):
+        compiled = jax.jit(f).lower(*args).compile()
+        text = compiled.as_text()
+        assert sorted(set(re.findall(r"mla_causal_\w+?(?=[./\"])", text))) \
+            == sorted(names)
+        assert text.count("custom_call_target=\"tpu_custom_call\"") \
+            == len(names)
+        assert not re.search(r"\[[\d,]*8192,[\d,]*8192[\d,]*\]", text)
+        assert "[2,16,1024,1024]" not in text
+        looped = jax.jit(loop).lower(*args).compile()
+        assert "[2,16,1024,1024]" in looped.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < looped.memory_analysis().temp_size_in_bytes + more
