@@ -23,10 +23,10 @@ another. This module replaces that walk with a real scheduler:
    host→device round trip for the whole run). Outputs are bit-identical to
    node-by-node execution — the chain applies the same transforms in the
    same order.
-3. **Per-node trace** — every unit records wall time plus whatever phases
-   the lower layers report (``transfer_s``/``compute_s`` from
-   ``common/streaming.py``) into ``common/metrics.py``
-   (``executor_trace()`` / ``executor_phase_summary()``).
+3. **Per-node trace** — every unit's span holds its wall time plus whatever
+   phases the lower layers report (``transfer_s``/``compute_s`` from
+   ``common/streaming.py``); ``common/metrics.py`` reads them from the span
+   ring (``executor_trace()`` / ``executor_phase_summary()``).
 4. **Fault tolerance** — failed units are retried under the central
    :class:`~alink_tpu.common.resilience.RetryPolicy` when the error is
    transient (``is_retryable``); this is safe because ``_executed`` is only
@@ -290,20 +290,26 @@ def _run_unit_resilient(unit: _Unit) -> Dict[str, Any]:
     return state
 
 
-def _run_unit(unit: _Unit, record: bool, ctx=None):
+def _run_unit(unit: _Unit, ctx=None):
     phases: Dict[str, Any] = {}
     state = {"defused": False, "attempts": 0}
     t0 = time.perf_counter()
     with attach_context(ctx):
         # one span per scheduled unit: a fused chain is ONE span with a
         # `fused` mark (it ran as one program), parented to the dag.run
-        # root even though this executes on an alink-dag pool thread
+        # root even though this executes on an alink-dag pool thread. The
+        # span is the unit's one record: executor_trace() reads it from
+        # the ring
         with trace_span(unit.label(),
                         fused=len(unit.ops) if unit.fused else None) as sp:
             try:
                 with node_phase_context(phases):
                     state = _run_unit_resilient(unit)
             finally:
+                # HBM watermark at the node boundary (performance
+                # observatory): a cheap latched no-op on backends without
+                # memory_stats (CPU)
+                hbm_bytes = sample_device_memory()
                 if sp is not None:
                     sp.phases.update({k: v for k, v in phases.items()
                                       if isinstance(v, (int, float))})
@@ -311,24 +317,9 @@ def _run_unit(unit: _Unit, record: bool, ctx=None):
                         sp.outcome = sp.outcome or "defused"
                     if state["attempts"] > 1:
                         sp.attrs["attempts"] = state["attempts"]
-    # HBM watermark at the node boundary (performance observatory): a
-    # cheap latched no-op on backends without memory_stats (CPU)
-    hbm_bytes = sample_device_memory()
-    if record:
-        wall = time.perf_counter() - t0
-        rec = {"op": unit.label(), "wall_s": round(wall, 6)}
-        if hbm_bytes is not None:
-            rec["hbm_bytes"] = hbm_bytes
-        if unit.fused:
-            rec["fused"] = len(unit.ops)
-        if state["attempts"] > 1:
-            rec["attempts"] = state["attempts"]
-        if state["defused"]:
-            rec["defused"] = True
-        for k, v in phases.items():
-            rec[k] = round(v, 6) if isinstance(v, float) else v
-        metrics.record_bounded("executor.node", _TRACE_LIMIT, **rec)
-        metrics.observe("executor.node_s", wall)
+                    if hbm_bytes is not None:
+                        sp.attrs["hbm_bytes"] = hbm_bytes
+    metrics.observe("executor.node_s", time.perf_counter() - t0)
 
 
 def run_dag(env, roots: Sequence[Any], record: bool = True) -> None:
@@ -392,7 +383,7 @@ def _run_scheduled(env, roots: Sequence[Any], units: List[_Unit],
             try:
                 while ready:
                     u = ready[-1]
-                    futures[pool.submit(_run_unit, u, record, ctx)] = u
+                    futures[pool.submit(_run_unit, u, ctx)] = u
                     ready.pop()
             except BaseException as exc:
                 # pool broke (shutdown/exhaustion), not the unit itself:

@@ -70,6 +70,16 @@ class _Histogram:
         self.min = v if self.min is None else min(self.min, v)
         self.max = v if self.max is None else max(self.max, v)
 
+    def observe_mean(self, value: float, n: int) -> None:
+        """``n`` observations known only by their mean: sum and count stay
+        exact, the bucket counts say where the mean lay."""
+        v = float(value)
+        self.counts[bisect.bisect_left(self.buckets, v)] += n
+        self.count += n
+        self.sum += v * n
+        self.min = v if self.min is None else min(self.min, v)
+        self.max = v if self.max is None else max(self.max, v)
+
     def quantile(self, q: float) -> Optional[float]:
         if not self.count:
             return None
@@ -197,6 +207,7 @@ class StepMetrics:
         self._labeled_hists: Dict[str, Dict[tuple, _Histogram]] = {}
         self._gauges: Dict[str, Dict[tuple, float]] = {}
         self._export_hooks: List[Any] = []
+        self._read_hooks: List[Any] = []
         self._counters: Dict[str, int] = defaultdict(int)
         self._counter_lock = threading.Lock()
         # one lock for series+timers+histograms: executor pool threads,
@@ -243,6 +254,17 @@ class StepMetrics:
                         buckets or DEFAULT_BUCKETS)
                 h.observe(value)
 
+    def observe_mean(self, name: str, value: float, n: int):
+        """``n`` observations of histogram ``name`` that were summed where
+        they were made, no lock being allowed there (the collector's
+        callbacks): they enter as ``n`` entries of their mean."""
+        if self.enabled and n > 0:
+            with self._data_lock:
+                h = self._hists.get(name)
+                if h is None:
+                    h = self._hists[name] = _Histogram(DEFAULT_BUCKETS)
+                h.observe_mean(value, n)
+
     def set_gauge(self, name: str, value: float, **labels):
         """Last-write-wins gauge, optionally labeled (one series per label
         set). Gauges are for readout surfaces that recompute a current
@@ -265,6 +287,24 @@ class StepMetrics:
         if fn not in self._export_hooks:
             self._export_hooks.append(fn)
 
+    def register_read_hook(self, fn):
+        """Register a callable invoked before a counter or a histogram is
+        read (``counter``, ``counters``, ``histogram``,
+        ``histogram_states``): for a source that may take no lock
+        where it counts (``tracing``'s collector callbacks) and hands its
+        totals over when somebody looks. Called with no lock held; a hook
+        may ``observe`` and ``incr``. Failures are counted
+        (``metrics.dropped``), never raised."""
+        if fn not in self._read_hooks:
+            self._read_hooks.append(fn)
+
+    def _before_read(self):
+        for hook in list(self._read_hooks):
+            try:
+                hook()
+            except Exception as e:
+                _count_drop("read_hook", e)
+
     def incr(self, name: str, n: int = 1):
         """Monotonic event counter (retries, dead-letter drops, defusions).
         Counters count even while recording is disabled — they are the
@@ -274,10 +314,12 @@ class StepMetrics:
             self._counters[name] += n
 
     def counter(self, name: str) -> int:
+        self._before_read()
         with self._counter_lock:
             return self._counters.get(name, 0)
 
     def counters(self, prefix: str = "") -> Dict[str, int]:
+        self._before_read()
         with self._counter_lock:
             return {k: v for k, v in self._counters.items()
                     if k.startswith(prefix)}
@@ -302,6 +344,7 @@ class StepMetrics:
     def histogram(self, name: str) -> Optional[Dict[str, Any]]:
         """count/sum/min/max/mean plus p50/p90/p99 estimates for one
         histogram, or None if it was never observed."""
+        self._before_read()
         with self._data_lock:
             h = self._hists.get(name)
             h = h.snapshot() if h is not None else None
@@ -314,6 +357,7 @@ class StepMetrics:
     def histogram_states(self) -> Dict[str, Dict[str, Any]]:
         """Raw serializable state of every (unlabeled) histogram — the
         worker-side source the telemetry relay diffs and ships."""
+        self._before_read()
         with self._data_lock:
             return {n: h.state() for n, h in self._hists.items()}
 
@@ -519,10 +563,32 @@ def add_node_phase(key: str, seconds: float):
 
 
 def executor_trace() -> List[Dict[str, Any]]:
-    """Per-node records of the last executed DAGs: one dict per node with
-    ``op``/``wall_s`` plus any phases (``transfer_s``, ``compute_s``,
-    ``fused``) the node reported."""
-    return metrics.series("executor.node")
+    """Per-node records of the last executed DAGs, oldest first: one dict
+    per scheduled unit with ``op``/``wall_s`` plus any phases
+    (``transfer_s``, ``compute_s``, ``compile_s``) the node reported, and
+    ``fused``, ``attempts``, ``defused``, ``hbm_bytes`` where they apply.
+    Read from the span ring: a unit's span, the child of a scheduled
+    ``dag.run``, is its one record (so it holds what the ring still holds,
+    and nothing while ``ALINK_TRACING`` is off); ``span_id`` tells two
+    records apart."""
+    from .tracing import tracer
+
+    spans = tracer.spans()
+    runs = {s["span_id"] for s in spans if s["name"] == "dag.run"
+            and (s.get("attrs") or {}).get("mode") != "serial"}
+    out = []
+    for s in spans:
+        if s["parent_id"] not in runs:
+            continue
+        attrs = s.get("attrs") or {}
+        rec = {"op": s["name"], "wall_s": s["wall_s"],
+               "span_id": s["span_id"], **(s.get("phases") or {}),
+               **{k: attrs[k] for k in ("fused", "attempts", "hbm_bytes")
+                  if k in attrs}}
+        if s["outcome"] == "defused":
+            rec["defused"] = True
+        out.append(rec)
+    return out
 
 
 def executor_phase_summary() -> Dict[str, Any]:

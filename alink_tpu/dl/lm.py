@@ -985,7 +985,8 @@ class CausalLM:
         T = self.prefill_chunk
         n, total = len(prompts), int(lens[:len(prompts)].sum())
         chunks = -(-int(lens.max()) // T)
-        with trace_span("lm.prefill", rows=n, tokens=total, chunks=chunks):
+        with trace_span("lm.prefill", rows=n, tokens=total,
+                        chunks=chunks) as sp:
             tokens = np.zeros((rows, chunks * T), np.int32)
             for r in range(rows):       # rows beyond n repeat the last prompt
                 p = prompts[min(r, n - 1)]
@@ -995,6 +996,9 @@ class CausalLM:
             pos = np.where((pos < lens[:, None]) & live, pos, -1).astype(np.int32)
             prog = self._program("lm.prefill_chunk", _build_prefill_chunk, rows)
             hidden = jnp.zeros((rows, self.cfg.hidden_size), jnp.float32)
+            # a chunk's hand-over: the host's pace until the runtime's queue
+            # is full, the device's after; the last one waits for the token
+            slowest, t_last = (0, 0.0), time.perf_counter()
             for c in range(chunks):
                 sl = slice(c * T, (c + 1) * T)
                 last = lens - 1 - c * T
@@ -1003,9 +1007,19 @@ class CausalLM:
                                      tokens[:, sl], pos[:, sl], c == 0, last,
                                      hidden)
                 self.cache.put(state)
+                now = time.perf_counter()
+                if c < chunks - 1:
+                    if now - t_last > slowest[1]:
+                        slowest = (c, now - t_last)
+                    t_last = now
             tok, logprob = self._program("lm.sample", _build_sample, rows)(
                 self.params, hidden)
             tok.block_until_ready()
+            if time.perf_counter() - t_last > slowest[1]:
+                slowest = (chunks - 1, time.perf_counter() - t_last)
+            if sp is not None:
+                sp.attrs["slowest_step"] = slowest[0]
+                sp.attrs["slowest_step_s"] = round(slowest[1], 6)
         metrics.incr("lm.prefill_tokens", total)
         return tok, logprob
 
@@ -1016,9 +1030,9 @@ class CausalLM:
         between two steps' ends is a step's and the device never waits."""
         toks, logprobs = [tok], [logprob]
         live = np.arange(rows) < n
-        with trace_span("lm.decode", rows=n, steps=max_new - 1):
+        with trace_span("lm.decode", rows=n, steps=max_new - 1) as sp:
             prog = self._program("lm.decode_step", _build_decode_step, rows)
-            t_last = time.perf_counter()
+            slowest, t_last = (0, 0.0), time.perf_counter()
             for i in range(1, max_new):
                 with step_annotation("lm.decode_step", i):
                     state, tok, logprob = prog(
@@ -1037,7 +1051,12 @@ class CausalLM:
                     metrics.observe("lm.step_latent_positions",
                                     float(lens[:n].mean()) + i,
                                     buckets=_POSITION_BUCKETS)
+                if now - t_last > slowest[1]:
+                    slowest = (i, now - t_last)
                 t_last = now
+            if sp is not None:
+                sp.attrs["slowest_step"] = slowest[0]
+                sp.attrs["slowest_step_s"] = round(slowest[1], 6)
             ids = np.stack([np.asarray(t) for t in toks], axis=1)
             lps = np.stack([np.asarray(l) for l in logprobs], axis=1)
         metrics.incr("lm.decode_tokens", n * max_new)

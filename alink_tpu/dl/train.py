@@ -37,6 +37,10 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import queue
+import threading
+import time
+from collections import deque
 from dataclasses import dataclass
 from functools import partial
 from typing import (Any, Callable, Dict, Iterable, Iterator, List, NamedTuple,
@@ -44,7 +48,8 @@ from typing import (Any, Callable, Dict, Iterable, Iterator, List, NamedTuple,
 
 import numpy as np
 
-from ..common.tracing import step_annotation, trace_span
+from ..common.tracing import (slow_against, step_annotation, trace_span,
+                              tracing_enabled)
 from .sharding import batch_sharding, param_shardings
 
 
@@ -490,6 +495,94 @@ def _pad_tail(arrs: List[np.ndarray], target: int) -> List[np.ndarray]:
             for a in arrs]
 
 
+# 1 ms to 10 s in steps of 10%: a median read from these edges lies within
+# 5% of the step, where the default ladder has one bucket from 50 to 100 ms
+_DEVICE_STEP_BUCKETS = tuple(1e-3 * 1.1 ** i for i in range(98))
+
+
+class _StepWatch:
+    """Each optimizer step's finish on the device's side. The loop hands
+    over every step's scalar loss with the host clock at hand-over; one
+    thread waits on them in order (``block_until_ready`` releases the
+    interpreter) and observes ``train.device_step_s`` = finish(i) less the
+    later of finish(i-1) and hand-over(i): the device's pace while the host
+    runs ahead, the host's where the device waits for it. The first step of
+    a call has no finish before it and is left out. A step over the median
+    of the last 32 by more than 5% and 20 ms counts ``slow.train.step``."""
+
+    def __init__(self):
+        self._handed: Any = queue.Queue()
+        self._recent: Any = deque(maxlen=32)
+        self._last_finish: Optional[float] = None
+        # the epoch's count and slowest step, which ``drain`` takes away
+        self._lock = threading.Lock()
+        self._steps = 0
+        self._slowest: Tuple[Optional[int], float] = (None, 0.0)
+        self._thread = threading.Thread(target=self._run, daemon=True,
+                                        name="alink-train-watch")
+        self._thread.start()
+
+    def hand(self, step: int, loss) -> None:
+        self._handed.put((step, loss, time.perf_counter()))
+
+    def _run(self) -> None:
+        import jax
+
+        from ..common.metrics import metrics
+
+        while True:
+            item = self._handed.get()
+            try:
+                if item is None:
+                    return
+                self._watch(jax, metrics, *item)
+            except Exception:
+                # an instrument never ends the fit: a step it cannot read
+                # (the loop meets a failed step itself) is counted, and the
+                # thread lives on, so ``drain`` is always answered
+                metrics.incr("train.watch_errors")
+            finally:
+                self._handed.task_done()
+
+    def _watch(self, jax, metrics, step: int, loss, t_hand: float) -> None:
+        jax.block_until_ready(loss)
+        now = time.perf_counter()
+        took = None
+        if self._last_finish is not None:
+            took = now - max(self._last_finish, t_hand)
+            metrics.observe("train.device_step_s", took,
+                            buckets=_DEVICE_STEP_BUCKETS)
+            if slow_against(self._recent, took) is not None:
+                metrics.incr("slow.train.step")
+            self._recent.append(took)
+        self._last_finish = now
+        with self._lock:
+            self._steps += 1
+            if took is not None and took > self._slowest[1]:
+                self._slowest = (step, took)
+
+    def drain(self, span, wait: bool = True) -> None:
+        """Put the epoch's count of steps and its slowest step on its span.
+        ``wait``: for every step handed over so far, which costs nothing
+        where the loop has just read the last step's loss itself; where it
+        has not (``log_every``), the watcher adds no synchronisation of its
+        own, and a step still on the device counts to the next epoch."""
+        if wait:
+            self._handed.join()
+        with self._lock:
+            steps, slowest = self._steps, self._slowest
+            self._steps, self._slowest = 0, (None, 0.0)
+        if span is not None:
+            span.attrs["steps"] = steps
+            if slowest[0] is not None:
+                span.attrs["slowest_step"] = slowest[0]
+                span.attrs["slowest_step_s"] = round(slowest[1], 6)
+
+    def close(self) -> None:
+        self._handed.put(None)
+        self._thread.join()
+
+
 def train_model(
     model,
     inputs: Dict[str, np.ndarray],
@@ -744,6 +837,8 @@ def train_model(
 
     def _after_step(s, l, epoch):
         nonlocal step
+        if watch is not None:
+            watch.hand(step, l)
         step += 1
         _metrics.incr("train.steps")
         _metrics.incr("train.rows", int(min(bs, n_train - s * bs))
@@ -761,159 +856,167 @@ def train_model(
             _metrics.record("dl.train", step=step, loss=lv,
                             samples_per_sec=step * bs / max(elapsed, 1e-9))
 
-    for epoch in range(start_epoch, cfg.num_epochs):
-        # one rank-tagged span per epoch: in a multi-process drill each
-        # rank exports its own train.epoch lane into the stitched trace
-        with trace_span("train.epoch", epoch=epoch, rank=shard_idx,
-                         shards=num_shards):
-            # per-(seed, epoch) generator, NOT the sequentially-consumed rng: a
-            # crash-resumed run must replay the exact shuffle of the epochs it
-            # skipped past (dropout keys already align via fold_in(key, step))
-            order = np.random.default_rng((cfg.seed, epoch)).permutation(n_train)
-            if n_train < bs:  # tile tiny datasets up to one full batch
-                order = np.resize(order, bs)
+    # the device's side of every step: one thread for this call, ended with it
+    watch = _StepWatch() if tracing_enabled() else None
+    try:
+        for epoch in range(start_epoch, cfg.num_epochs):
+            # one rank-tagged span per epoch: in a multi-process drill each
+            # rank exports its own train.epoch lane into the stitched trace
+            with trace_span("train.epoch", unit="train", epoch=epoch,
+                            rank=shard_idx, shards=num_shards) as epoch_span:
+                # per-(seed, epoch) generator, NOT the sequentially-consumed rng: a
+                # crash-resumed run must replay the exact shuffle of the epochs it
+                # skipped past (dropout keys already align via fold_in(key, step))
+                order = np.random.default_rng((cfg.seed, epoch)).permutation(n_train)
+                if n_train < bs:  # tile tiny datasets up to one full batch
+                    order = np.resize(order, bs)
 
-            if not scale:
-                def build(s, _order=order):
-                    idx = _order[s * bs:(s + 1) * bs]
-                    arrs = [tr_inputs[k][idx] for k in names] + [tr_y[idx]]
-                    w = np.ones(len(idx), np.float32)
-                    if len(idx) < padded_bs:
-                        arrs = _pad_tail(arrs, padded_bs)
-                        w = np.concatenate(
-                            [w, np.zeros(padded_bs - len(idx), np.float32)])
-                    return arrs + [w]
+                if not scale:
+                    def build(s, _order=order):
+                        idx = _order[s * bs:(s + 1) * bs]
+                        arrs = [tr_inputs[k][idx] for k in names] + [tr_y[idx]]
+                        w = np.ones(len(idx), np.float32)
+                        if len(idx) < padded_bs:
+                            arrs = _pad_tail(arrs, padded_bs)
+                            w = np.concatenate(
+                                [w, np.zeros(padded_bs - len(idx), np.float32)])
+                        return arrs + [w]
 
-                t_step = _time.perf_counter()
-                for s, devs in _timed_feed(_feed(
-                        build, place, steps_per_epoch, mode=cfg.feed,
-                        depth=cfg.feed_depth, phases=feed_phases)):
-                    batch = dict(zip(names, devs[:-2]))
-                    yb, wb = devs[-2], devs[-1]
-                    with step_annotation("train.step", step):
-                        params, opt_state, l = train_step(
-                            params, opt_state, batch, yb, wb,
-                            jax.random.fold_in(key, step)
-                        )
-                    _metrics.observe("train.step_s",
-                                     _time.perf_counter() - t_step)
                     t_step = _time.perf_counter()
-                    _after_step(s, l, epoch)
-            elif cfg.accum_mode == "fused":
-                def build_full(s, _order=order):
-                    idx = _order[s * bs:(s + 1) * bs]
-                    arrs = [tr_inputs[k][idx] for k in names] + [tr_y[idx]]
-                    w = np.ones(len(idx), np.float32)
-                    if len(idx) < padded_bs:
-                        arrs = _pad_tail(arrs, padded_bs)
-                        w = np.concatenate(
-                            [w, np.zeros(padded_bs - len(idx), np.float32)])
-                    # pre-chunk host-side: (accum, micro, ...) — the scan's
-                    # chunk layout is decided HERE, not by an in-program
-                    # reshard (see chunked_batch_sharding)
-                    return [a.reshape((accum, micro_rows) + a.shape[1:])
-                            for a in arrs + [w]]
-
-                t_step = _time.perf_counter()
-                for s, devs in _timed_feed(_feed(
-                        build_full, place_chunked, steps_per_epoch,
-                        mode=cfg.feed, depth=cfg.feed_depth,
-                        phases=feed_phases)):
-                    batch = dict(zip(names, devs[:-2]))
-                    yb, wb = devs[-2], devs[-1]
-                    with step_annotation("train.step", step):
-                        skey = jax.random.fold_in(key, step)
-                        dkeys = jnp.stack([jax.random.fold_in(skey, k)
-                                           for k in range(accum)])
-                        params, opt_state, l = fused_prog(
-                            params, opt_state, batch, yb, wb, dkeys)
-                    _metrics.observe("train.step_s",
-                                     _time.perf_counter() - t_step)
-                    t_step = _time.perf_counter()
-                    _after_step(s, l, epoch)
-            else:
-                def build_micro(m, _order=order):
-                    s, k = divmod(m, accum)
-                    start = s * bs
-                    m_real = min(bs, len(_order) - start)
-                    lo = k * micro_rows + shard_idx * shard_rows
-                    pos = np.arange(lo, lo + shard_rows)
-                    # positions past the real rows pad by repeating the LAST
-                    # real row of the effective batch with zero loss-weight —
-                    # the same exact-padding contract as the fused reference
-                    idx = _order[start + np.minimum(pos, m_real - 1)]
-                    arrs = [tr_inputs[k2][idx] for k2 in names] + [tr_y[idx]]
-                    return arrs + [(pos < m_real).astype(np.float32)]
-
-                t_step = _time.perf_counter()
-                skey = None
-                # an optimizer step spans accum feed items: its annotation
-                # opens on the first chunk and closes after the apply (or
-                # when the loop raises)
-                with contextlib.ExitStack() as step_scope:
-                    for m, devs in _timed_feed(_feed(
-                            build_micro, place, steps_per_epoch * accum,
-                            mode=cfg.feed, depth=cfg.feed_depth,
-                            phases=feed_phases)):
-                        s, k = divmod(m, accum)
-                        if k == 0:
-                            skey = jax.random.fold_in(key, step)
-                            step_scope.enter_context(
-                                step_annotation("train.step", step))
+                    for s, devs in _timed_feed(_feed(
+                            build, place, steps_per_epoch, mode=cfg.feed,
+                            depth=cfg.feed_depth, phases=feed_phases)):
                         batch = dict(zip(names, devs[:-2]))
                         yb, wb = devs[-2], devs[-1]
-                        gacc, wacc, lacc = micro_prog(
-                            gacc, wacc, lacc, params, batch, yb, wb,
-                            jax.random.fold_in(skey, k))
-                        _metrics.incr("train.micro_steps")
-                        if k == accum - 1:
-                            ga, wa, la = gacc, wacc, lacc
-                            if num_shards > 1:
-                                # rank-ordered sum of the per-process chunk
-                                # accumulators — bit-identical on every
-                                # process
-                                ga, wa, la = ordered_cross_process_sum(
-                                    (gacc, wacc, lacc))
-                            t_f = _time.perf_counter()
-                            params, opt_state, l, gacc, wacc, lacc = \
-                                apply_prog(params, opt_state, ga, wa, la)
-                            _metrics.observe("train.accum_flush_s",
-                                             _time.perf_counter() - t_f)
-                            _metrics.observe("train.step_s",
-                                             _time.perf_counter() - t_step)
-                            step_scope.close()
-                            t_step = _time.perf_counter()
-                            _after_step(s, l, epoch)
-            if not cfg.log_every:
-                lv = float(l)
-                history["loss"].append(lv)
-                elapsed = _time.perf_counter() - t_start
-                _metrics.record(
-                    "dl.train", step=step, loss=lv,
-                    samples_per_sec=(step - start_step) * bs / max(elapsed, 1e-9))
+                        with step_annotation("train.step", step):
+                            params, opt_state, l = train_step(
+                                params, opt_state, batch, yb, wb,
+                                jax.random.fold_in(key, step)
+                            )
+                        _metrics.observe("train.step_s",
+                                         _time.perf_counter() - t_step)
+                        t_step = _time.perf_counter()
+                        _after_step(s, l, epoch)
+                elif cfg.accum_mode == "fused":
+                    def build_full(s, _order=order):
+                        idx = _order[s * bs:(s + 1) * bs]
+                        arrs = [tr_inputs[k][idx] for k in names] + [tr_y[idx]]
+                        w = np.ones(len(idx), np.float32)
+                        if len(idx) < padded_bs:
+                            arrs = _pad_tail(arrs, padded_bs)
+                            w = np.concatenate(
+                                [w, np.zeros(padded_bs - len(idx), np.float32)])
+                        # pre-chunk host-side: (accum, micro, ...) — the scan's
+                        # chunk layout is decided HERE, not by an in-program
+                        # reshard (see chunked_batch_sharding)
+                        return [a.reshape((accum, micro_rows) + a.shape[1:])
+                                for a in arrs + [w]]
 
-            if on_epoch is not None:
-                on_epoch(epoch, params)
-            if save_ckpt:
-                ckpt.save(step, jax.device_get(params), jax.device_get(opt_state),
-                          {"step": step, "epoch": epoch})
-            if n_eval:
-                logits = _batched_apply(eval_prog, params, ev_inputs, mesh,
-                                        in_shard, bs)
-                if regression:
-                    metric = -float(np.mean((logits.squeeze(-1) - ev_y) ** 2))
+                    t_step = _time.perf_counter()
+                    for s, devs in _timed_feed(_feed(
+                            build_full, place_chunked, steps_per_epoch,
+                            mode=cfg.feed, depth=cfg.feed_depth,
+                            phases=feed_phases)):
+                        batch = dict(zip(names, devs[:-2]))
+                        yb, wb = devs[-2], devs[-1]
+                        with step_annotation("train.step", step):
+                            skey = jax.random.fold_in(key, step)
+                            dkeys = jnp.stack([jax.random.fold_in(skey, k)
+                                               for k in range(accum)])
+                            params, opt_state, l = fused_prog(
+                                params, opt_state, batch, yb, wb, dkeys)
+                        _metrics.observe("train.step_s",
+                                         _time.perf_counter() - t_step)
+                        t_step = _time.perf_counter()
+                        _after_step(s, l, epoch)
                 else:
-                    metric = float(np.mean(np.argmax(logits, -1) == ev_y))
-                history["eval_metric"].append(metric)
-                if best_metric is None or metric > best_metric:
-                    # host copy: the next train_step DONATES the live buffers, so
-                    # stashing the device tree directly would dangle
-                    best_metric, best_params = metric, jax.device_get(params)
-                    patience_left = cfg.early_stopping_patience
-                elif cfg.early_stopping_patience:
-                    patience_left -= 1
-                    if patience_left <= 0:
-                        break
+                    def build_micro(m, _order=order):
+                        s, k = divmod(m, accum)
+                        start = s * bs
+                        m_real = min(bs, len(_order) - start)
+                        lo = k * micro_rows + shard_idx * shard_rows
+                        pos = np.arange(lo, lo + shard_rows)
+                        # positions past the real rows pad by repeating the LAST
+                        # real row of the effective batch with zero loss-weight —
+                        # the same exact-padding contract as the fused reference
+                        idx = _order[start + np.minimum(pos, m_real - 1)]
+                        arrs = [tr_inputs[k2][idx] for k2 in names] + [tr_y[idx]]
+                        return arrs + [(pos < m_real).astype(np.float32)]
+
+                    t_step = _time.perf_counter()
+                    skey = None
+                    # an optimizer step spans accum feed items: its annotation
+                    # opens on the first chunk and closes after the apply (or
+                    # when the loop raises)
+                    with contextlib.ExitStack() as step_scope:
+                        for m, devs in _timed_feed(_feed(
+                                build_micro, place, steps_per_epoch * accum,
+                                mode=cfg.feed, depth=cfg.feed_depth,
+                                phases=feed_phases)):
+                            s, k = divmod(m, accum)
+                            if k == 0:
+                                skey = jax.random.fold_in(key, step)
+                                step_scope.enter_context(
+                                    step_annotation("train.step", step))
+                            batch = dict(zip(names, devs[:-2]))
+                            yb, wb = devs[-2], devs[-1]
+                            gacc, wacc, lacc = micro_prog(
+                                gacc, wacc, lacc, params, batch, yb, wb,
+                                jax.random.fold_in(skey, k))
+                            _metrics.incr("train.micro_steps")
+                            if k == accum - 1:
+                                ga, wa, la = gacc, wacc, lacc
+                                if num_shards > 1:
+                                    # rank-ordered sum of the per-process chunk
+                                    # accumulators — bit-identical on every
+                                    # process
+                                    ga, wa, la = ordered_cross_process_sum(
+                                        (gacc, wacc, lacc))
+                                t_f = _time.perf_counter()
+                                params, opt_state, l, gacc, wacc, lacc = \
+                                    apply_prog(params, opt_state, ga, wa, la)
+                                _metrics.observe("train.accum_flush_s",
+                                                 _time.perf_counter() - t_f)
+                                _metrics.observe("train.step_s",
+                                                 _time.perf_counter() - t_step)
+                                step_scope.close()
+                                t_step = _time.perf_counter()
+                                _after_step(s, l, epoch)
+                if not cfg.log_every:
+                    lv = float(l)
+                    history["loss"].append(lv)
+                    elapsed = _time.perf_counter() - t_start
+                    _metrics.record(
+                        "dl.train", step=step, loss=lv,
+                        samples_per_sec=(step - start_step) * bs / max(elapsed, 1e-9))
+                if watch is not None:
+                    watch.drain(epoch_span, wait=not cfg.log_every)
+
+                if on_epoch is not None:
+                    on_epoch(epoch, params)
+                if save_ckpt:
+                    ckpt.save(step, jax.device_get(params), jax.device_get(opt_state),
+                              {"step": step, "epoch": epoch})
+                if n_eval:
+                    logits = _batched_apply(eval_prog, params, ev_inputs, mesh,
+                                            in_shard, bs)
+                    if regression:
+                        metric = -float(np.mean((logits.squeeze(-1) - ev_y) ** 2))
+                    else:
+                        metric = float(np.mean(np.argmax(logits, -1) == ev_y))
+                    history["eval_metric"].append(metric)
+                    if best_metric is None or metric > best_metric:
+                        # host copy: the next train_step DONATES the live buffers, so
+                        # stashing the device tree directly would dangle
+                        best_metric, best_params = metric, jax.device_get(params)
+                        patience_left = cfg.early_stopping_patience
+                    elif cfg.early_stopping_patience:
+                        patience_left -= 1
+                        if patience_left <= 0:
+                            break
+    finally:
+        if watch is not None:
+            watch.close()
 
     if best_params is not None:
         params = best_params

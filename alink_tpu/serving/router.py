@@ -294,19 +294,28 @@ class _ModelEntry:
                             self._cond.wait(0.1)
                 with trace_span("serving.collect") as sp:
                     # let the batch fill until the oldest waiter's flush
-                    # deadline
+                    # deadline; why it was cut says whether a closed loop's
+                    # batches stay whole (full) or split (deadline)
                     flush_at = (self._oldest_enqueued()
                                 + self.config.flush_deadline_s)
+                    flushed_by = "full"
                     while (len(self._high) + len(self._normal)
                            < self.config.max_batch_rows):
                         rem = flush_at - time.perf_counter()
-                        if rem <= 0 or self._draining:
+                        if self._draining:
+                            flushed_by = "drain"
+                            break
+                        if rem <= 0:
+                            flushed_by = "deadline"
                             break
                         self._cond.wait(rem)
                     batch = self._pop_batch_locked()
                     self.batches += 1
+                    if flushed_by != "drain":
+                        metrics.incr("serving.flush_" + flushed_by)
                     if sp is not None:
                         sp.attrs["rows"] = len(batch)
+                        sp.attrs["flushed_by"] = flushed_by
             try:
                 self._run_batch(batch)
             except BaseException as e:
@@ -332,7 +341,8 @@ class _ModelEntry:
                              and now > r.future.deadline)), None)
         failed: List[Tuple[_Request, BaseException]] = []
         with attach_context(ctx):
-            with trace_span("serving.batch", model=self.name) as sp:
+            with trace_span("serving.batch", unit=self.name,
+                            model=self.name) as sp:
                 served = self._serve_batch(batch, now, failed, sp)
             # nothing but completions from here on: once the first client
             # wakes and resubmits, the next batch's flush deadline runs, and

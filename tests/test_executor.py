@@ -291,11 +291,11 @@ def test_fused_chain_keeps_passthrough_columns():
 
 
 def _trace_since(before):
-    """The records added after ``before`` was read, by identity: the trace is
-    a ring, and an offset into it means nothing once earlier tests of this
-    process have filled it."""
-    old = {id(r) for r in before}
-    return [r for r in executor_trace() if id(r) not in old]
+    """The records added after ``before`` was read, by their spans' ids: the
+    trace is read from the span ring, and an offset into it means nothing
+    once earlier tests of this process have filled it."""
+    old = {r["span_id"] for r in before}
+    return [r for r in executor_trace() if r["span_id"] not in old]
 
 
 def test_executor_records_per_node_trace():

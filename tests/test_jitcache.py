@@ -521,8 +521,12 @@ def test_compile_events_land_on_executor_node_phases():
 def test_executor_phase_summary_includes_compile():
     from alink_tpu.common.metrics import executor_phase_summary
 
-    metrics.record_bounded("executor.node", 4096, op="CompileProbeOp",
-                           wall_s=0.5, compile_s=0.25)
+    from alink_tpu.common.tracing import trace_span
+
+    # a unit's record is its span under a scheduled dag.run (PR 36)
+    with trace_span("dag.run", nodes=2, units=1):
+        with trace_span("CompileProbeOp") as sp:
+            sp.phases["compile_s"] = 0.25
     summary = executor_phase_summary()
     assert summary["CompileProbeOp"]["compile_s"] == pytest.approx(0.25)
 

@@ -189,9 +189,12 @@ def test_executor_phase_summary_aggregates_any_phase():
     (transfer/compute/compile) aggregate too."""
     from alink_tpu.common.metrics import executor_phase_summary
 
-    metrics.record_bounded("executor.node", 4096, op="PhaseProbeOp",
-                           wall_s=1.0, transfer_s=0.25, quantize_s=0.5,
-                           fused=2)
+    from alink_tpu.common.tracing import trace_span
+
+    # a unit's record is its span under a scheduled dag.run (PR 36)
+    with trace_span("dag.run", nodes=2, units=1):
+        with trace_span("PhaseProbeOp", fused=2) as sp:
+            sp.phases.update(transfer_s=0.25, quantize_s=0.5)
     summary = executor_phase_summary()
     d = summary["PhaseProbeOp"]
     assert d["count"] >= 1
@@ -330,9 +333,23 @@ def test_span_tree_matches_dag_with_parity(monkeypatch):
     assert sorted(k["name"] for k in tree["children"]) == names
 
     monkeypatch.setenv("ALINK_TRACING", "off")
+    # off turns the collector's hook, the CPU seconds and the slow-unit
+    # record off with the spans (PR 36): nothing is counted, nothing kept
+    import gc
+
+    from alink_tpu.common.metrics import metrics
+
+    full0 = metrics.counter("host.gc_collections.gen2")
+    ring0 = len(tracer.spans(collector=True))
+    cpu0 = {n for n in metrics.histogram_names() if n.startswith("cpu.")}
     off = _build_and_run_dag()
+    gc.collect()
     for k in ("a", "b", "c"):
         assert np.array_equal(on[k], off[k]), f"parity broke on {k}"
+    assert metrics.counter("host.gc_collections.gen2") == full0
+    assert len(tracer.spans(collector=True)) == ring0
+    assert {n for n in metrics.histogram_names()
+            if n.startswith("cpu.")} == cpu0
 
 
 @pytest.mark.observability

@@ -455,7 +455,7 @@ def test_fused_chain_defuses_and_succeeds_node_by_node():
     assert len(fused_units) == 1 and len(fused_units[0].ops) == 3
     # fail exactly the fused unit's first attempt
     faults.install(faults.FaultSpec.parse("unit:count=1"))
-    _run_unit(fused_units[0], record=False)
+    _run_unit(fused_units[0])
     faults.clear()
 
     assert defused() == 1
