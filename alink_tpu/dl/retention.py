@@ -18,9 +18,17 @@ This module holds the two forms of that one layer that a served generator
 runs, over grouped heads (``G`` query heads read each key/value head's state):
 
 - :func:`retention_chunk`: a chunk of a prompt, quadratic inside the chunk
-  (the attention form, no ``phi``) and through the state between chunks;
-  ``phi`` exists for one key/value head of one chunk at a time.
-- :func:`retention_step`: one new token for a batch of states.
+  (the attention form, no ``phi``) and through the state between chunks.
+  Through the state it has two programs. In a one-chip TPU process, at a
+  head width of 128, one kernel (:mod:`alink_tpu.dl.retention_pallas`):
+  ``phi`` exists in VMEM alone, one cyclic distance (128 of its 8,256
+  entries) of one row and key/value head at a time, and the state is read
+  and written once, in place. Everywhere else (the CPU, a mesh, another
+  width) XLA's form, a loop over key/value heads: ``phi`` of a chunk's
+  queries is written to memory for one key/value head at a time. The XLA
+  form is what the kernel is held to.
+- :func:`retention_step`: one new token for a batch of states; ``phi`` of
+  one position a head is written whole (XLA on every backend).
 
 A position marked invalid (padding) leaves a row's state untouched: its gate
 is 1 and its ``phi(k)`` is 0. State and normaliser are float32; ``dtype`` is
@@ -29,10 +37,15 @@ that of the matrix products' operands (bfloat16 on the chip).
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+
+from ..common.metrics import metrics
+from ..native.kernels import interpret_mode
+from .retention_pallas import chunk_through_state, use_chunk_kernel
 
 RETENTION_SCOPE = "retention_core"
 
@@ -81,6 +94,42 @@ def _mask_padding(k, log_g, valid):
             jnp.where(valid[..., None], log_g, 0))
 
 
+def _inside_chunk(q, k, v, cum, dtype):
+    """The attention form inside a chunk, with no ``phi``: q ``(...,G,T,D)``,
+    k, v ``(...,T,D)``, cum ``(...,T)``. Returns the numerators
+    ``(...,G,T,D)`` and the normalisers ``(...,G,T)``, float32."""
+    T, D = q.shape[-2:]
+    s = einsum_f32("...gtd,...sd->...gts", q.astype(dtype),
+                   k.astype(dtype)) / math.sqrt(D)
+    decay = jnp.where(jnp.tril(jnp.ones((T, T), bool)), jnp.exp(jnp.minimum(
+        cum[..., :, None] - cum[..., None, :], 0.0)), 0.0)    # (...,T,T)
+    # numerator and normaliser sum the same rounded weights, so that an
+    # output stays a convex combination of the values
+    a = (s * s * decay[..., None, :, :]).astype(dtype)
+    return (einsum_f32("...gts,...sd->...gtd", a, v.astype(dtype)),
+            a.astype(jnp.float32).sum(-1))
+
+
+def _through_state_xla(q, k, v, cum, S, z, *, dtype):
+    """What the state before the chunk adds to each position, and the state
+    after the chunk, for one key/value head: q ``(B,G,T,D)``, k, v
+    ``(B,T,D)``, cum ``(B,T)``, S ``(B,P,D)``, z ``(B,P)``."""
+    f32 = jnp.float32
+    # decayed to each position
+    pq = (power_embed(q.astype(f32))
+          * jnp.exp(cum)[:, None, :, None]).astype(dtype)
+    num = einsum_f32("bgtp,bpd->bgtd", pq, S.astype(dtype))
+    den = einsum_f32("bgtp,bp->bgt", pq, z.astype(dtype))
+    # every key decayed to the chunk's end
+    total = cum[:, -1]
+    pk = (power_embed(k.astype(f32))
+          * jnp.exp(total[:, None] - cum)[..., None]).astype(dtype)
+    Sn = jnp.exp(total)[:, None, None] * S + einsum_f32(
+        "btp,btd->bpd", pk, v.astype(dtype))
+    zn = jnp.exp(total)[:, None] * z + pk.astype(f32).sum(1)
+    return num, den, Sn, zn
+
+
 def retention_chunk(q, k, v, log_g, valid, S, z, *, eps: float,
                     dtype=jnp.float32):
     """One chunk of ``T`` prompt positions for ``B`` rows.
@@ -88,51 +137,41 @@ def retention_chunk(q, k, v, log_g, valid, S, z, *, eps: float,
     q ``(B,T,Hq,D)``; k, v ``(B,T,Hkv,D)``; log_g ``(B,T,Hkv)`` (log of the
     gate, <= 0); valid ``(B,T)`` bool or None; S ``(B,Hkv,P,D)`` and z
     ``(B,Hkv,P)`` float32, the state before the chunk. Returns the outputs
-    ``(B,T,Hq,D)`` float32 and the state after the chunk."""
+    ``(B,T,Hq,D)`` float32 and the state after the chunk.
+
+    One layer, two programs, chosen by the call's own shapes and the
+    kernel's gate (``retention_pallas.use_chunk_kernel``); which one a layer
+    was traced down is counted (``retention.chunk_fused_traces`` /
+    ``retention.chunk_xla_traces``)."""
     B, T, Hq, D = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
     k, log_g = _mask_padding(k, log_g.astype(jnp.float32), valid)
     cum = jnp.cumsum(log_g, axis=1)                       # (B,T,Hkv)
-    causal = jnp.tril(jnp.ones((T, T), bool))
-    f32 = jnp.float32
+    heads = lambda x: x.transpose(0, 2, 1, 3)             # (B,Hkv,T,D)
+    small = (q.reshape(B, T, Hkv, G, D).transpose(0, 2, 3, 1, 4), heads(k),
+             heads(v), cum.transpose(0, 2, 1))
 
-    def one_head(args):
-        qj, kj, vj, cj, Sj, zj = args     # (B,T,G,D) (B,T,D) (B,T,D) (B,T) ..
+    def attend(q, k, v, cum, S, z, through_state):
+        """Any leading dimensions: all heads at once or one of them."""
         with jax.named_scope(RETENTION_SCOPE):
-            s = einsum_f32("btgd,bsd->bgts", qj.astype(dtype),
-                           kj.astype(dtype)) / math.sqrt(D)
-            decay = jnp.where(causal, jnp.exp(jnp.minimum(
-                cj[:, :, None] - cj[:, None, :], 0.0)), 0.0)      # (B,T,T)
-            # numerator and normaliser sum the same rounded weights, so that
-            # an output stays a convex combination of the values
-            a = (s * s * decay[:, None]).astype(dtype)
-            num = einsum_f32("bgts,bsd->btgd", a, vj.astype(dtype))
-            den = a.astype(f32).sum(-1).transpose(0, 2, 1)        # (B,T,G)
-            # what the state before the chunk adds, decayed to each position
-            pq = (power_embed(qj.astype(f32))
-                  * jnp.exp(cj)[:, :, None, None]).astype(dtype)
-            num = num + einsum_f32("btgp,bpd->btgd", pq, Sj.astype(dtype))
-            den = den + einsum_f32("btgp,bp->btg", pq, zj.astype(dtype))
-            o = num / (den[..., None] + eps)
-            # the state after the chunk: every key decayed to the chunk's end
-            total = cj[:, -1]
-            pk = (power_embed(kj.astype(f32)) * jnp.exp(
-                total[:, None] - cj)[..., None]).astype(dtype)
-            Sn = jnp.exp(total)[:, None, None] * Sj + einsum_f32(
-                "btp,btd->bpd", pk, vj.astype(dtype))
-            zn = jnp.exp(total)[:, None] * zj + pk.astype(f32).sum(1)
-        return o, Sn, zn
+            num, den = _inside_chunk(q, k, v, cum, dtype)
+            num_s, den_s, S, z = through_state(q, k, v, cum, S, z, dtype=dtype)
+            return (num + num_s) / ((den + den_s)[..., None] + eps), S, z
 
-    heads_first = (q.reshape(B, T, Hkv, G, D).transpose(2, 0, 1, 3, 4),
-                   k.transpose(2, 0, 1, 3), v.transpose(2, 0, 1, 3),
-                   cum.transpose(2, 0, 1), S.transpose(1, 0, 2, 3),
-                   z.transpose(1, 0, 2))
-    # one key/value head at a time: phi of a chunk's queries for all heads
-    # at once would be the largest tensor of the model
-    o, Sn, zn = jax.lax.map(one_head, heads_first)
-    o = o.transpose(1, 2, 0, 3, 4).reshape(B, T, Hq, D)
-    return o, Sn.transpose(1, 0, 2, 3), zn.transpose(1, 0, 2)
+    if use_chunk_kernel(T, D):
+        metrics.incr("retention.chunk_fused_traces")
+        o, S, z = attend(*small, S, z, functools.partial(
+            chunk_through_state, interpret=interpret_mode()))
+    else:
+        metrics.incr("retention.chunk_xla_traces")
+        # one key/value head at a time: phi of a chunk's queries for all
+        # heads at once would be the largest tensor of the model
+        o, S, z = (jnp.moveaxis(x, 0, 1) for x in jax.lax.map(
+            lambda head: attend(*head, _through_state_xla),
+            tuple(jnp.moveaxis(x, 1, 0) for x in small + (S, z))))
+    # (B,Hkv,G,T,D) -> (B,T,Hq,D)
+    return o.transpose(0, 3, 1, 2, 4).reshape(B, T, Hq, D), S, z
 
 
 def retention_step(q, k, v, log_g, valid, S, z, *, eps: float):

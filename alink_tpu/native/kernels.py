@@ -10,7 +10,7 @@ roofline worst-offenders ranking, and alink-lint ALK008 reads
 :data:`KERNEL_MODULES` as the allow-list for ``jax.experimental.pallas``
 imports — a Pallas call site outside a registered module fails ``--check``.
 
-All three kernels share ONE gate parser (:func:`kernel_enabled`): an env
+All kernels share ONE gate parser (:func:`kernel_enabled`): an env
 knob set to a falsey spelling (``0/off/false/no``) disables, any other
 non-blank value enables, blank/unset defers to the backend default (on for
 the ``tpu`` backend, off elsewhere). Kernels are compiled by Mosaic unless
@@ -145,6 +145,27 @@ _REGISTRY: Dict[str, Dict[str, Any]] = {
                     "(tests/test_attn_fused.py); blockwise/ring outputs "
                     "within atol=1e-5 of the XLA path (fp32), knob-off "
                     "byte-identical (tests/test_kernels.py)",
+    },
+    "dl.retention_pallas": {
+        "knob": "ALINK_RETENTION_PALLAS",
+        # called from the generator's prefill program, which is placed on
+        # one device; on a mesh GSPMD could not partition it
+        "single_device_only": True,
+        "module": "alink_tpu/dl/retention_pallas.py",
+        # what a prompt chunk sends through the retention state of a row
+        # and key/value head (the queries' read of the state before the
+        # chunk, the keys' update of it), phi built in VMEM a cyclic
+        # distance at a time against that distance's block of the state
+        "entry": "chunk_through_state",
+        "programs": ("lm.prefill_chunk",),
+        "fallback": "retention_chunk's loop over key/value heads with phi "
+                    "from power_embed (dl/retention._through_state_xla), "
+                    "also for a head width other than 128 and a chunk "
+                    "length that is not a multiple of 8",
+        "contract": "outputs and the state after the chunk within 2e-5 "
+                    "(fp32) and 4e-2 (bf16, values of order 1) of the XLA "
+                    "form, padded positions and an inherited state "
+                    "included (tests/test_retention_pallas.py)",
     },
 }
 

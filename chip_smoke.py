@@ -358,6 +358,8 @@ def phase_kernels(ctx: Dict[str, Any]) -> Dict[str, Any]:
 
     from alink_tpu.dl.attention import blockwise_attention, packed_attention
     from alink_tpu.dl.attn_pallas import use_attn_pallas
+    from alink_tpu.dl.retention import phi_dim, retention_chunk
+    from alink_tpu.dl.retention_pallas import use_retention_pallas
     from alink_tpu.embedding import SkipGramConfig, train_skipgram_sharded
     from alink_tpu.embedding.sgns_pallas import use_sgns_pallas
     from alink_tpu.native.kernels import interpret_mode, kernel_ids
@@ -409,12 +411,29 @@ def phase_kernels(ctx: Dict[str, Any]) -> Dict[str, Any]:
 
         return np.asarray(jax.jit(both)(q, k, v, mask))
 
+    rd, rt = 128, 16          # a retention head is 128 wide at any width
+    rq, rk, rv, rs = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                      for shape in ((2, rt, 4, rd), (2, rt, 2, rd),
+                                    (2, rt, 2, rd), (2, 2, phi_dim(rd), rd)))
+    rz = jnp.abs(rs[..., 0]) + 1.0
+    rg = jnp.asarray(-rng.uniform(0.0, 0.1, (2, rt, 2)), jnp.float32)
+    rvalid = jnp.arange(rt)[None, :] < jnp.asarray([rt, rt - 5])[:, None]
+
+    def retention():
+        # a prompt chunk on an inherited state, one row ending inside it
+        out = jax.jit(lambda *a: retention_chunk(*a, eps=1e-6))(
+            rq, rk, rv, rg, rvalid, rs, rz)
+        return np.concatenate([np.asarray(x).ravel() for x in out])
+
     checks = {
         "tree.pallas_hist": ("ALINK_GBDT_PALLAS", use_pallas_hist, forest,
                              1e-5),
         "embedding.sgns_pallas": ("ALINK_SGNS_PALLAS", use_sgns_pallas, sgns,
                                   5e-5),
         "dl.attn_pallas": ("ALINK_ATTN_PALLAS", use_attn_pallas, attn, 1e-5),
+        # sums over 8,256 products in another order, values up to 5
+        "dl.retention_pallas": ("ALINK_RETENTION_PALLAS",
+                                use_retention_pallas, retention, 1e-4),
     }
     if set(checks) != set(kernel_ids()):
         raise RuntimeError(f"registered kernels {kernel_ids()} are not the "

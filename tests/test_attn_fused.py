@@ -597,3 +597,41 @@ def test_causal_kernels_compile_for_v5e_at_the_training_cells_size(
         assert "[2,16,1024,1024]" in looped.as_text()
         assert compiled.memory_analysis().temp_size_in_bytes \
             < looped.memory_analysis().temp_size_in_bytes + more
+
+
+def test_retention_chunk_kernel_compiles_for_v5e_at_the_generators_size(
+        one_chip, monkeypatch):
+    """``retention_chunk`` down its kernel at the Brumby cell's shapes (16
+    rows, a chunk of 256, 40 + 8 heads of 128, bfloat16 products, the state
+    donated) through Mosaic proper: the compiled program holds the kernel by
+    the name the trace reader knows, writes nothing as wide as phi, and
+    updates the state in place (one copy of its 541 MB, aliased). Here, beside
+    the other kernels of the main paths: one file loads the TPU's library."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl.retention import phi_dim, retention_chunk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # einsum_f32
+    monkeypatch.setenv("ALINK_RETENTION_PALLAS", "1")
+    monkeypatch.delenv("ALINK_PALLAS_INTERPRET")
+    b, t, hq, hkv, d = 16, 256, 40, 8, 128
+    p = phi_dim(d)
+    shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+    chunk = lambda *a: retention_chunk(*a, eps=1e-6, dtype=jnp.bfloat16)
+    compiled = jax.jit(chunk, donate_argnums=(5, 6)).lower(
+        shape(b, t, hq, d), shape(b, t, hkv, d), shape(b, t, hkv, d),
+        shape(b, t, hkv), shape(b, t, dtype=jnp.bool_),
+        shape(b, hkv, p, d), shape(b, hkv, p)).compile()
+    text = compiled.as_text()
+    assert "retention_chunk_state" in text
+    # phi of a chunk's queries or keys, flat or by distance
+    assert not re.search(rf"\[[\d,]*\b{t},[\d,]*\b({p}|{p + d + 1}|65,{d})\b",
+                         text)
+    memory = compiled.memory_analysis()
+    state = b * hkv * p * d * 4
+    assert memory.alias_size_in_bytes >= state
+    assert memory.temp_size_in_bytes < state

@@ -31,8 +31,8 @@ def test_registry_contents():
     from alink_tpu.native.kernels import (KERNEL_MODULES, covering,
                                           kernel_ids, kernel_spec, registry)
 
-    assert kernel_ids() == ("dl.attn_pallas", "embedding.sgns_pallas",
-                            "tree.pallas_hist")
+    assert kernel_ids() == ("dl.attn_pallas", "dl.retention_pallas",
+                            "embedding.sgns_pallas", "tree.pallas_hist")
     for kid in kernel_ids():
         spec = kernel_spec(kid)
         assert spec["knob"].startswith("ALINK_")
@@ -46,6 +46,8 @@ def test_registry_contents():
     assert covering("embedding.sgns_sharded") == "embedding.sgns_pallas"
     assert covering("dl.train_step") == "dl.attn_pallas"
     assert covering("dl.attention") == "dl.attn_pallas"
+    assert covering("lm.prefill_chunk") == "dl.retention_pallas"
+    assert covering("lm.decode_step") is None   # retention_step is XLA's
     assert covering("optim.lbfgs") is None
     assert covering("embedding.sgns") is None   # host engine: no kernel
 
@@ -72,6 +74,7 @@ def test_registry_readout_never_starts_a_backend():
     env = dict(os.environ, ALINK_GBDT_PALLAS="1")
     env.pop("ALINK_ATTN_PALLAS", None)
     env.pop("ALINK_SGNS_PALLAS", None)
+    env.pop("ALINK_RETENTION_PALLAS", None)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
                           capture_output=True, text=True, timeout=60)
@@ -79,6 +82,7 @@ def test_registry_readout_never_starts_a_backend():
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     assert out["jax"] is False
     assert out["enabled"] == {"dl.attn_pallas": None,
+                              "dl.retention_pallas": None,
                               "embedding.sgns_pallas": None,
                               "tree.pallas_hist": True}
 
@@ -100,14 +104,16 @@ def test_shared_gate_parses_all_three_knobs_identically(
         monkeypatch, value, expect):
     """One parser for every kernel knob: pallas_hist's historical
     convention (falsey spellings off, any other non-blank on) now comes
-    from the registry for all three ``use_*()`` gates."""
+    from the registry for every ``use_*()`` gate."""
     from alink_tpu.dl.attn_pallas import use_attn_pallas
+    from alink_tpu.dl.retention_pallas import use_retention_pallas
     from alink_tpu.embedding.sgns_pallas import use_sgns_pallas
     from alink_tpu.tree.pallas_hist import use_pallas_hist
 
     for knob, fn in (("ALINK_GBDT_PALLAS", use_pallas_hist),
                      ("ALINK_SGNS_PALLAS", use_sgns_pallas),
-                     ("ALINK_ATTN_PALLAS", use_attn_pallas)):
+                     ("ALINK_ATTN_PALLAS", use_attn_pallas),
+                     ("ALINK_RETENTION_PALLAS", use_retention_pallas)):
         monkeypatch.setenv(knob, value)
         assert fn() is expect, (knob, value)
         monkeypatch.delenv(knob)
@@ -340,13 +346,14 @@ def test_every_kernel_lowers_for_the_tpu_platform(monkeypatch):
     ``interpret=False`` and lower it for the ``tpu`` platform, which runs the
     BlockSpec tiling rules and the jaxpr->Mosaic lowering (the Mosaic
     compiler proper only runs on the chip — chip_smoke.py). Shapes are the
-    callers': 12 heads x 64 with K/V block 128; dim 128 with 5 negatives;
-    a depth-6, 64-bin histogram."""
+    callers': 12 heads x 64 with K/V block 128; a retention head 128 wide;
+    dim 128 with 5 negatives; a depth-6, 64-bin histogram."""
     import jax
     import jax.numpy as jnp
 
     from alink_tpu.dl.attention import (blockwise_attention, packed_attention,
                                         ring_attention)
+    from alink_tpu.dl.retention_pallas import chunk_through_state
     from alink_tpu.embedding.sgns_pallas import sgns_block_grads
     from alink_tpu.parallel.mesh import AXIS_SEQ, make_mesh
     from alink_tpu.tree.pallas_hist import pallas_histogram
@@ -378,6 +385,15 @@ def test_every_kernel_lowers_for_the_tpu_platform(monkeypatch):
     lowers(packed, qkv, mask)
     lowers(jax.grad(lambda *a: packed(*a).astype(jnp.float32).sum()),
            qkv, mask)
+
+    # a prompt chunk through the retention state, two query heads a
+    # key/value head (tests/test_attn_fused.py compiles it at the cell's size)
+    head = jnp.zeros((2, 2, 16, 128), jnp.bfloat16)
+    lowers(lambda *a: chunk_through_state(*a, dtype=jnp.bfloat16),
+           jnp.stack([head, head], axis=2), head, head,
+           jnp.zeros((2, 2, 16), jnp.float32),
+           jnp.zeros((2, 2, 8256, 128), jnp.float32),
+           jnp.zeros((2, 2, 8256), jnp.float32))
 
     v = jnp.zeros((256, 128), jnp.float32)
     lowers(sgns_block_grads, v, v, jnp.zeros((256, 5, 128), jnp.float32))
