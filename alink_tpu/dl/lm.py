@@ -679,8 +679,9 @@ def _shared_expert(cfg: CausalLMConfig, layer, n):
 
 def _experts_ffn(cfg: CausalLMConfig, layer, x, valid, load):
     """The expert layer over ``x (B,T,H)``: the routed experts held here,
-    the shared expert, and each row's count a held expert added to
-    ``load (B,E)``."""
+    the shared expert, each row's count a held expert added to ``load
+    (B,E)``, and ``(2,)`` int32: the call's sorted assignment rows that went
+    through the held experts' products (its rung) and those cut."""
     import jax
     import jax.numpy as jnp
 
@@ -693,11 +694,14 @@ def _experts_ffn(cfg: CausalLMConfig, layer, x, valid, load):
     with jax.named_scope(moe.ROUTE_SCOPE):
         local, added = moe.held_load(idx.reshape(B, T, -1), valid,
                                      cfg.experts_held)
-    y = moe.routed_experts(flat, local.reshape(B * T, -1), w, added.sum(axis=0),
+        sizes = added.sum(axis=0)
+    y = moe.routed_experts(flat, local.reshape(B * T, -1), w, sizes,
                            layer["experts_gate_up"], layer["experts_down"],
                            dtype=jnp.dtype(cfg.dtype))
     shared = _shared_expert(cfg, layer, n)
-    return x + y.reshape(B, T, H) + shared, load + added
+    rows = moe.rung(sizes.sum(), local.size)[1]
+    return (x + y.reshape(B, T, H) + shared, load + added,
+            jnp.stack([rows, local.size - rows]))
 
 
 def _experts_ffn_whole(cfg: CausalLMConfig, layer, x, bias, piece: int):
@@ -877,7 +881,8 @@ def _block(cfg: CausalLMConfig, i: int, layer, x, pos, valid, state, *,
            chunk: bool):
     """Layer ``i`` over a chunk ``x (B,T,H)`` or a step ``x (B,H)``, with
     what the layer keeps of each row (:meth:`CausalLMConfig.layer_state`);
-    the residual stream is float32."""
+    the residual stream is float32. Also an expert layer's rows through its
+    held experts' products and cut (:func:`_experts_ffn`), else None."""
     import jax.numpy as jnp
 
     kind, ffn = cfg.layer_types[i], cfg.ffn_type(i)
@@ -909,9 +914,21 @@ def _block(cfg: CausalLMConfig, i: int, layer, x, pos, valid, state, *,
                                     one(valid), *state[:3])
         x, kept = x + back(y), tuple(kept)
     if ffn == "dense":
-        return _ffn(cfg, layer, x), kept
-    x, load = _experts_ffn(cfg, layer, one(x), one(valid), state[-1])
-    return back(x), kept + (load,)
+        return _ffn(cfg, layer, x), kept, None
+    x, load, rows = _experts_ffn(cfg, layer, one(x), one(valid), state[-1])
+    return back(x), kept + (load,), rows
+
+
+def _count_rows(through, rows, first):
+    """What an expert stack's program hands back beside the state: its
+    ``through`` (a ``(2,)`` int32 of sorted assignment rows through the held
+    experts' products and cut since the batch began, emptied where ``first``
+    says one begins) with the expert layers' ``rows`` of this call added. A
+    stack without expert layers takes and gives nothing."""
+    import jax.numpy as jnp
+
+    return tuple(jnp.where(first, 0, t) + sum(r for r in rows if r is not None)
+                 for t in through)
 
 
 def _greedy(cfg: CausalLMConfig, params, x):
@@ -957,23 +974,26 @@ def _build_prefill_chunk(cfg: CausalLMConfig, rows: int):
     import jax
     import jax.numpy as jnp
 
-    def run(params, state, tokens, pos, first, last_idx, hidden):
+    def run(params, state, tokens, pos, first, last_idx, hidden, *through):
         """tokens, pos ``(rows, T)`` (pos < 0: padding); first: the batch's
         first chunk; last_idx ``(rows,)``: where in this chunk a row's
         prompt ends, or -1; hidden ``(rows, H)``: the last position's
-        residual of the rows whose prompt ended in an earlier chunk."""
+        residual of the rows whose prompt ended in an earlier chunk;
+        through: an expert stack's count (:func:`_count_rows`)."""
         valid = pos >= 0
         x = jnp.take(params["embed_tokens"], tokens, axis=0).astype(jnp.float32)
-        new = []
+        new, counted = [], []
         for i, (layer, kept) in enumerate(zip(params["layers"],
                                               _rows_of(state, rows))):
-            x, kept = _block(cfg, i, layer, x, jnp.maximum(pos, 0), valid,
-                             _fresh(cfg, i, kept, first), chunk=True)
+            x, kept, r = _block(cfg, i, layer, x, jnp.maximum(pos, 0), valid,
+                                _fresh(cfg, i, kept, first), chunk=True)
             new.append(kept)
+            counted.append(r)
         at = jnp.take_along_axis(
             x, jnp.maximum(last_idx, 0)[:, None, None], axis=1)[:, 0]
         hidden = jnp.where((last_idx >= 0)[:, None], at, hidden)
-        return _rows_back(state, new, rows), hidden
+        return ((_rows_back(state, new, rows), hidden)
+                + _count_rows(through, counted, first))
 
     return jax.jit(run, donate_argnums=(1,))
 
@@ -988,19 +1008,22 @@ def _build_decode_step(cfg: CausalLMConfig, rows: int):
     import jax
     import jax.numpy as jnp
 
-    def run(params, state, tok, pos):
+    def run(params, state, tok, pos, *through):
         """tok, pos ``(rows,)``; pos < 0: a row beyond the batch's, which
-        writes no state it could read and asks no expert."""
+        writes no state it could read and asks no expert; through: an
+        expert stack's count (:func:`_count_rows`)."""
         valid = pos >= 0
         x = jnp.take(params["embed_tokens"], tok, axis=0).astype(jnp.float32)
-        new = []
+        new, counted = [], []
         for i, (layer, kept) in enumerate(zip(params["layers"],
                                               _rows_of(state, rows))):
-            x, kept = _block(cfg, i, layer, x, jnp.maximum(pos, 0), valid, kept,
-                             chunk=False)
+            x, kept, r = _block(cfg, i, layer, x, jnp.maximum(pos, 0), valid,
+                                kept, chunk=False)
             new.append(kept)
+            counted.append(r)
         tok, logprob = _greedy(cfg, params, x)
-        return _rows_back(state, new, rows), tok, logprob
+        return ((_rows_back(state, new, rows), tok, logprob)
+                + _count_rows(through, counted, False))
 
     return jax.jit(run, donate_argnums=(1,))
 
@@ -1075,6 +1098,8 @@ class CausalLM:
 
     def __init__(self, cfg: CausalLMConfig, params, *, slots: int,
                  positions: int = 0, prefill_chunk: int = 0):
+        import jax.numpy as jnp
+
         from ..common.jitcache import bucket_rows
 
         self.cfg, self.params = cfg, params
@@ -1095,6 +1120,11 @@ class CausalLM:
                 f"needs `positions`, the positions a slot's cache holds")
         self.cache = StateCache(cfg, bucket_rows(slots),
                                 positions if self._grows else 0)
+        # an expert stack's programs add up, on the device, the sorted rows
+        # their expert layers take through the held experts' products and
+        # those they cut (_count_rows); read once a batch
+        self._through = ((jnp.zeros((2,), jnp.int32),)
+                         if "experts" in cfg.ffn_types else ())
 
     def _program(self, kernel_id: str, builder, rows: int):
         from ..common.jitcache import cached_jit
@@ -1175,17 +1205,21 @@ class CausalLM:
         the assignments each held expert served, ``(rows, expert layers,
         experts held)``, returned; from them the counters: the assignments
         the batch's tokens made, those held here, and each expert layer's
-        largest load over its mean."""
+        largest load over its mean; beside them the sorted rows the batch's
+        expert layers took through the held experts' products and cut."""
+        import jax
         import jax.numpy as jnp
 
-        by_row = np.asarray(jnp.stack(
+        by_row, (through, cut) = jax.device_get((jnp.stack(
             [kept[-1][:n] for kept, ffn in zip(self.cache.peek(),
                                                self.cfg.ffn_types)
-             if ffn == "experts"], axis=1))
+             if ffn == "experts"], axis=1), self._through[0]))
         loads = by_row.sum(axis=0)
         metrics.incr("moe.assignments",
                      tokens * self.cfg.num_experts_per_tok * len(loads))
         metrics.incr("moe.assignments_held", int(loads.sum()))
+        metrics.incr("moe.rows_through", int(through))
+        metrics.incr("moe.rows_cut", int(cut))
         for load in loads:
             if load.sum():
                 metrics.observe("moe.expert_load_max_over_mean",
@@ -1217,9 +1251,9 @@ class CausalLM:
                 sl = slice(c * T, (c + 1) * T)
                 last = lens - 1 - c * T
                 last = np.where((last >= 0) & (last < T), last, -1).astype(np.int32)
-                state, hidden = prog(self.params, self.cache.take(),
-                                     tokens[:, sl], pos[:, sl], c == 0, last,
-                                     hidden)
+                state, hidden, *self._through = prog(
+                    self.params, self.cache.take(), tokens[:, sl], pos[:, sl],
+                    c == 0, last, hidden, *self._through)
                 self.cache.put(state)
                 now = time.perf_counter()
                 if c < chunks - 1:
@@ -1249,9 +1283,10 @@ class CausalLM:
             slowest, t_last = (0, 0.0), time.perf_counter()
             for i in range(1, max_new):
                 with step_annotation("lm.decode_step", i):
-                    state, tok, logprob = prog(
+                    state, tok, logprob, *self._through = prog(
                         self.params, self.cache.take(), tok,
-                        np.where(live, lens + i - 1, -1).astype(np.int32))
+                        np.where(live, lens + i - 1, -1).astype(np.int32),
+                        *self._through)
                     self.cache.put(state)
                     toks.append(tok)
                     logprobs.append(logprob)

@@ -17,6 +17,12 @@ terms: an assignment to an expert elsewhere is that expert's chip's to
 compute, and nothing stands in for it here. No token is dropped: the
 assignments held are sorted by expert and go through grouped matrix products
 (``jax.lax.ragged_dot``) whose groups are as long as the router made them.
+Those products take the sorted rows up to the smallest rung of a ladder of
+row counts (:func:`rungs`) that holds every held assignment of the call, and
+not all ``N K`` of them: with 16 of 256 experts held some 6% of the rows are
+held, and every other row would be gathered, activated, zeroed and unsorted
+for nothing. The top rung is ``N K``, so a router however skewed loses no
+assignment.
 
 Under ``jax.grad`` (the training step of :mod:`alink_tpu.dl.lm`) the choice is
 a constant: the gradient reaches ``W_r`` through the chosen scores' normalised
@@ -32,6 +38,7 @@ the peak in the training step; PERF.md section 6, PR 34).
 from __future__ import annotations
 
 from functools import partial
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +47,10 @@ from .retention import einsum_f32
 
 ROUTE_SCOPE = "moe_route"
 EXPERTS_SCOPE = "moe_experts"
+# the ladder of sorted rows the grouped form takes: down to a sixteenth of a
+# call's assignments, and no rung under 128 rows (a pass of the matrix unit)
+RUNG_DEPTH = 16
+RUNG_MIN_ROWS = 128
 
 
 def route(logits, bias, *, n_group: int, topk_group: int, top_k: int,
@@ -118,17 +129,41 @@ def _expert_rows(xs, w_rows, sizes, gate_up, down, *, dtype):
                xs.dtype).astype(dtype)                           # (R,H)
 
 
+def rungs(rows: int) -> Tuple[int, ...]:
+    """The row counts, ascending, the grouped form may take through the held
+    experts' products for ``rows`` sorted assignments: each power of two
+    below ``rows`` down to ``rows / RUNG_DEPTH`` and none under
+    ``RUNG_MIN_ROWS``, then ``rows`` itself."""
+    low, p = max(RUNG_MIN_ROWS, -(-rows // RUNG_DEPTH)), 1
+    while 2 * p < rows:
+        p *= 2
+    ladder = [rows]
+    while low <= p < rows:
+        ladder.append(p)
+        p //= 2
+    return tuple(reversed(ladder))
+
+
+def rung(held, rows: int):
+    """The smallest rung of :func:`rungs` that holds ``held`` assignments,
+    on the device: its index and its rows (int32)."""
+    ladder = rungs(rows)
+    i = jnp.sum(jnp.asarray(ladder[:-1], jnp.int32) < held, dtype=jnp.int32)
+    return i, jnp.asarray(ladder, jnp.int32)[i]
+
+
 def routed_experts(n, local, w, sizes, gate_up, down, *, dtype,
                    piece: int = 0):
     """The held experts' part of the layer's output.
 
     n ``(N,H)`` float32; local, w ``(N,K)``: each assignment's expert among
     the ``E`` held (``E``: not held) and its weight; sizes ``(E,)`` int32:
-    assignments an expert; gate_up ``(E,H,2F)``, down ``(E,F,H)``. With
-    ``piece`` an expert's sorted rows are taken ``piece`` at a time through
-    plain products (:func:`_pieces_fwd`): the training step's form."""
+    assignments an expert; gate_up ``(E,H,2F)``, down ``(E,F,H)``. The
+    sorted rows go through the grouped products up to the call's rung
+    (:func:`rung`). With ``piece`` an expert's sorted rows are taken
+    ``piece`` at a time through plain products (:func:`_pieces_fwd`): the
+    training step's form."""
     N, K = local.shape
-    E = gate_up.shape[0]
     flat = local.reshape(-1)
     order = jnp.argsort(flat, stable=True)       # held first, by expert
     if piece:
@@ -136,15 +171,24 @@ def routed_experts(n, local, w, sizes, gate_up, down, *, dtype,
         order = jnp.concatenate([order, jnp.zeros((piece,), order.dtype)])
         return _pieces(n.astype(dtype), w, gate_up, down, order, sizes, dtype,
                        piece)
-    back = jnp.argsort(order)
-    ours = (flat[order] < E)[:, None]
+    held = sizes.sum()
+
+    def through(R: int):
+        def run(n_t, w, order, held, sizes, gate_up, down):
+            src = order[:R]
+            out = _expert_rows(n_t[src // K], w.reshape(-1)[src], sizes,
+                               gate_up, down, dtype=dtype)       # (R,H)
+            # a row past the held ones belongs to no expert held here
+            tok = jnp.where(jnp.arange(R) < held, src // K, N)
+            return jnp.zeros(n_t.shape, jnp.float32).at[tok].add(
+                out.astype(jnp.float32), mode="drop")
+        return run
+
     with jax.named_scope(EXPERTS_SCOPE):
-        xs = n.astype(dtype)[order // K]                         # (NK,H)
-        out = _expert_rows(xs, w.reshape(-1)[order], sizes, gate_up, down,
-                           dtype=dtype)
-        # a row past the last group belongs to no expert held here
-        out = jnp.where(ours, out, 0)
-        return out[back].reshape(N, K, -1).astype(jnp.float32).sum(axis=1)
+        return jax.lax.switch(rung(held, N * K)[0],
+                              [through(R) for R in rungs(N * K)],
+                              n.astype(dtype), w, order, held, sizes, gate_up,
+                              down)
 
 
 def _rows_through(xs, w_rows, gate_up, down, *, dtype):

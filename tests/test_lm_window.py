@@ -487,8 +487,9 @@ def test_the_sixteen_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
                      "expert_bias": w["mlp.gate.e_score_correction_bias"]}
             held = dataclasses.replace(cfg, experts_held=(e, e + 1),
                                        dtype="float32")
-            y, load = L._experts_ffn(held, layer, n[None], jnp.ones((1, N), bool),
-                                     jnp.zeros((1, 1), jnp.int32))
+            y, load, _ = L._experts_ffn(held, layer, n[None],
+                                        jnp.ones((1, N), bool),
+                                        jnp.zeros((1, 1), jnp.int32))
             return y[0] - n, load
 
         parts = [share_of(e) for e in range(E_all)]
@@ -562,6 +563,42 @@ def test_counters_and_gauges_of_a_batch(model):
     assert steps == NEW - 1
     assert "lm.step_latent_positions" not in h1 or \
         h1["lm.step_latent_positions"] == h0.get("lm.step_latent_positions")
+
+
+@pytest.mark.parametrize("valid", [128, 40, 0])
+def test_an_expert_layer_counts_its_rung_and_the_rows_it_cut(model, valid):
+    """One call of an expert layer over 2 x 128 positions, 1,024 sorted
+    rows: the rows through the held experts' products are the smallest rung
+    that holds the held assignments, and with those cut they are every
+    row."""
+    from alink_tpu.dl import moe
+
+    x = jax.random.normal(jax.random.PRNGKey(valid), (2, 128, 64))
+    ok = jnp.broadcast_to(jnp.arange(128) < valid, (2, 128))
+    load = jnp.zeros((2, 4), jnp.int32)
+    _, added, (through, cut) = L._experts_ffn(model.cfg, model.params["layers"][1],
+                                              x, ok, load)
+    held = int(added.sum())
+    assert int(through) + int(cut) == 2 * 128 * 4
+    assert int(through) == min(r for r in moe.rungs(1024) if r >= held)
+    assert held <= 2 * valid * 4 and (held > 0) == (valid > 0)
+
+
+def test_a_batch_counts_the_rows_its_expert_layers_took_and_cut(model):
+    """Chunks of 16 over 8 rows: 512 sorted rows a prompt chunk and layer,
+    of which the held ones fit a lower rung, and 32 a decode step (one rung).
+    The two counters a batch adds, from its one read, are every row of every
+    call in all six expert layers."""
+    m = L.CausalLM(model.cfg, model.params, slots=8, positions=POSITIONS,
+                   prefill_chunk=16)
+    c0 = dict(metrics.counters("moe."))
+    m.generate(PROMPTS, NEW)
+    grew = lambda n: metrics.counters("moe.").get(n, 0) - c0.get(n, 0)
+    chunks = -(-max(map(len, PROMPTS)) // 16)
+    assert grew("moe.rows_through") + grew("moe.rows_cut") == 6 * 4 * 8 * (
+        chunks * 16 + NEW - 1)
+    assert grew("moe.rows_cut") > 0
+    assert grew("moe.rows_through") >= grew("moe.assignments_held") > 0
     assert metrics.gauge("lm.kv_cache_positions") == POSITIONS + L.HYBRID_PREFILL_CHUNK
 
 
