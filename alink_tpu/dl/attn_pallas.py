@@ -762,8 +762,8 @@ def use_gqa_attention(rows: int, qk_dim: int, v_dim: int, positions: int,
     """Whether ``dl/gqa.full_attend`` takes the kernel: decided from the
     call's own shapes and the kernel's gate. The cache in whole blocks of a
     whole number of sublane tiles, a key/value head's queries whole sublane
-    tiles, queries and keys of whole or half lane groups, values of whole
-    ones."""
+    tiles (``dl/gqa`` pads them to one), queries and keys of whole or half
+    lane groups, values of whole ones."""
     return (positions % block == 0 and block % _SUBLANE == 0
             and rows % _SUBLANE == 0
             and qk_dim % (_LANES // 2) == 0 and v_dim % _LANES == 0
@@ -863,8 +863,8 @@ def gqa_attention(q, K, V, start, hi, chunk: int, *, scale: float, block: int,
 
 def use_window_attention(rows: int, qk_dim: int, v_dim: int) -> bool:
     """Whether ``dl/gqa.window_attend`` takes the kernel: a key/value head's
-    queries whole sublane tiles, queries and keys of whole or half lane
-    groups, values of whole ones, and the kernel's gate."""
+    queries whole sublane tiles (``dl/gqa`` pads them to one), q and k of
+    whole or half lane groups, v of whole ones, and the kernel's gate."""
     return (rows % _SUBLANE == 0 and qk_dim % (_LANES // 2) == 0
             and v_dim % _LANES == 0 and use_attn_pallas())
 

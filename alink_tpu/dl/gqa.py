@@ -17,6 +17,13 @@ multiple of the block, that is one kernel (``attn_pallas.gqa_attention``,
 length and no further; everywhere else it is XLA's loop over blocks here
 (:func:`_blocks`), the kernel's reference.
 
+Both kernels take a key/value head's query rows as whole sublane tiles of 8.
+Where ``G x T`` is not one (a decode step of 6 query heads a key/value head:
+6 rows), the rows are padded to the next tile with rows whose outputs are
+dropped (:func:`tile_rows`); the rows added are counted on the host
+(``attention.rows_padded``, :func:`rows_added`). Where ``G x T`` is whole
+tiles, the call is as it was.
+
 ``window``: a query at ``t`` sees ``t - W + 1 .. t`` (``W`` the window) and,
 where the layer has one, a learned sink logit a head joins the softmax's
 denominator and weighs no value::
@@ -46,8 +53,8 @@ import jax.numpy as jnp
 
 from ..common.metrics import metrics
 from ..native.kernels import interpret_mode
-from .attn_pallas import (gqa_attention, use_gqa_attention, use_window_attention,
-                          window_attention)
+from .attn_pallas import (_SUBLANE, gqa_attention, use_gqa_attention,
+                          use_window_attention, window_attention)
 from .retention import einsum_f32
 
 GQA_SCOPE = "gqa_core"
@@ -60,6 +67,31 @@ KV_BLOCK = 1024
 # a masked score: finite, so that a block no key of which is seen leaves
 # the running sums as they were instead of making them NaN
 _NEG = -1e30
+
+
+def tile_rows(rows: int) -> int:
+    """A key/value head's ``rows`` query rows as the kernels take them: in
+    whole sublane tiles."""
+    return -(-rows // _SUBLANE) * _SUBLANE
+
+
+def rows_added(kind: str, rows: int, qk_dim: int, v_dim: int,
+               positions: int) -> int:
+    """Query rows a key/value head's block gains in one call of ``kind``'s
+    core (``attention`` over a cache of ``positions``, or ``window``) to
+    reach a whole tile: 0 where the rows are whole tiles or no kernel takes
+    the call. The kernels' own gates decide, as in the call."""
+    tile = tile_rows(rows)
+    on = (use_window_attention(tile, qk_dim, v_dim) if kind == "window" else
+          use_gqa_attention(tile, qk_dim, v_dim, positions, KV_BLOCK))
+    return tile - rows if on else 0
+
+
+def _pad_rows(x, tile: int, axis: int, **how):
+    """``x`` padded along ``axis`` (a head's query rows) to ``tile``."""
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, tile - x.shape[axis])
+    return jnp.pad(x, pad, **how)
 
 
 def cache_positions(positions: int, chunk: int) -> int:
@@ -123,14 +155,19 @@ def full_attend(q, k, v, K, V, length, valid, *, scale: float, dtype):
     # nothing where the chunk holds none of its positions
     hi = jnp.where(valid.any(axis=1), length, 0).astype(jnp.int32)
     rows = _grouped(q, hkv, dtype)
-    fused = use_gqa_attention(rows.shape[2], q.shape[-1], V.shape[-1], P,
-                              KV_BLOCK)
+    R = rows.shape[2]
+    tile = tile_rows(R)
+    fused = use_gqa_attention(tile, q.shape[-1], V.shape[-1], P, KV_BLOCK)
     with jax.named_scope(GQA_SCOPE):
         if fused:
             metrics.incr("attention.gqa_fused_traces")
+            if tile != R:   # row r >= R reads as row r % T: dropped below
+                rows = _pad_rows(rows, tile, 2)
             o = gqa_attention(rows, K, V, start.astype(jnp.int32), hi, T,
                               scale=scale, block=KV_BLOCK,
                               interpret=interpret_mode())
+            if tile != R:
+                o = o[:, :, :R]
         else:
             metrics.incr("attention.gqa_block_traces")
             o = _blocks(rows, K.astype(dtype), V.astype(dtype),
@@ -208,7 +245,8 @@ def window_attend(q, k, v, ring_k, ring_v, length, valid, sink, *,
     B, T, Hq, D = q.shape
     hkv, W = ring_v.shape[1], ring_v.shape[2]
     G = Hq // hkv
-    fused = use_window_attention(G * T, D, v.shape[-1])
+    tile = tile_rows(G * T)
+    fused = use_window_attention(tile, D, v.shape[-1])
     with jax.named_scope(WINDOW_SCOPE):
         qpos = length[:, None] + jnp.arange(T)[None, :]                # (B,T)
         held = ring_positions(length, W)                               # (B,W)
@@ -223,11 +261,18 @@ def window_attend(q, k, v, ring_k, ring_v, length, valid, sink, *,
                                      axis=2)
             sinks = jnp.full((hkv, G), _NEG, jnp.float32) if sink is None \
                 else sink.astype(jnp.float32).reshape(hkv, G)
-            o = _ungrouped(window_attention(
-                _grouped(q, hkv, dtype), keys, values,
-                jnp.tile(qpos, (1, G))[..., None], kpos[:, None, :],
-                jnp.repeat(sinks, T, axis=1)[..., None], scale=scale,
-                window=window, interpret=interpret_mode()), T)
+            rows = _grouped(q, hkv, dtype)
+            rows_at = jnp.tile(qpos, (1, G))[..., None]
+            keys_at = kpos[:, None, :]
+            rows_sink = jnp.repeat(sinks, T, axis=1)[..., None]
+            if tile != G * T:   # rows added: zero queries at the last row's
+                rows = _pad_rows(rows, tile, 2)      # position with no sink,
+                rows_at = _pad_rows(rows_at, tile, 1, mode="edge")  # dropped
+                rows_sink = _pad_rows(rows_sink, tile, 1, constant_values=_NEG)
+            o = window_attention(rows, keys, values, rows_at, keys_at,
+                                 rows_sink, scale=scale, window=window,
+                                 interpret=interpret_mode())
+            o = _ungrouped(o if tile == G * T else o[:, :, :G * T], T)
         else:
             metrics.incr("attention.window_block_traces")
             o = _window_xla(q, k, v, ring_k, ring_v, qpos, held, valid, sink,

@@ -13,17 +13,24 @@ mixes positions is named per layer in the configuration's ``layer_types``:
 - ``mla`` (:mod:`alink_tpu.dl.mla`): softmax attention over a compressed
   cache that grows with the sequence, one latent a position.
 - ``attention`` and ``window`` (:mod:`alink_tpu.dl.gqa`): grouped-query
-  softmax attention with no q/k norm, rotary on the first ``rotary_dim`` of a
-  head's widths, over a cache of keys and values that grows with the
-  sequence (full) or over a ring of the last ``sliding_window`` positions
-  with a learned sink a head (window); each kind has its own key/value heads,
-  widths and rotary base, and both scale the values by
-  ``attention_value_scale``.
+  softmax attention, rotary on the first ``rotary_dim`` of a head's widths,
+  over a cache of keys and values that grows with the sequence (full) or
+  over a ring of the last ``sliding_window`` positions, with a learned sink a
+  head where the family has one (window); each kind has its own key/value
+  heads, widths and rotary base, and both scale the values by
+  ``attention_value_scale``. Four switches, off unless the family has them:
+  a per-head RMS norm on queries and keys (``qk_norm``), no rotary in full
+  layers (``full_rotary`` off: they carry no positions), a sigmoid gate on
+  every output channel of the heads before ``o_proj`` (``attention_gate``,
+  device scope ``attn_gate``), and the mixer's and the feed-forward's
+  outputs normed before they join the residual stream (``sandwich_norms``).
 
 What follows the mixer is named per layer in ``ffn_types``: the ``dense``
 SiLU-gated feed-forward, or ``experts`` (:mod:`alink_tpu.dl.moe`), the routed
-experts this process holds and the shared expert where the family has one.
-``from_hf`` derives both patterns from a checkpoint's ``model_type``.
+experts this process holds and the shared expert where the family has one
+(device scope ``shared_expert``). The embedding is scaled by
+``embedding_scale`` (1 but where the family scales it). ``from_hf`` derives
+both patterns from a checkpoint's ``model_type``.
 
 Parameters keep the checkpoint's layout (HF: a linear's weight is
 ``(out, in)``) and its bfloat16 on the device; nothing is transposed or
@@ -131,6 +138,14 @@ class CausalLMConfig:
     swa_sink: bool = False
     rotary_dim: int = 0
     attention_value_scale: float = 1.0
+    # the afmoe block's switches (module docstring), each off in the families
+    # before it; the FFN's input norm is then ``pre_mlp_layernorm`` and
+    # ``post_attention_layernorm`` norms the mixer's output
+    qk_norm: bool = False
+    full_rotary: bool = True
+    attention_gate: bool = False
+    sandwich_norms: bool = False
+    embedding_scale: float = 1.0
     # training an expert layer: the weight of the sequence-wise balance term
     # and the step of the router's bias (DeepSeek-V3 section 4.2), unless the
     # checkpoint's config.json says otherwise
@@ -143,8 +158,9 @@ class CausalLMConfig:
         (retention in every layer unless ``layer_types`` says otherwise: the
         family publishes the dense block's keys and no key of its mixer),
         ``bailing_hybrid`` (:func:`_bailing_hybrid_fields`),
-        ``deepseek_v3`` (:func:`_deepseek_v3_fields`) or ``mimo_v2_flash``
-        (:func:`_mimo_v2_flash_fields`)."""
+        ``deepseek_v3`` (:func:`_deepseek_v3_fields`), ``mimo_v2_flash``
+        (:func:`_mimo_v2_flash_fields`) or ``afmoe``
+        (:func:`_afmoe_fields`)."""
         if hf.get("hidden_act", "silu") != "silu":
             raise NotImplementedError(f"hidden_act {hf['hidden_act']!r}")
         n = int(hf["num_hidden_layers"])
@@ -171,11 +187,13 @@ class CausalLMConfig:
             fields.update(_deepseek_v3_fields(hf, n))
         elif model_type == "mimo_v2_flash":
             fields.update(_mimo_v2_flash_fields(hf, n))
+        elif model_type == "afmoe":
+            fields.update(_afmoe_fields(hf, n))
         else:
             raise NotImplementedError(
                 f"model_type {model_type!r}: the block is written for "
-                f"'brumby', 'bailing_hybrid', 'deepseek_v3' and "
-                f"'mimo_v2_flash'")
+                f"'brumby', 'bailing_hybrid', 'deepseek_v3', "
+                f"'mimo_v2_flash' and 'afmoe'")
         fields.update(over)
         return cls(**fields)
 
@@ -422,6 +440,60 @@ def _mimo_v2_flash_fields(hf: Dict[str, Any], n: int) -> Dict[str, Any]:
         router_bias_name="e_score_correction_bias")
 
 
+def _afmoe_fields(hf: Dict[str, Any], n: int) -> Dict[str, Any]:
+    """The ``afmoe`` keys (Trinity): layer ``i`` is a window layer where
+    ``layer_types[i]`` is ``sliding_attention`` and a full layer, with no
+    rotary, where it is ``full_attention`` (the published list has an entry
+    a published layer; the first ``n`` are the kept ones); the first
+    ``num_dense_layers`` layers have the dense feed-forward, the rest experts
+    beside ``num_shared_experts`` shared ones of their summed width; a q/k
+    norm, a sigmoid gate on every head's output channel and four norms a
+    layer; the embedding times ``sqrt(hidden_size)`` (``mup_enabled``); the
+    chosen experts' sigmoid scores normalised and times ``route_scale``. A
+    setting the block does not compute is refused, not ignored: another
+    scoring, unnormalised or grouped routing, an embedding left unscaled, a
+    head tied to the embedding, biases, scaled rotary, another layer type."""
+    want = dict(score_func="sigmoid", route_norm=True, mup_enabled=True,
+                tie_word_embeddings=False, attention_bias=False,
+                rope_scaling=None)
+    bad = {k: hf[k] for k, v in want.items() if k in hf and hf[k] != v}
+    for key in ("n_group", "num_expert_groups", "topk_group",
+                "num_limited_groups"):
+        if int(hf.get(key) or 1) != 1:
+            bad[key] = hf[key]
+    if bad:
+        raise NotImplementedError(f"afmoe with {bad}: the block computes "
+                                  f"none of these")
+    every = int(hf.get("global_attn_every_n_layers") or 1)
+    types = list(hf.get("layer_types") or (
+        "full_attention" if (i + 1) % every == 0 else "sliding_attention"
+        for i in range(n)))[:n]
+    by_type = {"sliding_attention": "window", "full_attention": "attention"}
+    if len(types) != n or set(types) - set(by_type):
+        raise NotImplementedError(
+            f"layer_types {types} for {n} layers: 'sliding_attention' or "
+            f"'full_attention' a layer")
+    d = int(hf["head_dim"])
+    kv = int(hf["num_key_value_heads"])
+    dense = int(hf.get("num_dense_layers", 0))
+    experts = int(hf["num_experts"])
+    return dict(
+        layer_types=tuple(by_type[t] for t in types),
+        ffn_types=tuple("dense" if i < dense else "experts" for i in range(n)),
+        v_head_dim=d, swa_num_key_value_heads=kv, swa_head_dim=d,
+        swa_v_head_dim=d, swa_rope_theta=float(hf.get("rope_theta", 1e4)),
+        sliding_window=int(hf["sliding_window"]), qk_norm=True,
+        full_rotary=False, attention_gate=True, sandwich_norms=True,
+        embedding_scale=float(hf["hidden_size"]) ** 0.5,
+        num_experts=experts, experts_held=_held(hf, experts, 1),
+        num_experts_per_tok=int(hf["num_experts_per_tok"]),
+        routed_scaling_factor=float(hf.get("route_scale") or 1.0),
+        moe_intermediate_size=int(hf["moe_intermediate_size"]),
+        shared_intermediate_size=int(hf.get("num_shared_experts") or 0)
+        * int(hf["moe_intermediate_size"]),
+        router_bias_name="expert_bias")
+
+
 def _mixer_shapes(cfg: CausalLMConfig, kind: str) -> Dict[str, Tuple[int, ...]]:
     """A mixer's tensors by their name inside ``self_attn``; a name without
     ``.weight`` or ``.bias`` is a bare parameter."""
@@ -433,6 +505,10 @@ def _mixer_shapes(cfg: CausalLMConfig, kind: str) -> Dict[str, Tuple[int, ...]]:
                "v_proj.weight": (hkv * dv, h), "o_proj.weight": (h, hq * dv)}
         if kind == "window" and cfg.swa_sink:
             out["attention_sink_bias"] = (hq,)
+        if cfg.qk_norm:
+            out.update({"q_norm.weight": (dk,), "k_norm.weight": (dk,)})
+        if cfg.attention_gate:
+            out["g_proj.weight"] = (hq * dv, h)
         return out
     if kind == "retention":
         return {"q_proj.weight": (hq * d, h), "k_proj.weight": (hkv * d, h),
@@ -478,6 +554,17 @@ def _ffn_shapes(cfg: CausalLMConfig, kind: str) -> Dict[str, Tuple[int, ...]]:
     return out
 
 
+def layer_norms(cfg: CausalLMConfig) -> Tuple[str, ...]:
+    """The norms of a layer by name: the mixer's input norm and the FFN's,
+    which the families before afmoe call ``post_attention_layernorm``; with
+    sandwich norms, that name norms the mixer's output, ``pre_mlp_layernorm``
+    the FFN's input and ``post_mlp_layernorm`` its output."""
+    if cfg.sandwich_norms:
+        return ("input_layernorm", "post_attention_layernorm",
+                "pre_mlp_layernorm", "post_mlp_layernorm")
+    return ("input_layernorm", "post_attention_layernorm")
+
+
 def tensor_shapes(cfg: CausalLMConfig) -> Dict[str, Tuple[int, ...]]:
     """HF tensor name to shape, for every tensor of the model."""
     h = cfg.hidden_size
@@ -485,8 +572,8 @@ def tensor_shapes(cfg: CausalLMConfig) -> Dict[str, Tuple[int, ...]]:
            "model.norm.weight": (h,), "lm_head.weight": (cfg.vocab_size, h)}
     for i, kind in enumerate(cfg.layer_types):
         p = f"model.layers.{i}."
-        out[p + "input_layernorm.weight"] = (h,)
-        out[p + "post_attention_layernorm.weight"] = (h,)
+        for norm in layer_norms(cfg):
+            out[p + norm + ".weight"] = (h,)
         for name, shape in _mixer_shapes(cfg, kind).items():
             out[p + "self_attn." + name] = shape
         for name, shape in _ffn_shapes(cfg, cfg.ffn_type(i)).items():
@@ -644,10 +731,25 @@ def _swiglu(n, gate, up, down):
     return _linear(jax.nn.silu(_linear(n, gate)) * _linear(n, up), down)
 
 
+def _ffn_input(cfg: CausalLMConfig, layer, x):
+    """The FFN's input: ``x`` normed by its pre-FFN norm (:func:`layer_norms`)."""
+    return _rms_norm(x, layer["pre_mlp_layernorm" if cfg.sandwich_norms
+                              else "post_attention_layernorm"],
+                     cfg.rms_norm_eps)
+
+
+def _joined(cfg: CausalLMConfig, layer, norm: str, x, y):
+    """A sub-layer's output ``y`` added to the residual stream ``x``, normed
+    by ``norm`` first where the block has sandwich norms."""
+    if cfg.sandwich_norms:
+        y = _rms_norm(y, layer[norm], cfg.rms_norm_eps)
+    return x + y
+
+
 def _ffn(cfg: CausalLMConfig, layer, x):
-    n = _rms_norm(x, layer["post_attention_layernorm"], cfg.rms_norm_eps)
-    return x + _swiglu(n, layer["gate_proj"], layer["up_proj"],
-                       layer["down_proj"])
+    n = _ffn_input(cfg, layer, x)
+    return _joined(cfg, layer, "post_mlp_layernorm", x, _swiglu(
+        n, layer["gate_proj"], layer["up_proj"], layer["down_proj"]))
 
 
 def _route(cfg: CausalLMConfig, layer, flat, bias):
@@ -671,10 +773,13 @@ def _route(cfg: CausalLMConfig, layer, flat, bias):
 def _shared_expert(cfg: CausalLMConfig, layer, n):
     """The shared expert's term of an expert layer, or 0 where the family
     has none."""
+    import jax
+
     if not cfg.shared_intermediate_size:
         return 0.0
-    return _swiglu(n, layer["shared_gate_proj"], layer["shared_up_proj"],
-                   layer["shared_down_proj"])
+    with jax.named_scope("shared_expert"):
+        return _swiglu(n, layer["shared_gate_proj"], layer["shared_up_proj"],
+                       layer["shared_down_proj"])
 
 
 def _experts_ffn(cfg: CausalLMConfig, layer, x, valid, load):
@@ -688,7 +793,7 @@ def _experts_ffn(cfg: CausalLMConfig, layer, x, valid, load):
     from . import moe
 
     B, T, H = x.shape
-    n = _rms_norm(x, layer["post_attention_layernorm"], cfg.rms_norm_eps)
+    n = _ffn_input(cfg, layer, x)
     flat = n.reshape(B * T, H)
     _, idx, w = _route(cfg, layer, flat, layer["expert_bias"])
     with jax.named_scope(moe.ROUTE_SCOPE):
@@ -700,8 +805,11 @@ def _experts_ffn(cfg: CausalLMConfig, layer, x, valid, load):
                            dtype=jnp.dtype(cfg.dtype))
     shared = _shared_expert(cfg, layer, n)
     rows = moe.rung(sizes.sum(), local.size)[1]
-    return (x + y.reshape(B, T, H) + shared, load + added,
-            jnp.stack([rows, local.size - rows]))
+    # without post-norms the sums keep their order, (x + y) + shared
+    out = (_joined(cfg, layer, "post_mlp_layernorm", x,
+                   y.reshape(B, T, H) + shared) if cfg.sandwich_norms
+           else x + y.reshape(B, T, H) + shared)
+    return out, load + added, jnp.stack([rows, local.size - rows])
 
 
 def _experts_ffn_whole(cfg: CausalLMConfig, layer, x, bias, piece: int):
@@ -715,7 +823,7 @@ def _experts_ffn_whole(cfg: CausalLMConfig, layer, x, bias, piece: int):
     from . import moe
 
     B, T, H = x.shape
-    n = _rms_norm(x, layer["post_attention_layernorm"], cfg.rms_norm_eps)
+    n = _ffn_input(cfg, layer, x)
     flat = n.reshape(B * T, H)
     logits, idx, w = _route(cfg, layer, flat, bias)
     with jax.named_scope(moe.ROUTE_SCOPE):
@@ -847,9 +955,12 @@ def _mla_mixer_whole(cfg: CausalLMConfig, layer, a, pos):
 def _attention_mixer(cfg: CausalLMConfig, kind: str, layer, a, pos, valid, K,
                      V, length):
     """``a (B,T,H)`` through one ``attention`` or ``window`` layer's mixer:
-    projections, rotary on the first ``rotary_dim`` of queries and keys, the
-    core over what the layer caches (:mod:`alink_tpu.dl.gqa`), the values'
-    scale and ``o_proj``."""
+    projections, the q/k norm where the block has it, rotary on the first
+    ``rotary_dim`` of queries and keys (in full layers only where
+    ``full_rotary``), the core over what the layer caches
+    (:mod:`alink_tpu.dl.gqa`), the values' scale, the output gate where the
+    block has it, and ``o_proj``."""
+    import jax
     import jax.numpy as jnp
 
     from . import gqa
@@ -861,10 +972,14 @@ def _attention_mixer(cfg: CausalLMConfig, kind: str, layer, a, pos, valid, K,
     q = _linear(a, layer["q_proj"]).reshape(B, T, hq, dk)
     k = _linear(a, layer["k_proj"]).reshape(B, T, hkv, dk)
     v = _linear(a, layer["v_proj"]).reshape(B, T, hkv, dv)
+    if cfg.qk_norm:
+        q = _rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
+        k = _rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
     r = cfg.rotary_dim or dk
     turn = lambda x: jnp.concatenate([_rope(x[..., :r], pos, theta),
                                       x[..., r:]], axis=-1)
-    q, k = turn(q), turn(k)
+    if kind == "window" or cfg.full_rotary:
+        q, k = turn(q), turn(k)
     dtype = jnp.dtype(cfg.dtype)
     if kind == "attention":
         o, K, V, length = gqa.full_attend(q, k, v, K, V, length, valid,
@@ -873,8 +988,11 @@ def _attention_mixer(cfg: CausalLMConfig, kind: str, layer, a, pos, valid, K,
         o, K, V, length = gqa.window_attend(
             q, k, v, K, V, length, valid, layer.get("attention_sink_bias"),
             window=cfg.sliding_window, scale=dk ** -0.5, dtype=dtype)
-    o = o * cfg.attention_value_scale
-    return _linear(o.reshape(B, T, hq * dv), layer["o_proj"]), K, V, length
+    o = (o * cfg.attention_value_scale).reshape(B, T, hq * dv)
+    if cfg.attention_gate:
+        with jax.named_scope("attn_gate"):
+            o = o * jax.nn.sigmoid(_linear(a, layer["g_proj"]))
+    return _linear(o, layer["o_proj"]), K, V, length
 
 
 def _block(cfg: CausalLMConfig, i: int, layer, x, pos, valid, state, *,
@@ -900,19 +1018,20 @@ def _block(cfg: CausalLMConfig, i: int, layer, x, pos, valid, state, *,
         else:
             o, S, z = retention_step(q, k, v, log_g, None, S, z,
                                      eps=cfg.retention_eps)
-        x = x + _linear(o.reshape(*x.shape[:-1], -1), layer["o_proj"])
+        y = _linear(o.reshape(*x.shape[:-1], -1), layer["o_proj"])
         kept = (S, z)
     elif kind == "kda":
         y, S, tail = _kda_mixer(cfg, layer, a, valid, *state[:2], chunk=chunk)
-        x, kept = x + y, (S, tail)
+        kept = (S, tail)
     elif kind == "mla":
         y, latent, length = _mla_mixer(cfg, layer, one(a), one(pos), one(valid),
                                        *state[:2])
-        x, kept = x + back(y), (latent, length)
+        y, kept = back(y), (latent, length)
     else:
         y, *kept = _attention_mixer(cfg, kind, layer, one(a), one(pos),
                                     one(valid), *state[:3])
-        x, kept = x + back(y), tuple(kept)
+        y, kept = back(y), tuple(kept)
+    x = _joined(cfg, layer, "post_attention_layernorm", x, y)
     if ffn == "dense":
         return _ffn(cfg, layer, x), kept, None
     x, load, rows = _experts_ffn(cfg, layer, one(x), one(valid), state[-1])
@@ -929,6 +1048,15 @@ def _count_rows(through, rows, first):
 
     return tuple(jnp.where(first, 0, t) + sum(r for r in rows if r is not None)
                  for t in through)
+
+
+def _embed(cfg: CausalLMConfig, params, tokens):
+    """The tokens' rows of the embedding, float32, times the family's
+    ``embedding_scale``."""
+    import jax.numpy as jnp
+
+    x = jnp.take(params["embed_tokens"], tokens, axis=0).astype(jnp.float32)
+    return x * cfg.embedding_scale if cfg.embedding_scale != 1.0 else x
 
 
 def _greedy(cfg: CausalLMConfig, params, x):
@@ -981,7 +1109,7 @@ def _build_prefill_chunk(cfg: CausalLMConfig, rows: int):
         residual of the rows whose prompt ended in an earlier chunk;
         through: an expert stack's count (:func:`_count_rows`)."""
         valid = pos >= 0
-        x = jnp.take(params["embed_tokens"], tokens, axis=0).astype(jnp.float32)
+        x = _embed(cfg, params, tokens)
         new, counted = [], []
         for i, (layer, kept) in enumerate(zip(params["layers"],
                                               _rows_of(state, rows))):
@@ -1013,7 +1141,7 @@ def _build_decode_step(cfg: CausalLMConfig, rows: int):
         writes no state it could read and asks no expert; through: an
         expert stack's count (:func:`_count_rows`)."""
         valid = pos >= 0
-        x = jnp.take(params["embed_tokens"], tok, axis=0).astype(jnp.float32)
+        x = _embed(cfg, params, tok)
         new, counted = [], []
         for i, (layer, kept) in enumerate(zip(params["layers"],
                                               _rows_of(state, rows))):
@@ -1170,6 +1298,7 @@ class CausalLM:
             tok, logprob = self._prefill(prompts, lens, rows)
             out = self._decode(tok, logprob, lens, n, rows, max_new)
             self._count_positions_read(lens[:n], max_new)
+            self._count_rows_padded(lens, max_new)
             if "experts" in self.cfg.ffn_types:
                 load = self._count_assignments(
                     n, int(lens[:n].sum()) + n * (max_new - 1))
@@ -1199,6 +1328,30 @@ class CausalLM:
                      full * kinds.count("attention"))
         metrics.incr("attention.window_positions_read",
                      ring * kinds.count("window"))
+
+    def _count_rows_padded(self, lens: np.ndarray, max_new: int) -> None:
+        """The query rows the attention and window kernels added over a
+        batch to reach whole tiles (:func:`alink_tpu.dl.gqa.rows_added`),
+        from host arithmetic once a batch: a call's rows added a key/value
+        head, times the program's rows, the heads, the layers of the kind and
+        the calls (prompt chunks and decode steps)."""
+        from .gqa import cache_positions, rows_added
+
+        cfg, T = self.cfg, self.prefill_chunk
+        chunks = -(-int(lens.max()) // T)
+        added = 0
+        for kind in ("attention", "window"):
+            layers = cfg.layer_types.count(kind)
+            if not layers:
+                continue
+            hkv, dk, dv, _ = cfg.attention_dims(kind)
+            G = cfg.num_attention_heads // hkv
+            P = cache_positions(self.cache.positions, T)
+            added += layers * hkv * len(lens) * (
+                chunks * rows_added(kind, G * T, dk, dv, P)
+                + (max_new - 1) * rows_added(kind, G, dk, dv, P))
+        if added:
+            metrics.incr("attention.rows_padded", added)
 
     def _count_assignments(self, n: int, tokens: int) -> np.ndarray:
         """One read a batch, after its last step: per row and expert layer
@@ -1541,7 +1694,7 @@ def hf_tensors(cfg: CausalLMConfig, variables):
             else:
                 arr = layer[path[-1]]
             out.append((full, np.asarray(arr)))
-        for norm in ("input_layernorm", "post_attention_layernorm"):
+        for norm in layer_norms(cfg):
             out.append((p + norm + ".weight", np.asarray(layer[norm])))
         e += cfg.ffn_type(i) == "experts"
         yield out

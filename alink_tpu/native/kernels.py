@@ -140,7 +140,8 @@ _REGISTRY: Dict[str, Dict[str, Any]] = {
                     "(dl/attention._online_softmax_update) for the block "
                     "update; the XLA block loop of dl/gqa._blocks for the "
                     "grouped-query core, also for a cache not in whole "
-                    "blocks or query rows not in whole sublane tiles; "
+                    "blocks (query rows not in whole sublane tiles are "
+                    "padded to one, dl/gqa.tile_rows); "
                     "dl/gqa._window_xla for the window core",
         "contract": "fused core: outputs and dq, dk, dv within 2e-5 "
                     "(fp32) and 4e-2 (bf16, values of order 1) of "

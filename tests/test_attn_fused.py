@@ -684,3 +684,50 @@ def test_window_kernel_compiles_for_v5e_at_the_mimo_cells_size(one_chip, rows,
         shape(b, rows, 1, dtype=jnp.int32), shape(b, 1, keys, dtype=jnp.int32),
         shape(h, rows, 1, dtype=jnp.float32)).compile()
     assert "window_attend" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows,chunk", [(384, 64), (8, 1)],
+                         ids=["prompt_chunk", "decode_step"])
+def test_gqa_kernel_compiles_for_v5e_at_the_trinity_cells_size(one_chip, rows,
+                                                               chunk):
+    """``gqa_attend`` at the Trinity cell's full layer (16 rows, 8 key/value
+    heads of 6 queries, q/k and v 128, a cache of 17,408 positions): a
+    prompt chunk's 384 rows, and a decode step's 6 padded to a tile of 8."""
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl.attn_pallas import gqa_attention
+
+    b, h, d, p = 16, 8, 128, 17408
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+    compiled = jax.jit(lambda q, K, V, s, hi: gqa_attention(
+        q, K, V, s, hi, chunk, scale=d ** -0.5, block=1024)).lower(
+        shape(b, h, rows, d), shape(b, h, d, p), shape(b, h, p, d),
+        shape(b, dtype=jnp.int32), shape(b, dtype=jnp.int32)).compile()
+    assert "gqa_attend" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < b * h * p * d * 2
+
+
+@pytest.mark.parametrize("rows,keys", [(384, 4160), (8, 4097)],
+                         ids=["prompt_chunk", "decode_step"])
+def test_window_kernel_compiles_for_v5e_at_the_trinity_cells_size(one_chip, rows,
+                                                                  keys):
+    """``window_attend`` at the Trinity cell's sliding layers (16 rows, 8
+    key/value heads of 6 queries, q/k and v 128, a ring of 4,096 positions
+    beside a chunk of 64 or one): a prompt chunk's scores, 384 x 4,160
+    float32, held whole in VMEM; a decode step's 6 rows padded to 8."""
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl.attn_pallas import window_attention
+
+    b, h, d = 16, 8, 128
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+    compiled = jax.jit(lambda *a: window_attention(
+        *a, scale=d ** -0.5, window=4096)).lower(
+        shape(b, h, rows, d), shape(b, h, d, keys), shape(b, h, keys, d),
+        shape(b, rows, 1, dtype=jnp.int32), shape(b, 1, keys, dtype=jnp.int32),
+        shape(h, rows, 1, dtype=jnp.float32)).compile()
+    assert "window_attend" in compiled.as_text()
