@@ -869,46 +869,60 @@ def use_window_attention(rows: int, qk_dim: int, v_dim: int) -> bool:
             and v_dim % _LANES == 0 and use_attn_pallas())
 
 
-def _window_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, sink_ref, o_ref, *,
-                   scale: float, window: int):
+def _window_kernel(qpos_ref, ring_at_ref, own_at_ref, q_ref, ring_k_ref,
+                   ring_v_ref, k_ref, v_ref, sink_ref, o_ref, *, scale: float,
+                   window: int):
     """One row and key/value head: its queries against the ring's keys and
-    the chunk's own, side by side (keys transposed), each query seeing the
-    positions at or before its own within ``window``, its head's sink
-    logit in the denominator."""
+    the chunk's own, two operands under one softmax (keys transposed), each
+    query seeing the positions at or before its own within ``window``, its
+    head's sink logit in the denominator."""
     import jax.numpy as jnp
 
-    s = _dot(q_ref[...], k_ref[...], _NN) * scale              # (R, K)
-    qpos, kpos = qpos_ref[...], kpos_ref[...]                  # (R, 1), (1, K)
-    ok = (kpos >= 0) & (kpos <= qpos) & (qpos - kpos < window)
-    s = jnp.where(ok, s, _NEG_INF)
+    q, qpos = q_ref[...], qpos_ref[...]                        # (R, D), (R, 1)
+
+    def scores(k_ref, at_ref):
+        kpos = at_ref[...]                                     # (1, K)
+        ok = (kpos >= 0) & (kpos <= qpos) & (qpos - kpos < window)
+        return jnp.where(ok, _dot(q, k_ref[...], _NN) * scale, _NEG_INF), ok
+
+    s_ring, ok_ring = scores(ring_k_ref, ring_at_ref)          # (R, W)
+    s_own, ok_own = scores(k_ref, own_at_ref)                  # (R, T)
     sink = sink_ref[...]                                       # (R, 1)
-    m = jnp.maximum(sink, s.max(axis=-1, keepdims=True))
-    p = jnp.where(ok, jnp.exp(s - m), 0.0)
-    den = p.sum(axis=-1, keepdims=True) + jnp.exp(sink - m)
-    o_ref[...] = _dot(p.astype(v_ref.dtype), v_ref[...], _NN) / den
+    m = jnp.maximum(sink, jnp.maximum(s_ring.max(axis=-1, keepdims=True),
+                                      s_own.max(axis=-1, keepdims=True)))
+    p_ring = jnp.where(ok_ring, jnp.exp(s_ring - m), 0.0)
+    p_own = jnp.where(ok_own, jnp.exp(s_own - m), 0.0)
+    den = (p_ring.sum(axis=-1, keepdims=True) + p_own.sum(axis=-1, keepdims=True)
+           + jnp.exp(sink - m))
+    o = (_dot(p_ring.astype(ring_v_ref.dtype), ring_v_ref[...], _NN)
+         + _dot(p_own.astype(v_ref.dtype), v_ref[...], _NN))
+    o_ref[...] = o / den
 
 
-def window_attention(q, K, V, qpos, kpos, sink, *, scale: float, window: int,
-                     interpret: bool = False):
+def window_attention(q, ring_k, ring_v, k, v, qpos, ring_at, own_at, sink, *,
+                     scale: float, window: int, interpret: bool = False):
     """A window layer's core of ``dl/gqa.window_attend`` as one kernel.
 
     q ``(B, Hkv, R, D)``: a key/value head's queries (``dl/gqa._grouped``);
-    K ``(B, Hkv, D, S)`` (keys transposed), V ``(B, Hkv, S, Dv)``: the ring's
-    positions and the chunk's own side by side; qpos ``(B, R, 1)``,
-    kpos ``(B, 1, S)`` int32: each query's and key's position (a key's < 0:
-    not held); sink ``(Hkv, R, 1)`` float32: each query row's head's sink
-    logit (``-1e30``: none). Returns ``(B, Hkv, R, Dv)`` float32. The
-    arithmetic of the XLA form beside it: products in the operands' dtype
-    with float32 accumulation, scores, maximum and sums in float32, P cast
-    to the operands' dtype for its product; the scores live in VMEM only.
-    The caller checks :func:`use_window_attention` first."""
+    ring_k ``(B, Hkv, D, W)`` (keys transposed), ring_v ``(B, Hkv, W, Dv)``:
+    the ring as it stood before the chunk, in the cache's own layout; k
+    ``(B, Hkv, D, T)``, v ``(B, Hkv, T, Dv)``: the chunk's own, the same
+    layout; qpos ``(B, R, 1)``, ring_at ``(B, 1, W)``, own_at ``(B, 1, T)``
+    int32: each query's and key's position (a key's < 0: not held); sink
+    ``(Hkv, R, 1)`` float32: each query row's head's sink logit (``-1e30``:
+    none). Returns ``(B, Hkv, R, Dv)`` float32. The arithmetic of the XLA
+    form beside it: products in the operands' dtype with float32
+    accumulation, scores, maximum and sums in float32 over both operands,
+    P cast to the operands' dtype for its products; the scores live in VMEM
+    only, and ring and chunk are never put side by side in memory. The
+    caller checks :func:`use_window_attention` first."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, R, D = q.shape
-    S, Dv = K.shape[3], V.shape[-1]
+    W, T, Dv = ring_k.shape[3], k.shape[3], ring_v.shape[-1]
     cell = lambda *dims: pl.BlockSpec((None, None) + dims,
                                       lambda b, h: (b, h, 0, 0))
     by_row = lambda *dims: pl.BlockSpec((None,) + dims, lambda b, h: (b, 0, 0))
@@ -916,8 +930,9 @@ def window_attention(q, K, V, qpos, kpos, sink, *, scale: float, window: int,
     return pl.pallas_call(
         functools.partial(_window_kernel, scale=float(scale), window=int(window)),
         grid=(B, H),
-        in_specs=[by_row(R, 1), by_row(1, S), cell(R, D), cell(D, S),
-                  cell(S, Dv), by_head(R, 1)],
+        in_specs=[by_row(R, 1), by_row(1, W), by_row(1, T), cell(R, D),
+                  cell(D, W), cell(W, Dv), cell(D, T), cell(T, Dv),
+                  by_head(R, 1)],
         out_specs=cell(R, Dv),
         out_shape=jax.ShapeDtypeStruct((B, H, R, Dv), jnp.float32),
         compiler_params=None if interpret else pltpu.CompilerParams(
@@ -925,5 +940,7 @@ def window_attention(q, K, V, qpos, kpos, sink, *, scale: float, window: int,
             vmem_limit_bytes=_FUSED_VMEM_BYTES),
         interpret=interpret,
         name="window_attend",
-    )(qpos.astype(jnp.int32), kpos.astype(jnp.int32), q, K.astype(q.dtype),
-      V.astype(q.dtype), sink.astype(jnp.float32))
+    )(qpos.astype(jnp.int32), ring_at.astype(jnp.int32),
+      own_at.astype(jnp.int32), q, ring_k.astype(q.dtype),
+      ring_v.astype(q.dtype), k.astype(q.dtype), v.astype(q.dtype),
+      sink.astype(jnp.float32))

@@ -34,8 +34,11 @@ Its cache is a ring of the last ``W`` positions, position ``p`` in slot ``p
 % W``, laid out as the full layers' cache is; what a slot holds is known from
 the row's length alone. A prompt chunk
 (:func:`window_attend`) reads the ring and its own positions, never more,
-and leaves the ring holding the last ``W`` positions of the row, whatever the
+then writes its own positions into their slots (:func:`ring_write`), which
+leaves the ring holding the last ``W`` positions of the row, whatever the
 chunk's length and wherever its edges fall; a decode step is a chunk of one.
+The ring is never copied: a ring wider than the lane tiles a chunk reaches
+has the chunk's slots alone written in place, a narrower one one select.
 
 On one chip the window core is a kernel too (``attn_pallas.window_attention``,
 ``window_attend`` in a device trace), XLA's form everywhere else. Operands are
@@ -53,7 +56,7 @@ import jax.numpy as jnp
 
 from ..common.metrics import metrics
 from ..native.kernels import interpret_mode
-from .attn_pallas import (_SUBLANE, gqa_attention, use_gqa_attention,
+from .attn_pallas import (_LANES, _SUBLANE, gqa_attention, use_gqa_attention,
                           use_window_attention, window_attention)
 from .retention import einsum_f32
 
@@ -223,12 +226,88 @@ def ring_positions(length, W: int):
     return last - jnp.mod(last - jnp.arange(W)[None, :], W)
 
 
+def ring_write(ring_k, ring_v, length, k, v, n):
+    """A chunk's keys ``k (B,Hkv,D,T)`` and values ``v (B,Hkv,T,Dv)``, in
+    the ring's own layout, written to slots ``p % W`` of each row's ring
+    ``ring_k (B,Hkv,D,W)``, ``ring_v (B,Hkv,W,Dv)``: the first ``n (B,)``
+    positions from ``length (B,)`` on, the latest where two take one slot.
+    Only those slots change: the chunk's positions past a row's ``n`` leave
+    theirs as they were (in a ring they hold live keys).
+
+    A ring wider than the lane tiles a chunk's slots can reach has those
+    slots alone written in place, a row at a time (:func:`_ring_slices`).
+    A narrower one (MiMo's 128) is written whole by one select, the chunk
+    moved to its slots by a product with a one-hot matrix of its positions,
+    exact in any precision: a slice from a run-time slot runs at 15-35 GB/s
+    on a v5e, so there it cost more than the pass (a MiMo prompt chunk's
+    core 1.56 against 1.07 ms), where over Trinity's 4,096 the pass is the
+    cost (1.89 against 1.38 ms)."""
+    W, T = ring_k.shape[3], k.shape[3]
+    length, n = length.astype(jnp.int32), jnp.minimum(n.astype(jnp.int32), T)
+    s0 = jnp.mod(length, W)
+    if (min(T, W) + 2 * _LANES - 2) // _LANES * _LANES < W:
+        return (_ring_slices(ring_k, k, s0, n, axis=3),
+                _ring_slices(ring_v, v, s0, n, axis=2))
+    # the chunk's latest valid position that lands on each slot
+    last = n[:, None] - 1
+    j = last - jnp.mod(last - jnp.arange(W)[None, :] + s0[:, None], W)  # (B,W)
+    ok = j >= 0
+    hot = (ok[..., None] & (j[..., None] == jnp.arange(T))).astype(k.dtype)
+    moved = lambda spec, x, ring: jnp.einsum(
+        spec, x, hot, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32).astype(ring.dtype)
+    return (jnp.where(ok[:, None, None, :], moved("bhdt,bwt->bhdw", k, ring_k),
+                      ring_k),
+            jnp.where(ok[:, None, :, None], moved("bhtd,bwt->bhwd", v, ring_v),
+                      ring_v))
+
+
+def _ring_slices(ring, x, s0, m, *, axis: int):
+    """``x``'s positions ``j < m (B,)`` along ``axis`` (``T < W`` of them)
+    written to slots ``(s0 + j) % W`` of ``ring`` along it, in place, a row
+    at a time: a read-modify-write of ``T`` slots from ``s0``, the start
+    held where the slice would pass the ring's end (``dynamic_update_slice``
+    would clamp it there unasked), then of slots ``0 .. T - 2`` for the
+    positions that wrap round."""
+    B, W, T = ring.shape[0], ring.shape[axis], x.shape[axis]
+    pad = [(0, 0)] * ring.ndim
+    pad[axis] = (T, T)
+    x = jnp.pad(x.astype(ring.dtype), pad)       # position j now at j + T
+    zero = jnp.zeros((), jnp.int32)
+
+    def write(ring, b, at, j0, size):
+        """Slots ``at .. at + size`` of row ``b`` take the chunk's positions
+        from ``j0`` on, where they are valid."""
+        j = j0 + jnp.arange(size)
+        mask = [1] * ring.ndim
+        mask[axis] = size
+        ok = ((j >= 0) & (j < m[b])).reshape(mask)
+        new = jax.lax.dynamic_slice_in_dim(x[b:b + 1], jnp.clip(j0, -T, T) + T,
+                                           size, axis)
+        start = [jnp.asarray(b, jnp.int32)] + [zero] * (ring.ndim - 1)
+        start[axis] = at
+        old = jax.lax.dynamic_slice(ring, start, new.shape)
+        return jax.lax.dynamic_update_slice(ring, jnp.where(ok, new, old),
+                                            start)
+
+    first = jnp.minimum(s0, W - T)
+    for b in range(B):
+        ring = write(ring, b, first[b], first[b] - s0[b], T)
+    if T > 1:   # one position never wraps round
+        for b in range(B):
+            ring = write(ring, b, zero, W - s0[b], T - 1)
+    return ring
+
+
 def window_attend(q, k, v, ring_k, ring_v, length, valid, sink, *,
                   window: int, scale: float, dtype):
     """One window layer's core over a chunk (``T = 1``: a decode step): each
     query over the ring's positions and the chunk's own that lie in its
     window, the sink (``(Hq,)`` float32, or None) in the denominator; then
-    the ring left holding the row's last ``W`` positions.
+    the chunk's valid positions written into the ring (:func:`ring_write`),
+    which then holds the row's last ``W`` positions. The core reads the ring
+    as it stood before the chunk: written first, a chunk's later positions
+    would take the slots of keys still inside its earlier queries' window.
 
     q ``(B,T,Hq,D)`` rotated, k ``(B,T,Hkv,D)``, v ``(B,T,Hkv,Dv)``;
     ring_k ``(B,Hkv,D,W)``, ring_v ``(B,Hkv,W,Dv)``; length ``(B,)``: the
@@ -238,7 +317,7 @@ def window_attend(q, k, v, ring_k, ring_v, length, valid, sink, *,
     One algorithm, two programs, chosen by the call's own shapes and the
     kernel's gate: in a one-chip TPU process
     ``attn_pallas.window_attention`` (``window_attend`` in a device trace),
-    the ring's keys and the chunk's side by side and the scores in VMEM;
+    the ring and the chunk as operands of their own and the scores in VMEM;
     everywhere else XLA's form here (:func:`_window_xla`), the kernel's
     reference. Which one a layer was traced down is counted
     (``attention.window_fused_traces`` / ``attention.window_block_traces``)."""
@@ -247,52 +326,45 @@ def window_attend(q, k, v, ring_k, ring_v, length, valid, sink, *,
     G = Hq // hkv
     tile = tile_rows(G * T)
     fused = use_window_attention(tile, D, v.shape[-1])
+    # the chunk in the ring's layout: the kernel's operands and what is written
+    kt = k.astype(dtype).transpose(0, 2, 3, 1)                     # (B,h,D,T)
+    vt = v.astype(dtype).transpose(0, 2, 1, 3)                     # (B,h,T,Dv)
+    n = valid.sum(axis=1).astype(length.dtype)
+    # a decode step's one position takes the slot of the one W back, which
+    # its query does not see: written first, the ring the core reads is the
+    # written one, its layout pinned by the core (written after, XLA laid the
+    # whole ring out anew round the one-slot update, in and out, every step)
+    before = T == 1
     with jax.named_scope(WINDOW_SCOPE):
+        if before:
+            ring_k, ring_v = ring_write(ring_k, ring_v, length, kt, vt, n)
         qpos = length[:, None] + jnp.arange(T)[None, :]                # (B,T)
-        held = ring_positions(length, W)                               # (B,W)
+        held = ring_positions(length + n if before else length, W)     # (B,W)
+        own = valid & (not before)              # a key the ring holds: once
         if fused:
             metrics.incr("attention.window_fused_traces")
-            kpos = jnp.concatenate([jnp.where(held >= 0, held, -1),
-                                    jnp.where(valid, qpos, -1)], axis=1)
-            keys = jnp.concatenate([ring_k.astype(dtype),
-                                    k.astype(dtype).transpose(0, 2, 3, 1)], axis=3)
-            values = jnp.concatenate([ring_v.astype(dtype),
-                                      v.astype(dtype).transpose(0, 2, 1, 3)],
-                                     axis=2)
             sinks = jnp.full((hkv, G), _NEG, jnp.float32) if sink is None \
                 else sink.astype(jnp.float32).reshape(hkv, G)
             rows = _grouped(q, hkv, dtype)
             rows_at = jnp.tile(qpos, (1, G))[..., None]
-            keys_at = kpos[:, None, :]
             rows_sink = jnp.repeat(sinks, T, axis=1)[..., None]
             if tile != G * T:   # rows added: zero queries at the last row's
                 rows = _pad_rows(rows, tile, 2)      # position with no sink,
                 rows_at = _pad_rows(rows_at, tile, 1, mode="edge")  # dropped
                 rows_sink = _pad_rows(rows_sink, tile, 1, constant_values=_NEG)
-            o = window_attention(rows, keys, values, rows_at, keys_at,
-                                 rows_sink, scale=scale, window=window,
-                                 interpret=interpret_mode())
+            o = window_attention(
+                rows, ring_k, ring_v, kt, vt, rows_at,
+                jnp.where(held >= 0, held, -1)[:, None],
+                jnp.where(own, qpos, -1)[:, None], rows_sink, scale=scale,
+                window=window, interpret=interpret_mode())
             o = _ungrouped(o if tile == G * T else o[:, :, :G * T], T)
         else:
             metrics.incr("attention.window_block_traces")
-            o = _window_xla(q, k, v, ring_k, ring_v, qpos, held, valid, sink,
+            o = _window_xla(q, k, v, ring_k, ring_v, qpos, held, own, sink,
                             window=window, scale=scale, dtype=dtype)
-        # the ring after the chunk: slot s takes the chunk's position that
-        # is now the latest with p % W == s, where the chunk has one
-        n = valid.sum(axis=1).astype(length.dtype)
-        end = length + n
-        now = ring_positions(end, W)                                    # (B,W)
-        fresh = now >= length[:, None]
-        at = jnp.clip(now - length[:, None], 0, T - 1)
-        new_k, new_v = (jnp.take_along_axis(x, at[:, :, None, None], axis=1)
-                        for x in (k, v))                          # (B,W,h,.)
-        ring_k = jnp.where(fresh[:, None, None, :],
-                           new_k.transpose(0, 2, 3, 1).astype(ring_k.dtype),
-                           ring_k)
-        ring_v = jnp.where(fresh[:, None, :, None],
-                           new_v.transpose(0, 2, 1, 3).astype(ring_v.dtype),
-                           ring_v)
-    return o, ring_k, ring_v, end
+        if not before:
+            ring_k, ring_v = ring_write(ring_k, ring_v, length, kt, vt, n)
+    return o, ring_k, ring_v, length + n
 
 
 def _window_xla(q, k, v, ring_k, ring_v, qpos, held, valid, sink, *,

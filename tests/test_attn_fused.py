@@ -663,25 +663,28 @@ def test_gqa_kernel_compiles_for_v5e_at_the_mimo_cells_size(one_chip, rows,
     assert compiled.memory_analysis().temp_size_in_bytes < b * h * p * dv * 2
 
 
-@pytest.mark.parametrize("rows,keys", [(512, 192), (8, 129)],
+@pytest.mark.parametrize("rows,chunk", [(512, 64), (8, 1)],
                          ids=["prompt_chunk", "decode_step"])
 def test_window_kernel_compiles_for_v5e_at_the_mimo_cells_size(one_chip, rows,
-                                                               keys):
+                                                               chunk):
     """``window_attend`` at the MiMo cell's window layers (32 rows, 8
-    key/value heads of 8 queries, q/k 192 and v 128, the ring's 128
-    positions beside a chunk of 64 or one) through Mosaic proper."""
+    key/value heads of 8 queries, q/k 192 and v 128) through Mosaic proper:
+    the ring's 128 positions and a chunk of 64 or one as operands of their
+    own."""
     import jax
     import jax.numpy as jnp
 
     from alink_tpu.dl.attn_pallas import window_attention
 
-    b, h, d, dv = 32, 8, 192, 128
+    b, h, d, dv, w = 32, 8, 192, 128, 128
     shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         dims, dtype, sharding=one_chip)
     compiled = jax.jit(lambda *a: window_attention(
-        *a, scale=d ** -0.5, window=128)).lower(
-        shape(b, h, rows, d), shape(b, h, d, keys), shape(b, h, keys, dv),
-        shape(b, rows, 1, dtype=jnp.int32), shape(b, 1, keys, dtype=jnp.int32),
+        *a, scale=d ** -0.5, window=w)).lower(
+        shape(b, h, rows, d), shape(b, h, d, w), shape(b, h, w, dv),
+        shape(b, h, d, chunk), shape(b, h, chunk, dv),
+        shape(b, rows, 1, dtype=jnp.int32), shape(b, 1, w, dtype=jnp.int32),
+        shape(b, 1, chunk, dtype=jnp.int32),
         shape(h, rows, 1, dtype=jnp.float32)).compile()
     assert "window_attend" in compiled.as_text()
 
@@ -709,25 +712,71 @@ def test_gqa_kernel_compiles_for_v5e_at_the_trinity_cells_size(one_chip, rows,
     assert compiled.memory_analysis().temp_size_in_bytes < b * h * p * d * 2
 
 
-@pytest.mark.parametrize("rows,keys", [(384, 4160), (8, 4097)],
+@pytest.mark.parametrize("rows,chunk", [(384, 64), (8, 1)],
                          ids=["prompt_chunk", "decode_step"])
 def test_window_kernel_compiles_for_v5e_at_the_trinity_cells_size(one_chip, rows,
-                                                                  keys):
+                                                                  chunk):
     """``window_attend`` at the Trinity cell's sliding layers (16 rows, 8
-    key/value heads of 6 queries, q/k and v 128, a ring of 4,096 positions
-    beside a chunk of 64 or one): a prompt chunk's scores, 384 x 4,160
-    float32, held whole in VMEM; a decode step's 6 rows padded to 8."""
+    key/value heads of 6 queries, q/k and v 128): the ring's 4,096 positions
+    and a chunk of 64 or one as operands of their own, in the ring's layout;
+    a prompt chunk's scores, 384 x 4,096 float32, held whole in VMEM; a
+    decode step's 6 rows padded to 8. Its scratch is less than one ring:
+    nothing of the ring is copied round the kernel."""
     import jax
     import jax.numpy as jnp
 
     from alink_tpu.dl.attn_pallas import window_attention
 
-    b, h, d = 16, 8, 128
+    b, h, d, w = 16, 8, 128, 4096
     shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         dims, dtype, sharding=one_chip)
     compiled = jax.jit(lambda *a: window_attention(
-        *a, scale=d ** -0.5, window=4096)).lower(
-        shape(b, h, rows, d), shape(b, h, d, keys), shape(b, h, keys, d),
-        shape(b, rows, 1, dtype=jnp.int32), shape(b, 1, keys, dtype=jnp.int32),
+        *a, scale=d ** -0.5, window=w)).lower(
+        shape(b, h, rows, d), shape(b, h, d, w), shape(b, h, w, d),
+        shape(b, h, d, chunk), shape(b, h, chunk, d),
+        shape(b, rows, 1, dtype=jnp.int32), shape(b, 1, w, dtype=jnp.int32),
+        shape(b, 1, chunk, dtype=jnp.int32),
         shape(h, rows, 1, dtype=jnp.float32)).compile()
     assert "window_attend" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < b * h * w * d * 2
+
+
+@pytest.mark.parametrize("chunk", [64, 1], ids=["prompt_chunk", "decode_step"])
+def test_window_core_writes_the_ring_in_place_for_v5e_at_the_trinity_cells_size(
+        one_chip, monkeypatch, chunk):
+    """The whole of a Trinity window layer's core (``dl/gqa.window_attend``:
+    the kernel and the ring's write, 16 rows, 48 query heads over 8, a ring
+    of 4,096) compiled with the ring donated, as the serving programs hold
+    and call it: the ring is updated in
+    place (aliased), no copy of its shape is in the program, and the
+    scratch stays under one ring. (Written after the kernel, a decode
+    step's one-slot update made XLA lay both rings out anew, in and out,
+    every call.)"""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from alink_tpu.dl import gqa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("ALINK_ATTN_PALLAS", "1")
+    monkeypatch.delenv("ALINK_PALLAS_INTERPRET")
+    b, hq, h, d, w = 16, 48, 8, 128, 4096
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+    core = lambda q, k, v, rk, rv, n, ok, s: gqa.window_attend(
+        q, k, v, rk, rv, n, ok, s, window=w, scale=d ** -0.5,
+        dtype=jnp.bfloat16)
+    compiled = jax.jit(core, donate_argnums=(3, 4)).lower(
+        shape(b, chunk, hq, d), shape(b, chunk, h, d), shape(b, chunk, h, d),
+        shape(b, h, d, w), shape(b, h, w, d), shape(b, dtype=jnp.int32),
+        shape(b, chunk, dtype=jnp.bool_), shape(hq, dtype=jnp.float32)).compile()
+    text = compiled.as_text()
+    ring = b * h * w * d * 2
+    assert "window_attend" in text
+    assert not re.search(r"= bf16\[(16,8,128,4096|16,8,4096,128)\]\{[^}]*\} copy",
+                         text)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * ring
+    assert memory.temp_size_in_bytes < ring

@@ -10,6 +10,7 @@ manager, the counters, and each control of the benchmark's check.
 import dataclasses
 import json
 import os
+import re
 from functools import partial
 
 import jax
@@ -271,13 +272,17 @@ def test_the_full_core_takes_and_counts_the_kernel_where_its_gate_is_on(
                                _written_out(q, k, v, 10 ** 6, None), atol=2e-5)
 
 
-@pytest.mark.parametrize("T,start", [(64, [300, 5]), (8, [130, 0]), (1, [200, 3])],
-                         ids=["prompt_chunk", "short_chunk", "decode_step"])
+@pytest.mark.parametrize("T,start", [(64, [300, 5]), (8, [130, 0]), (1, [200, 3]),
+                                     (64, [230, 100])],
+                         ids=["prompt_chunk", "short_chunk", "decode_step",
+                              "wrapping_chunk"])
 def test_window_kernel_is_the_xla_form(monkeypatch, T, start):
     """``window_attend`` in the interpreter at the real widths (8 key/value
     heads of 8 queries, 192 / 128, a ring of 128) against XLA's form, the
-    sink in, bfloat16 operands; a row with part of its chunk valid and one
-    whose ring is not yet full. The ring left behind is the same array."""
+    sink in, bfloat16 operands: the ring and the chunk as the kernel's
+    operands of their own; a row with part of its chunk valid, one whose
+    ring is not yet full, and chunks whose slots pass the ring's end. The
+    ring left behind is the same array."""
     B, Hq, hkv, D, Dv, W = 2, 64, 8, 192, 128, 128
     ks = jax.random.split(jax.random.PRNGKey(5), 5)
     q = jax.random.normal(ks[0], (B, T, Hq, D))
@@ -302,6 +307,104 @@ def test_window_kernel_is_the_xla_form(monkeypatch, T, start):
     np.testing.assert_allclose(got[0], want[0], atol=2e-3)
     for a, b in zip(got[1:], want[1:]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _rebuilt(ring_k, ring_v, length, k, v, valid):
+    """The ring after a chunk as it was once computed: every slot gathered
+    anew from the chunk and selected whole against the ring."""
+    T, W = k.shape[1], ring_k.shape[3]
+    end = length + valid.sum(axis=1)
+    now = Q.ring_positions(end, W)
+    fresh = now >= length[:, None]
+    at = jnp.clip(now - length[:, None], 0, T - 1)
+    new_k, new_v = (jnp.take_along_axis(x, at[:, :, None, None], axis=1)
+                    for x in (k, v))
+    return (jnp.where(fresh[:, None, None, :], new_k.transpose(0, 2, 3, 1),
+                      ring_k),
+            jnp.where(fresh[:, None, :, None], new_v.transpose(0, 2, 1, 3),
+                      ring_v))
+
+
+# (chunk, ring, each row's positions before the chunk, each row's valid
+# ones). A ring of 16 or 8 is written whole (one select); one of 300 or
+# more in the chunk's slots alone, a row at a time.
+RING_CASES = {
+    "random_lengths": (4, 16, [37, 5, 90, 16], [4, 4, 4, 4]),
+    "a_row_with_none": (4, 16, [21, 9, 3, 40], [4, 0, 4, 0]),
+    "part_of_a_chunk": (6, 16, [12, 30, 0, 7], [6, 3, 1, 5]),
+    "decode_step": (1, 16, [15, 16, 0, 33], [1, 1, 0, 1]),
+    "past_the_ring_end": (6, 16, [13, 27, 45, 62], [6, 6, 2, 5]),
+    "ring_not_full": (4, 16, [0, 3, 8, 11], [4, 2, 4, 3]),
+    "chunk_over_the_ring": (20, 8, [3, 0, 14, 6], [20, 11, 7, 17]),
+    "rows_start_together": (4, 16, [24, 24, 9, 24], [4, 2, 0, 4]),
+    "wide_ring": (64, 512, [300, 5, 1000, 130], [64, 64, 10, 0]),
+    "wide_ring_past_its_end": (64, 512, [480, 500, 1023, 64], [64, 40, 64, 64]),
+    "wide_ring_decode_step": (1, 384, [383, 0, 1000, 255], [1, 1, 0, 1]),
+    "wide_ring_long_chunk": (200, 640, [600, 3, 250, 0], [200, 150, 0, 77]),
+    "wide_ring_not_whole_tiles": (4, 300, [299, 130, 10, 290], [4, 4, 4, 2]),
+}
+
+
+@pytest.mark.parametrize("case", list(RING_CASES))
+def test_the_ring_is_written_as_the_whole_ring_rebuilt(case):
+    """The ring after ``window_attend`` holds exactly what rebuilding it
+    whole from the chunk gives (a slot the chunk's valid positions do not
+    reach keeps what it held), whether the ring is written whole or in the
+    chunk's slots alone; and its outputs are XLA's form over the ring as
+    it stood before the chunk."""
+    T, W, lens, ns = RING_CASES[case]
+    B, Hq, hkv, D, Dv = len(lens), 4, 2, 8, 8
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 5)
+    q, k, v = (jax.random.normal(kk, (B, T, h, d)) for kk, h, d in
+               ((ks[0], Hq, D), (ks[1], hkv, D), (ks[2], hkv, Dv)))
+    ring_k = jax.random.normal(ks[3], (B, hkv, D, W))
+    ring_v = jax.random.normal(ks[4], (B, hkv, W, Dv))
+    length = jnp.asarray(lens, jnp.int32)
+    valid = jnp.arange(T)[None] < jnp.asarray(ns)[:, None]
+    sink = jnp.linspace(-1.0, 1.0, Hq)
+    with jax.default_matmul_precision("highest"):
+        o, got_k, got_v, end = Q.window_attend(
+            q, k, v, ring_k, ring_v, length, valid, sink, window=W,
+            scale=D ** -0.5, dtype=jnp.float32)
+        want_o = Q._window_xla(q, k, v, ring_k, ring_v,
+                               length[:, None] + jnp.arange(T)[None],
+                               Q.ring_positions(length, W), valid, sink,
+                               window=W, scale=D ** -0.5, dtype=jnp.float32)
+    want_k, want_v = _rebuilt(ring_k, ring_v, length, k, v, valid)
+    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    np.testing.assert_array_equal(np.asarray(end), np.asarray(lens) + ns)
+    np.testing.assert_allclose(o, want_o, atol=1e-5)
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["xla_form", "kernel"])
+@pytest.mark.parametrize("T", [4, 130, 1],
+                         ids=["prompt_chunk", "long_prompt_chunk", "decode_step"])
+def test_no_pass_over_the_whole_ring_is_lowered(monkeypatch, fused, T):
+    """``window_attend`` lowered on the CPU at a toy size with a ring of
+    four lane tiles donated, down XLA's form and down the kernel (in the
+    interpreter): no concatenation as wide as ring and chunk side by side,
+    no gather and no select of the ring's width, is in its program; the
+    ring is written by slices in place."""
+    if fused:
+        monkeypatch.setenv("ALINK_ATTN_PALLAS", "1")
+    B, Hq, hkv, D, Dv, W = 2, 8, 2, 64, 128, 512
+    f = lambda q, k, v, rk, rv, n, ok: Q.window_attend(
+        q, k, v, rk, rv, n, ok, jnp.zeros((Hq,)), window=W, scale=0.125,
+        dtype=jnp.bfloat16)
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype)
+    c0 = metrics.counters("attention.").get("attention.window_fused_traces", 0)
+    text = jax.jit(f, donate_argnums=(3, 4)).lower(
+        shape(B, T, Hq, D), shape(B, T, hkv, D), shape(B, T, hkv, Dv),
+        shape(B, hkv, D, W), shape(B, hkv, W, Dv),
+        shape(B, dtype=jnp.int32), shape(B, T, dtype=jnp.bool_)).as_text()
+    grew = metrics.counters("attention.").get("attention.window_fused_traces", 0) - c0
+    assert grew == int(fused)
+    ops = lambda name: re.findall(rf"stablehlo\.{name}.*-> tensor<([0-9x]+)x", text)
+    assert not [t for t in ops("concatenate") if str(W + T) in t.split("x")]
+    assert not [t for t in ops("gather") if str(W) in t.split("x")]
+    assert not [t for t in ops("select") if str(W) in t.split("x")]
+    assert "stablehlo.dynamic_update_slice" in text
 
 
 # -- the served generator against the reference --------------------------------
